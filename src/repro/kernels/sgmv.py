@@ -240,12 +240,8 @@ def _make_multibank_kernel(bucket_ranks, n_ob, resident, block_o):
 
             @pl.when(bkt == b)
             def _(b_ref=b_ref, r_b=r_b, res=resident[b]):
-                if res:
-                    bmat = pl.load(
-                        b_ref, (row, slice(None), pl.dslice(j * block_o,
-                                                            block_o)))
-                else:
-                    bmat = b_ref[0]
+                bmat = (b_ref[row, :, pl.ds(j * block_o, block_o)] if res
+                        else b_ref[0])
                 o_ref[...] = jnp.dot(
                     h_ref[:, :r_b], bmat,
                     preferred_element_type=jnp.float32).astype(o_ref.dtype)
@@ -439,12 +435,8 @@ def _make_multibank_expand_kernel(bucket_ranks, n_ob, resident, block_o):
 
             @pl.when(bkt == b)
             def _(b_ref=b_ref, r_b=r_b, res=resident[b]):
-                if res:
-                    bmat = pl.load(
-                        b_ref, (row, slice(None), pl.dslice(j * block_o,
-                                                            block_o)))
-                else:
-                    bmat = b_ref[0]
+                bmat = (b_ref[row, :, pl.ds(j * block_o, block_o)] if res
+                        else b_ref[0])
                 o_ref[...] = jnp.dot(
                     h_ref[:, :r_b], bmat,
                     preferred_element_type=jnp.float32).astype(o_ref.dtype)

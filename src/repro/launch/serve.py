@@ -9,9 +9,14 @@ phi-routing + distributed pool + demand estimation — and applies
 ``end_of_timestep`` rebalances while requests are in flight: arrivals
 are spread over wall-clock time with drifting adapter popularity, so at
 least one mid-run rebalance re-places adapters and re-seeds routing
-before the trace drains. This is the end-to-end driver deliverable
-(real model execution on CPU); the full-scale evaluation uses the
-calibrated simulator (benchmarks/).
+before the trace drains.
+
+The engines run the real model on whatever device JAX finds:
+``--size smoke`` (the default) builds the 2-layer reduced config that
+the CPU tests use, ``--size full`` the registered config at its
+published widths, for the accelerator (``chip_smoke.py`` drives this
+path on one TPU chip). Compiled programs are kept in JAX's persistent
+cache (``launch.compile_cache``).
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch llama-7b-paper \
@@ -25,10 +30,12 @@ import random
 import jax
 
 from repro.cluster import NetworkModel
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core import AdapterInfo, POLICIES, ServeRequest
 from repro.models import model as M
 from repro.serving import EngineBackend, LoRAServeCluster
+
+from .compile_cache import enable_compile_cache
 
 
 def build_trace(adapters, cfg, n_requests: int, prompt_len: int,
@@ -54,9 +61,21 @@ def build_trace(adapters, cfg, n_requests: int, prompt_len: int,
     return trace
 
 
-def main():
+def build_model(arch: str, size: str, seed: int):
+    """``(cfg, params)`` for ``arch``: the reduced smoke config or the
+    full published one, with fp32 weights drawn from ``seed``."""
+    cfg = get_config(arch) if size == "full" else get_smoke_config(arch)
+    params = jax.jit(M.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(seed))
+    return cfg, params
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama-7b-paper")
+    ap.add_argument("--size", default="smoke", choices=["smoke", "full"],
+                    help="model size: the 2-layer reduced config (smoke) "
+                         "or the published widths and depth (full)")
     ap.add_argument("--servers", type=int, default=2)
     ap.add_argument("--adapters", type=int, default=8)
     ap.add_argument("--requests", type=int, default=24)
@@ -142,11 +161,21 @@ def main():
     ap.add_argument("--duration", type=float, default=6.0,
                     help="seconds the trace arrivals span")
     ap.add_argument("--rebalance-period", type=float, default=1.5)
+    ap.add_argument("--timeout", type=float, default=120.0,
+                    help="seconds a request may wait in a server queue "
+                         "before it is dropped as timed out")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_smoke_config(args.arch)
-    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+
+def main(argv=None, *, model=None):
+    """Run the launcher and return its ``ClusterReport``. ``model`` is
+    an already built ``(cfg, params)`` from ``build_model`` for the same
+    ``--arch``/``--size``/``--seed``, so that a caller running several
+    replays initialises the weights once."""
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg, params = model or build_model(args.arch, args.size, args.seed)
 
     ranks = [8, 16, 32, 64, 128]
     adapters = [AdapterInfo(f"ad{i}-r{ranks[i % 5]}", ranks[i % 5],
@@ -170,7 +199,8 @@ def main():
         mesh_shape = (dp, tp)
     backend = EngineBackend(cfg, params, args.servers, max_batch=4,
                             max_len=args.prompt_len + args.max_new + 8,
-                            seed=args.seed, bank_mode=args.bank_mode,
+                            seed=args.seed, timeout=args.timeout,
+                            bank_mode=args.bank_mode,
                             decode_block=args.decode_block,
                             lora_kernel=args.lora_kernel,
                             mesh_shape=mesh_shape)
@@ -217,7 +247,7 @@ def main():
               f"unregistered={report.unregistered}")
         _write_trace()
         print("gateway drained OK")
-        return
+        return report
 
     trace = build_trace(adapters, cfg, args.requests, args.prompt_len,
                         args.max_new, args.duration, args.seed)
@@ -230,7 +260,8 @@ def main():
     s = report.summary
     print(f"bank_mode={report.bank_mode} mesh={report.mesh_shape}")
     print(f"policy={args.policy} finished={report.completed()}"
-          f"/{len(trace)} p95_ttft={s['p95_ttft']:.3f}s "
+          f"/{len(trace)} timed_out={report.timed_out} "
+          f"p95_ttft={s['p95_ttft']:.3f}s "
           f"mean_tbt={s['mean_tbt'] * 1e3:.1f}ms "
           f"fetch_latency(mean)={s['mean_fetch_latency'] * 1e3:.1f}ms")
     print(f"rebalances={report.rebalances} "
@@ -268,6 +299,7 @@ def main():
             print(f"flight_recorder: dumps={recorder.n_dumps} "
                   f"-> {args.flight_recorder}")
     print("cluster drained OK")
+    return report
 
 
 if __name__ == "__main__":

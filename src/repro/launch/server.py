@@ -43,14 +43,13 @@ def build_sim_cluster(args) -> LoRAServeCluster:
 
 
 def build_engine_cluster(args) -> LoRAServeCluster:
-    import jax
-
-    from repro.configs import get_smoke_config
-    from repro.models import model as M
     from repro.serving import EngineBackend
 
-    cfg = get_smoke_config(args.arch)
-    params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
+    from .compile_cache import enable_compile_cache
+    from .serve import build_model
+
+    enable_compile_cache()
+    cfg, params = build_model(args.arch, args.size, args.seed)
     adapters = default_adapters(args.adapters)
     backend = EngineBackend(cfg, params, args.servers, max_batch=4,
                             max_len=args.max_len, seed=args.seed)
@@ -92,6 +91,9 @@ def main(argv=None):
                          "cost model (sim) or real JAX engines (engine)")
     ap.add_argument("--arch", default="llama-7b-paper",
                     help="base model (engine backend)")
+    ap.add_argument("--size", default="smoke", choices=["smoke", "full"],
+                    help="engine backend model size: the 2-layer reduced "
+                         "config (smoke) or the published widths (full)")
     ap.add_argument("--servers", type=int, default=2)
     ap.add_argument("--adapters", type=int, default=8)
     ap.add_argument("--policy", default="loraserve",

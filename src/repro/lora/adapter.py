@@ -90,9 +90,7 @@ def init_bank(cfg, ranks, key, n_layers=None, dtype=jnp.float32):
     for r in ranks:
         key, k2 = jax.random.split(key)
         a = init_adapter(cfg, r, k2, n_layers=n_layers, dtype=dtype)
-        # pad rank dim to max_r
-        a = jax.tree.map(lambda t: pad_rank(t, max_r), a)
-        singles.append(a)
+        singles.append(pad_rank(a, max_r))
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=1), *singles)
 
 
@@ -119,15 +117,21 @@ def init_bank_from(cfg, adapter_ranks: Dict[str, int], key, n_layers=None,
     for aid in ids:
         a = init_adapter(cfg, adapter_ranks[aid], adapter_key(key, aid),
                          n_layers=n_layers, dtype=dtype)
-        singles.append(jax.tree.map(lambda t: pad_rank(t, max_r), a))
+        singles.append(pad_rank(a, max_r))
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=1), *singles)
 
 
-def pad_rank(t: jax.Array, max_r: int) -> jax.Array:
-    # A: (L, in, r) -> pad last; B: (L, r, out) -> pad middle
-    if t.shape[-1] <= max_r and t.shape[-2] > t.shape[-1]:
-        return jnp.pad(t, ((0, 0), (0, 0), (0, max_r - t.shape[-1])))
-    return jnp.pad(t, ((0, 0), (0, max_r - t.shape[-2]), (0, 0)))
+def pad_rank(adapter, max_r: int):
+    """Zero-pad one adapter to rank ``max_r``: every target's A
+    (L, in, r) along its last axis and B (L, r, out) along its middle
+    one."""
+    def pad(w, axis):
+        widths = [(0, 0)] * w.ndim
+        widths[axis] = (0, max_r - w.shape[axis])
+        return jnp.pad(w, widths)
+
+    return {t: {"A": pad(w["A"], -1), "B": pad(w["B"], -2)}
+            for t, w in adapter.items()}
 
 
 def merge_adapter(params, adapter, cfg, scaling: float = 1.0):

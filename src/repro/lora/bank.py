@@ -158,7 +158,7 @@ def build_bank(cfg, adapter_ranks: Dict[str, int], key, *,
         for aid in members[b]:
             a = init_adapter(cfg, adapter_ranks[aid], adapter_key(key, aid),
                              n_layers=n_layers, dtype=dtype)
-            singles.append(jax.tree.map(lambda t: pad_rank(t, b), a))
+            singles.append(pad_rank(a, b))
         data.append(jax.tree.map(lambda *xs: jnp.stack(xs, axis=1),
                                  *singles))
     return LoRABank("bucketed", tuple(ids), tuple(ranks), tuple(data),

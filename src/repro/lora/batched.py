@@ -110,7 +110,6 @@ def _lora_delta_sgmv(x, target, idx, scaling, block_t, interpret):
     if co is not None and A.shape[1] % co[2] == 0 \
             and B.shape[2] % co[2] == 0:
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels import resolve_interpret
         from repro.kernels.sgmv import sgmv_expand, sgmv_shrink
@@ -127,11 +126,11 @@ def _lora_delta_sgmv(x, target, idx, scaling, block_t, interpret):
             h = jax.lax.psum(h, axis)
             return sgmv_expand(h, Bs, blk, block_t=bt, interpret=interp)
 
-        y_pad = shard_map(
+        y_pad = jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(P(None, axis), P(None, axis, None),
                       P(None, None, axis), P(None)),
-            out_specs=P(None, axis), check_rep=False,
+            out_specs=P(None, axis), check_vma=False,
         )(x_pad, A, B, block_adapter)
         y = y_pad[dest] * scaling
         return constrain(tokens_to_rows(y, B_, S_), "batch", None,
@@ -162,7 +161,6 @@ def _lora_delta_sgmv_bucketed(x, bucket_targets, idx, scaling, block_t,
                     and t["B"].shape[2] % co[2] == 0
                     for t in bucket_targets):
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.kernels import resolve_interpret
         from repro.kernels.sgmv import (sgmv_multibank_expand,
@@ -189,13 +187,13 @@ def _lora_delta_sgmv_bucketed(x, bucket_targets, idx, scaling, block_t,
             return sgmv_multibank_expand(h, Bs, bkt, row, block_t=bt,
                                          interpret=interp)
 
-        y_pad = shard_map(
+        y_pad = jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(P(None, axis),
                       tuple(P(None, axis, None) for _ in A_banks),
                       tuple(P(None, None, axis) for _ in B_banks),
                       P(None), P(None)),
-            out_specs=P(None, axis), check_rep=False,
+            out_specs=P(None, axis), check_vma=False,
         )(x_pad, A_banks, B_banks, block_bucket, block_row)
         y = y_pad[dest] * scaling
         return constrain(tokens_to_rows(y, B_, S_), "batch", None,

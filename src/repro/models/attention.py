@@ -25,17 +25,6 @@ def _zero_lora(name, x):
     return 0.0
 
 
-# shard_map moved to the jax root (and check_rep became check_vma) in
-# newer jax; support both so the head-parallel path runs on the pinned
-# 0.4.x toolchain too.
-try:
-    from jax import shard_map as _shard_map
-    _SM_NOCHECK = {"check_vma": False}
-except ImportError:                                    # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NOCHECK = {"check_rep": False}
-
-
 def run_flash(q, k, v, *, causal, q_positions, k_positions, window=0,
               scale=None, extra_qk=None):
     """Flash attention, head-parallel under shard_map when the mesh
@@ -66,17 +55,16 @@ def run_flash(q, k, v, *, causal, q_positions, k_positions, window=0,
         def local(q, k, v, q2, k2):
             return flash_attention(q, k, v, **{**kw, "extra_qk": (q2, k2)})
 
-        return _shard_map(local, mesh=mesh,
-                          in_specs=(hspec, hspec, hspec, hspec,
-                                    P(bspec, None, None)),
-                          out_specs=hspec,
-                          **_SM_NOCHECK)(q, k, v, q2, k2)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(hspec, hspec, hspec, hspec,
+                                       P(bspec, None, None)),
+                             out_specs=hspec, check_vma=False)(q, k, v, q2, k2)
 
     def local(q, k, v):
         return flash_attention(q, k, v, **kw)
 
-    return _shard_map(local, mesh=mesh, in_specs=(hspec, hspec, hspec),
-                      out_specs=hspec, **_SM_NOCHECK)(q, k, v)
+    return jax.shard_map(local, mesh=mesh, in_specs=(hspec, hspec, hspec),
+                         out_specs=hspec, check_vma=False)(q, k, v)
 
 
 # ---------------------------------------------------------------------------

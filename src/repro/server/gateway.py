@@ -111,12 +111,19 @@ class ServeGateway:
         self.state = "draining"
 
     async def serve_until_stopped(self) -> None:
+        """Wait until the gateway has stopped. A failure that ended the
+        pump is raised here, so the caller sees it instead of a clean
+        drain."""
         await self._stopped.wait()
+        await self._pump_task
 
     async def _pump(self) -> None:
         """The cluster's event loop: poll on the cluster clock, fan
         events out to request streams, and — once draining — exit when
-        everything in flight has finished *and* been flushed."""
+        everything in flight has finished *and* been flushed. An
+        exception from the cluster (a device or compiler error) stops
+        the gateway and is raised by ``serve_until_stopped``."""
+        failed = True
         try:
             while True:
                 events = self.cluster.poll(self.cluster.clock())
@@ -138,17 +145,24 @@ class ServeGateway:
                         and not self._streams:
                     break
                 await asyncio.sleep(self.poll_interval)
+            failed = False
         finally:
-            await self._teardown()
+            await self._teardown(failed)
 
-    async def _teardown(self) -> None:
-        self.final_report = self.cluster.report()
-        self.cluster.close()
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-        self.state = "stopped"
-        self._stopped.set()
+    async def _teardown(self, failed: bool) -> None:
+        try:
+            if not failed:
+                self.final_report = self.cluster.report()
+                self.cluster.close()
+            if self._server is not None:
+                self._server.close()
+                if not failed:
+                    # after a failure, streams still open are cancelled
+                    # when the event loop ends
+                    await self._server.wait_closed()
+        finally:
+            self.state = "stopped"
+            self._stopped.set()
 
     # -- connection handling ----------------------------------------------
     async def _handle_connection(self, reader, writer) -> None:

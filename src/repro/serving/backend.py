@@ -374,19 +374,25 @@ class EngineBackend:
         from .engine import ServingEngine
         self._engine_cls = ServingEngine
         self.cfg = cfg
-        self.params = params
         self.n_servers = n_servers
         self.bank_mode = bank_mode
         self.decode_block = decode_block
         self.lora_kernel = lora_kernel
-        # mesh-sharded engines: every server's engine runs over its own
-        # (dp, tp) mesh built from the process's devices. None keeps the
-        # single-device engines unchanged.
+        # mesh-sharded engines: every server's engine runs over the same
+        # (dp, tp) mesh of the process's first dp*tp devices. None keeps
+        # the single-device engines unchanged.
         self.mesh_shape = mesh_shape
         self._mesh = None
         if mesh_shape is not None:
             from repro.launch.mesh import make_engine_mesh
+
+            from .sharding import make_engine_sharding
             self._mesh = make_engine_mesh(*mesh_shape)
+            # lay the base weights out once: every server's engine then
+            # shares this sharded copy instead of placing its own
+            params = make_engine_sharding(
+                self._mesh, cfg, max_batch).shard_params(params)
+        self.params = params
         self.max_batch = max_batch
         self.max_len = max_len
         self.seed = seed

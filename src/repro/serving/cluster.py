@@ -70,6 +70,7 @@ class ServeResult:
     tbt: Optional[float]
     fetch_latency: float
     n_output: int
+    tokens: Tuple[int, ...] = ()       # decoded ids (real engines only)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1064,7 +1065,8 @@ class LoRAServeCluster:
                 ttft=r.ttft if finished else None,
                 tbt=r.tbt if finished else None,
                 fetch_latency=r.fetch_latency,
-                n_output=len(r.output) if r.output else r.decoded))
+                n_output=len(r.output) if r.output else r.decoded,
+                tokens=tuple(r.output) if finished else ()))
         store = self.orch.store
         if self.orch.policy.replicate_all:
             max_adapters = len(self.adapters)

@@ -27,6 +27,21 @@ def setup():
 
 
 # -- bank construction ----------------------------------------------------
+@pytest.mark.parametrize("mode", ["padded", "bucketed"])
+def test_bank_rank_above_projection_width(mode):
+    """A rank above a projection's output width (rank 128 on the smoke
+    InternLM2's 64-wide k/v) still pads A along its rank axis and B
+    along its rank axis, never the other way round."""
+    cfg = get_smoke_config("internlm2-1.8b")
+    kv_out = cfg.n_kv_heads * cfg.resolved_head_dim
+    assert kv_out < 128
+    bank = build_bank(cfg, {"a-r8": 8, "b-r128": 128},
+                      jax.random.PRNGKey(0), mode=mode)
+    top = bank.data if mode == "padded" else bank.data[-1]
+    assert top["k"]["A"].shape[-2:] == (cfg.d_model, 128)
+    assert top["k"]["B"].shape[-2:] == (128, kv_out)
+
+
 def test_rank_bucket_power_of_two():
     assert [rank_bucket(r) for r in (1, 2, 5, 8, 9, 64, 100, 128)] == \
         [1, 2, 8, 8, 16, 64, 128, 128]

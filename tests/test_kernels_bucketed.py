@@ -7,6 +7,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import sgmv, sgmv_rank_bucketed, sgmv_reference
+from repro.kernels.ops import padded_len, prepare_segments_bucketed
+from repro.kernels.sgmv import (sgmv_multibank_blocks,
+                                sgmv_multibank_expand,
+                                sgmv_multibank_shrink)
 
 
 def _mixed_setup(seed=3, T=29, d=128, do=256, r_small=8, r_big=64):
@@ -97,3 +101,34 @@ def test_scaling_applied_bucketed():
                             scaling=1.0, interpret=True)
     np.testing.assert_allclose(np.asarray(y1), 2 * np.asarray(y2),
                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("resident", [(True, True), (False, True)])
+@pytest.mark.parametrize("kernel", ["fused", "split"])
+def test_multibank_resident_bank_over_several_output_blocks(kernel,
+                                                            resident):
+    """A resident bucket holds its whole (Na, r, d_out) B bank, so with
+    block_o < d_out each output block slices its own columns out of the
+    bank row: the fused multibank kernel and the split expand kernel
+    (the mesh-sharded path) must both still match the oracle."""
+    x, banks, (Apad, Bpad), aid, bucket, local = _mixed_setup()
+    T, d = x.shape
+    bt, bo = 8, 128                      # d_out=256: two output blocks
+    Na = bucket.shape[0]
+    dest, blk = prepare_segments_bucketed(aid, bucket, Na, len(banks), bt)
+    x_pad = jnp.zeros((padded_len(T, Na, bt), d), x.dtype).at[dest].set(x)
+    bkt, row = bucket[blk], local[blk]
+    if kernel == "fused":
+        y_pad = sgmv_multibank_blocks(x_pad, tuple(banks), bkt, row,
+                                      block_t=bt, block_o=bo,
+                                      resident=resident, interpret=True)
+    else:
+        h = sgmv_multibank_shrink(x_pad, tuple(A for A, _ in banks), bkt,
+                                  row, block_t=bt, resident=resident,
+                                  interpret=True)
+        y_pad = sgmv_multibank_expand(h, tuple(B for _, B in banks), bkt,
+                                      row, block_t=bt, block_o=bo,
+                                      resident=resident, interpret=True)
+    y_r = sgmv_reference(x, Apad, Bpad, aid)
+    np.testing.assert_allclose(np.asarray(y_pad[dest]), np.asarray(y_r),
+                               atol=1e-4)

@@ -654,3 +654,51 @@ def test_launch_server_real_sigterm_subprocess():
     assert proc.returncode == 0, out
     assert "gateway drained OK" in out
     assert "served=1" in out
+
+
+def test_gateway_poll_failure_is_raised_to_the_caller():
+    """A cluster whose ``poll`` raises (a device OOM or compiler error
+    in the engine step) stops the gateway, and ``run_gateway`` raises
+    that error instead of returning a report as if it had drained."""
+    from repro.launch.server import run_gateway
+
+    cluster, _ = make_sim_cluster()
+
+    def poll(now=None):
+        raise RuntimeError("device step failed")
+
+    cluster.poll = poll
+    lines = []
+    with pytest.raises(RuntimeError, match="device step failed"):
+        run_gateway(cluster, "127.0.0.1", 0, announce=lines.append)
+    assert lines and lines[0].startswith("listening on ")
+
+
+POLL_FAILURE_SCRIPT = r"""
+import sys
+
+from repro.launch import server
+from repro.serving import LoRAServeCluster
+
+
+def poll(self, now=None):
+    raise RuntimeError("device step failed")
+
+
+LoRAServeCluster.poll = poll
+server.main(sys.argv[1:])
+"""
+
+
+def test_launcher_exits_nonzero_when_the_gateway_fails():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.abspath("src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    env.setdefault("JAX_PLATFORMS", "cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", POLL_FAILURE_SCRIPT, "--backend", "sim",
+         "--port", "0", "--servers", "2", "--adapters", "4"],
+        capture_output=True, text=True, env=env, timeout=120)
+    assert proc.returncode != 0, proc.stdout
+    assert "device step failed" in proc.stderr
+    assert "gateway drained OK" not in proc.stdout

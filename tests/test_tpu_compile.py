@@ -1,0 +1,121 @@
+"""Compile-only checks of the SGMV kernels for a described TPU v5e chip.
+
+Nothing runs: each test lowers one kernel at a served model's widths for
+one chip of a described ``v5e:2x2`` topology and asserts that the TPU
+compiler accepted it as a Mosaic kernel (``tpu_custom_call``). That
+catches what interpret mode cannot: slices the tiling does not allow,
+blocks over the kernel's fast-memory limit, APIs the installed Pallas no
+longer has. Widths are fp32 InternLM2-1.8B (d_model 2048; q/o project to
+2048, k/v to 1024) and the paper's Llama-7B (d_model 4096), whole for
+the single-chip kernels and cut to d/4 for the per-shard halves the
+mesh-sharded engine runs at tp=4.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU compiler's library, and every test
+worker imports this file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.sgmv import (sgmv_fused_blocks, sgmv_multibank_blocks,
+                                sgmv_multibank_expand,
+                                sgmv_multibank_shrink)
+
+WIDTHS = {                       # name -> (d_model, d_out)
+    "internlm2-qo": (2048, 2048),
+    "internlm2-kv": (2048, 1024),
+    "llama7b": (4096, 4096),      # two 2048-wide output blocks
+}
+RANKS, COUNTS = (8, 64, 128), (2, 1, 1)   # rank buckets, adapters each
+BLOCK_T, TOKENS = 16, 64
+TP = 4
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # no compiler logs on disk
+        # a program compiled for a described chip can be written to the
+        # persistent cache but never read back: keep the cache off here
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            try:
+                topo = topologies.get_topology_desc(
+                    platform="tpu", topology_name="v5e:2x2")
+            except Exception as e:     # no TPU compiler in this install
+                pytest.skip(f"no v5e:2x2 topology can be described: {e}")
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _shapes(sharding, *shapes, dtype=jnp.float32):
+    return [jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+            for s in shapes]
+
+
+def _block_meta(sharding, n_adapters):
+    t_pad = TOKENS + n_adapters * BLOCK_T
+    nblocks = t_pad // BLOCK_T
+    meta = _shapes(sharding, (nblocks,), (nblocks,), dtype=jnp.int32)
+    return t_pad, meta
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_sgmv_fused_blocks_compiles(one_chip, width):
+    d, d_out = WIDTHS[width]
+    na, r = 4, max(RANKS)
+    t_pad, (blk, _) = _block_meta(one_chip, na)
+    x, a, b = _shapes(one_chip, (t_pad, d), (na, d, r), (na, r, d_out))
+    fn = functools.partial(sgmv_fused_blocks, block_t=BLOCK_T,
+                           interpret=False)
+    assert "tpu_custom_call" in _compiled_text(fn, x, a, b, blk)
+
+
+@pytest.mark.parametrize("resident", [False, True],
+                         ids=["blocked", "resident"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_sgmv_multibank_blocks_compiles(one_chip, width, resident):
+    d, d_out = WIDTHS[width]
+    t_pad, (bkt, row) = _block_meta(one_chip, sum(COUNTS))
+    (x,) = _shapes(one_chip, (t_pad, d))
+    banks = tuple(tuple(_shapes(one_chip, (n, d, r), (n, r, d_out)))
+                  for r, n in zip(RANKS, COUNTS))
+    fn = functools.partial(sgmv_multibank_blocks, block_t=BLOCK_T,
+                           resident=(resident,) * len(RANKS),
+                           interpret=False)
+    assert "tpu_custom_call" in _compiled_text(fn, x, banks, bkt, row)
+
+
+@pytest.mark.parametrize("resident", [False, True],
+                         ids=["blocked", "resident"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_sgmv_multibank_shard_halves_compile(one_chip, width, resident):
+    """The mesh-sharded engine's per-shard shrink (local d/tp slice of
+    every A bank) and expand (local d_out/tp slice of every B bank)."""
+    d, d_out = (w // TP for w in WIDTHS[width])
+    t_pad, (bkt, row) = _block_meta(one_chip, sum(COUNTS))
+    x, h = _shapes(one_chip, (t_pad, d), (t_pad, max(RANKS)))
+    a_banks = tuple(_shapes(one_chip, *[(n, d, r)
+                                        for r, n in zip(RANKS, COUNTS)]))
+    b_banks = tuple(_shapes(one_chip, *[(n, r, d_out)
+                                        for r, n in zip(RANKS, COUNTS)]))
+    res = (resident,) * len(RANKS)
+    shrink = functools.partial(sgmv_multibank_shrink, block_t=BLOCK_T,
+                               resident=res, interpret=False)
+    expand = functools.partial(sgmv_multibank_expand, block_t=BLOCK_T,
+                               resident=res, interpret=False)
+    assert "tpu_custom_call" in _compiled_text(shrink, x, a_banks, bkt, row)
+    assert "tpu_custom_call" in _compiled_text(expand, h, b_banks, bkt, row)
