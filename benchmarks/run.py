@@ -12,7 +12,7 @@ import sys
 import time
 
 from . import (bench_ablation, bench_autoscale, bench_chaos,
-               bench_interference, bench_kernels, bench_mesh, bench_obs,
+               bench_interference, bench_kernels, bench_mesh,
                bench_placement, bench_rank_skew, bench_roofline,
                bench_scalability, bench_server, bench_transfer,
                bench_workloads)
@@ -27,7 +27,6 @@ BENCHES = {
     # its padding-tax / flash-skip rows now come from padding_tax_rows()
     "kernels": bench_kernels.run,
     "mesh": bench_mesh.run,
-    "obs": bench_obs.run,
     "placement": bench_placement.run,
     "workloads": bench_workloads.run,
     "scalability": bench_scalability.run,

@@ -16,6 +16,9 @@ Two execution paths, each with a padded and a bucketed form:
     ``sgmv_bucketed_fused`` (single dispatch, every bucket at its own
     rank) for bucketed banks.
 
+Every delta the callback adds runs under ``jax.named_scope("lora")``,
+so a device trace can tell the LoRA work of a step from the rest.
+
 ``make_lora_cb`` is layout-polymorphic: a dict bank slice selects the
 padded path with ``idx: (Bt,)`` global adapter rows; a tuple of per-
 bucket slices selects the bucketed path with ``idx: (Bt, 2)`` carrying
@@ -24,6 +27,7 @@ produces.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.models.common import constrain, rows_to_tokens, tokens_to_rows
@@ -231,10 +235,11 @@ def make_lora_cb(bank_layer, idx, scaling: float = 1.0, *,
             targets = [bk.get(name) for bk in bank_layer]
             if any(t is None for t in targets):
                 return 0.0
-            if kernel == "sgmv":
-                return _lora_delta_sgmv_bucketed(x, targets, idx, scaling,
-                                                 block_t, interpret)
-            return lora_delta_bucketed(x, targets, idx, scaling)
+            with jax.named_scope("lora"):
+                if kernel == "sgmv":
+                    return _lora_delta_sgmv_bucketed(
+                        x, targets, idx, scaling, block_t, interpret)
+                return lora_delta_bucketed(x, targets, idx, scaling)
 
         return cb_bucketed
 
@@ -242,9 +247,11 @@ def make_lora_cb(bank_layer, idx, scaling: float = 1.0, *,
         t = bank_layer.get(name)
         if t is None:
             return 0.0
-        if kernel == "sgmv":
-            return _lora_delta_sgmv(x, t, idx, scaling, block_t, interpret)
-        return lora_delta(x, t["A"], t["B"], idx, scaling)
+        with jax.named_scope("lora"):
+            if kernel == "sgmv":
+                return _lora_delta_sgmv(x, t, idx, scaling, block_t,
+                                        interpret)
+            return lora_delta(x, t["A"], t["B"], idx, scaling)
 
     return cb
 

@@ -5,6 +5,10 @@ Every projection accepts an optional ``lora`` hook: a callable
 ``lora(name, x) -> delta`` used by the serving engine to add batched
 heterogeneous-adapter deltas on the Q/K/V/O projections (the paper's
 attach points).
+
+GQA names its work for the device trace: ``proj`` (the q/k/v/o
+matmuls and biases) and ``attention`` (rope, the KV write, attention
+over the cache or flash prefill); the hook names its own ``lora``.
 """
 from __future__ import annotations
 
@@ -92,20 +96,22 @@ def init_gqa(cfg, key, dtype=jnp.float32):
 def _qkv(cfg, p, x, positions, lora, rope: bool = True):
     B, S, d = x.shape
     H, Kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    q = x @ p["wq"] + lora("q", x)
-    k = x @ p["wk"] + lora("k", x)
-    v = x @ p["wv"] + lora("v", x)
-    if cfg.qkv_bias:
-        q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    q = q.reshape(B, S, H, hd)
-    k = k.reshape(B, S, Kv, hd)
-    v = v.reshape(B, S, Kv, hd)
-    if rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
-    q = constrain(q, "batch", None, "model", None)
-    k = constrain(k, "batch", None, "model", None)
-    v = constrain(v, "batch", None, "model", None)
+    with jax.named_scope("proj"):
+        q = x @ p["wq"] + lora("q", x)
+        k = x @ p["wk"] + lora("k", x)
+        v = x @ p["wv"] + lora("v", x)
+        if cfg.qkv_bias:
+            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    with jax.named_scope("attention"):
+        q = q.reshape(B, S, H, hd)
+        k = k.reshape(B, S, Kv, hd)
+        v = v.reshape(B, S, Kv, hd)
+        if rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
+        q = constrain(q, "batch", None, "model", None)
+        k = constrain(k, "batch", None, "model", None)
+        v = constrain(v, "batch", None, "model", None)
     return q, k, v
 
 
@@ -158,25 +164,27 @@ def gqa_full(cfg, p, x, positions, *, causal=True, window=0,
     q, k, v = _qkv(cfg, p, x, positions, lora)
     H, Kv = cfg.n_heads, cfg.n_kv_heads
     plan = _regroup_plan(H, Kv, _mesh_model_size())
-    if plan is not None:
-        # §Perf iter 4: duplicate kv heads (+ zero-pad query groups) so
-        # the head dims divide the mesh and the shard_map flash path
-        # engages — an identity transform, validated in
-        # test_models_features.test_kv_regroup_identity.
-        rep, Gp = plan
-        qf = _pad_regroup_q(q, Kv, rep, Gp)
-        kf = jnp.repeat(k, rep, axis=2)
-        vf = jnp.repeat(v, rep, axis=2)
-        o = run_flash(qf, kf, vf, causal=causal, q_positions=positions,
-                      k_positions=positions, window=window,
-                      scale=1.0 / (cfg.resolved_head_dim ** 0.5))
-        o = _unpad_o(o, Kv, H // Kv, rep, Gp)
-    else:
-        o = run_flash(q, k, v, causal=causal, q_positions=positions,
-                      k_positions=positions, window=window)
     B, S = x.shape[:2]
-    o = o.reshape(B, S, -1)
-    out = o @ p["wo"] + lora("o", o)
+    with jax.named_scope("attention"):
+        if plan is not None:
+            # §Perf iter 4: duplicate kv heads (+ zero-pad query groups)
+            # so the head dims divide the mesh and the shard_map flash
+            # path engages — an identity transform, validated in
+            # test_models_features.test_kv_regroup_identity.
+            rep, Gp = plan
+            qf = _pad_regroup_q(q, Kv, rep, Gp)
+            kf = jnp.repeat(k, rep, axis=2)
+            vf = jnp.repeat(v, rep, axis=2)
+            o = run_flash(qf, kf, vf, causal=causal, q_positions=positions,
+                          k_positions=positions, window=window,
+                          scale=1.0 / (cfg.resolved_head_dim ** 0.5))
+            o = _unpad_o(o, Kv, H // Kv, rep, Gp)
+        else:
+            o = run_flash(q, k, v, causal=causal, q_positions=positions,
+                          k_positions=positions, window=window)
+        o = o.reshape(B, S, -1)
+    with jax.named_scope("proj"):
+        out = o @ p["wo"] + lora("o", o)
     return constrain_resid(out), (k, v)
 
 
@@ -189,22 +197,25 @@ def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, window=0,
     B = x.shape[0]
     S = k_cache.shape[1]
     q, k, v = _qkv(cfg, p, x, pos[:, None], lora)
-    if SHARDING_MODE != "baseline":
-        # opt (§Perf iter 1): the cache is sequence-sharded over the model
-        # axis (context-parallel decode); the new token's k/v is tiny —
-        # replicate it rather than asking for a kv-head layout the mesh
-        # cannot divide (avoids the (8,2)<->(16,1) reshard storm).
-        k = constrain(k, "batch", None, None, None)
-        v = constrain(v, "batch", None, None, None)
-    write_idx = pos % S if window else pos
-    bidx = jnp.arange(B)
-    k_cache = k_cache.at[bidx, write_idx].set(k[:, 0])
-    v_cache = v_cache.at[bidx, write_idx].set(v[:, 0])
-    slots = jnp.arange(S)[None, :]
-    valid = slots <= jnp.minimum(pos, S - 1)[:, None]
-    o = attend_cache(q, k_cache, v_cache, valid)
-    o = o.reshape(B, 1, -1)
-    out = o @ p["wo"] + lora("o", o)
+    with jax.named_scope("attention"):
+        if SHARDING_MODE != "baseline":
+            # opt (§Perf iter 1): the cache is sequence-sharded over the
+            # model axis (context-parallel decode); the new token's k/v
+            # is tiny — replicate it rather than asking for a kv-head
+            # layout the mesh cannot divide (avoids the (8,2)<->(16,1)
+            # reshard storm).
+            k = constrain(k, "batch", None, None, None)
+            v = constrain(v, "batch", None, None, None)
+        write_idx = pos % S if window else pos
+        bidx = jnp.arange(B)
+        k_cache = k_cache.at[bidx, write_idx].set(k[:, 0])
+        v_cache = v_cache.at[bidx, write_idx].set(v[:, 0])
+        slots = jnp.arange(S)[None, :]
+        valid = slots <= jnp.minimum(pos, S - 1)[:, None]
+        o = attend_cache(q, k_cache, v_cache, valid)
+        o = o.reshape(B, 1, -1)
+    with jax.named_scope("proj"):
+        out = o @ p["wo"] + lora("o", o)
     return constrain_resid(out), (k_cache, v_cache)
 
 

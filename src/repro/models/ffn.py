@@ -39,10 +39,11 @@ def swiglu(p, x, shared: bool = False):
     w1 = p["ws1" if shared else "w1"]
     w3 = p["ws3" if shared else "w3"]
     w2 = p["ws2" if shared else "w2"]
-    h = jax.nn.silu(x @ w1) * (x @ w3)
-    h = constrain(h, "batch", None, "model")
-    out = h @ w2
-    return constrain_resid(out)
+    with jax.named_scope("mlp"):
+        h = jax.nn.silu(x @ w1) * (x @ w3)
+        h = constrain(h, "batch", None, "model")
+        out = h @ w2
+        return constrain_resid(out)
 
 
 def init_moe(cfg, key, dtype=jnp.float32):

@@ -211,6 +211,13 @@ def _rwkv_block(cfg, bp, x, st, lora):
 # ---------------------------------------------------------------------------
 
 
+def _layer_slice(tree, i):
+    """Layer ``i`` of every stacked leaf of ``tree``."""
+    return jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
+        tree)
+
+
 def _bank_slice(bank, i=None):
     if bank is None:
         return None
@@ -510,12 +517,10 @@ def prefill(cfg, params, tokens, *, frontend=None, bank=None, lora_idx=None,
     fam = cfg.family
     if fam in ("dense", "moe"):
         h, kvs, _ = _run_dense_full(cfg, params, x, positions, **kw)
-        if cfg.mla is not None:
-            cache["c"] = _write_prefill_kv(kvs[0], cache["c"], window)
-            cache["kr"] = _write_prefill_kv(kvs[1], cache["kr"], window)
-        else:
-            cache["k"] = _write_prefill_kv(kvs[0], cache["k"], window)
-            cache["v"] = _write_prefill_kv(kvs[1], cache["v"], window)
+        names = ("c", "kr") if cfg.mla is not None else ("k", "v")
+        with jax.named_scope("attention"):
+            for n, kv in zip(names, kvs):
+                cache[n] = _write_prefill_kv(kv, cache[n], window)
     elif fam == "vlm":
         h, (kvs, xkv), _ = _run_vlm_full(cfg, params, x, positions,
                                          frontend=frontend, **kw)
@@ -547,9 +552,10 @@ def prefill(cfg, params, tokens, *, frontend=None, bank=None, lora_idx=None,
     else:
         raise ValueError(fam)
     cache["pos"] = jnp.full((B,), S, jnp.int32)
-    h_last = rmsnorm(h[:, -1], params["ln_f"], cfg.rmsnorm_eps)
-    logits = h_last.astype(jnp.float32) @ lm_head(cfg, params).astype(
-        jnp.float32)
+    with jax.named_scope("lm_head"):
+        h_last = rmsnorm(h[:, -1], params["ln_f"], cfg.rmsnorm_eps)
+        logits = h_last.astype(jnp.float32) @ lm_head(cfg, params).astype(
+            jnp.float32)
     return logits, cache
 
 
@@ -571,28 +577,39 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
         # slice per step) rather than xs/ys: while-loop carry state is
         # aliased in place by XLA, so the donated cache is updated without
         # double-buffering the full (L,B,S,...) arrays (§Perf iter 1c).
-        def body(carry, inp):
+        # Weights and bank are sliced per layer in the body, as scan
+        # would slice xs, so that each slice carries the scope of the
+        # work that reads it.
+        blocks = params["blocks"]
+
+        def body(carry, _):
             x, ck, cv, i = carry
+            bp = _layer_slice({k: v for k, v in blocks.items()
+                               if k not in ("attn", "ffn")}, i)
+            with jax.named_scope("proj"):
+                bp["attn"] = _layer_slice(blocks["attn"], i)
+            with jax.named_scope("mlp"):
+                bp["ffn"] = _layer_slice(blocks["ffn"], i)
+            lora = None
             if bank is not None:
-                bp, bk = inp
-            else:
-                bp, bk = inp, None
-            lora = make_lora_cb(bk, lora_idx, kernel=lora_kernel) \
-                if bk is not None else None
-            kc = jax.lax.dynamic_index_in_dim(ck, i, 0, keepdims=False)
-            vc = jax.lax.dynamic_index_in_dim(cv, i, 0, keepdims=False)
+                with jax.named_scope("lora"):
+                    bk = _layer_slice(bank, i)
+                lora = make_lora_cb(bk, lora_idx, kernel=lora_kernel)
+            with jax.named_scope("attention"):
+                kc = jax.lax.dynamic_index_in_dim(ck, i, 0, keepdims=False)
+                vc = jax.lax.dynamic_index_in_dim(cv, i, 0, keepdims=False)
             x, kc, vc = _dense_block_decode(cfg, bp, x, kc, vc, pos,
                                             window, lora, mla_absorbed)
-            ck = jax.lax.dynamic_update_index_in_dim(
-                ck, kc.astype(ck.dtype), i, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(
-                cv, vc.astype(cv.dtype), i, 0)
+            with jax.named_scope("attention"):
+                ck = jax.lax.dynamic_update_index_in_dim(
+                    ck, kc.astype(ck.dtype), i, 0)
+                cv = jax.lax.dynamic_update_index_in_dim(
+                    cv, vc.astype(cv.dtype), i, 0)
             return (x, ck, cv, i + 1), None
 
-        xs = (params["blocks"], bank) if bank is not None \
-            else params["blocks"]
         (x, ck2, cv2, _), _ = jax.lax.scan(
-            body, (x, ck, cv, jnp.zeros((), jnp.int32)), xs)
+            body, (x, ck, cv, jnp.zeros((), jnp.int32)), None,
+            length=cfg.n_layers)
         if cfg.mla is not None:
             new_cache["c"], new_cache["kr"] = ck2, cv2
         else:
@@ -699,7 +716,8 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
         raise ValueError(fam)
 
     new_cache["pos"] = pos + 1
-    h_last = rmsnorm(x[:, 0], params["ln_f"], cfg.rmsnorm_eps)
-    logits = h_last.astype(jnp.float32) @ lm_head(cfg, params).astype(
-        jnp.float32)
+    with jax.named_scope("lm_head"):
+        h_last = rmsnorm(x[:, 0], params["ln_f"], cfg.rmsnorm_eps)
+        logits = h_last.astype(jnp.float32) @ lm_head(cfg, params).astype(
+            jnp.float32)
     return logits, new_cache

@@ -40,14 +40,21 @@ class Clock(Protocol):
 
 
 class WallClock:
-    """Wall-clock seconds since construction (the engine substrate's
-    time domain — matches ``EngineBackend.wall_now``)."""
+    """Wall-clock seconds since its origin (the engine substrate's time
+    domain: an ``EngineBackend`` with a tracer attached moves the
+    tracer's clock to its own origin, so both read the same seconds).
 
-    def __init__(self):
-        self._t0 = time.monotonic()
+    ``origin_ns`` is ``time.monotonic_ns()`` at the clock's zero: a span
+    at ``t`` happened at monotonic ``origin_ns + t * 1e9`` ns."""
 
-    def reset(self) -> None:
-        self._t0 = time.monotonic()
+    def __init__(self, origin_ns: Optional[int] = None):
+        self.reset(origin_ns)
+
+    def reset(self, origin_ns: Optional[int] = None) -> None:
+        """Move the zero to ``origin_ns`` (default: now)."""
+        self.origin_ns = (time.monotonic_ns() if origin_ns is None
+                          else origin_ns)
+        self._t0 = self.origin_ns * 1e-9
 
     def now(self) -> float:
         return time.monotonic() - self._t0
@@ -74,8 +81,9 @@ class Span:
     ``cat`` groups spans by kind: ``request`` (per-request phase
     decomposition), ``iteration`` (per-server prefill/decode batches),
     ``transfer`` (adapter-store data plane), ``gateway`` (HTTP front
-    end + routing). ``track`` names the Perfetto row ("requests",
-    "server:3", "store", "gateway", "control")."""
+    end), ``step`` (host work inside a serving step). ``track`` names
+    the Perfetto row ("requests", "server:3", "store", "gateway",
+    "control")."""
 
     __slots__ = ("name", "cat", "start", "end", "track", "req_id",
                  "span_id", "parent_id", "attrs")
@@ -108,6 +116,14 @@ class Span:
 class Tracer:
     """Span sink shared by every component of one serving run.
 
+    Span categories: ``request``, ``iteration``, ``transfer`` and
+    ``gateway`` (see ``Span``), and ``step``: the work inside one
+    serving step, children of the ``iteration`` spans and of the
+    cluster's ``poll`` (``poll.store``, ``rebalance``, ``poll.step``,
+    ``poll.drain``, ``submit``; per engine ``engine.step``, ``admit``,
+    ``prefill.dispatch`` / ``.sync`` / ``.merge``, ``decode.dispatch``
+    / ``.sync`` / ``.tokens`` and ``bank.rebuild``).
+
     Keeps the full span list in memory by default (bounded by
     ``max_spans`` — oldest dropped first) and fans every span out to
     listeners (flight-recorder ring, drift meter, streaming writers).
@@ -129,6 +145,23 @@ class Tracer:
     def now(self) -> float:
         return self.clock.now()
 
+    @property
+    def origin_ns(self) -> Optional[int]:
+        """``time.monotonic_ns()`` at the zero of the span clock, or None
+        on a virtual clock."""
+        return getattr(self.clock, "origin_ns", None)
+
+    def rebase(self, origin_ns: int) -> None:
+        """Move the zero of the (wall) span clock to ``origin_ns``. The
+        spans already kept shift with it, each keeping its monotonic
+        time, so spans recorded before a run starts (a warm-up) land
+        before its zero rather than inside its window."""
+        shift = (origin_ns - self.clock.origin_ns) * 1e-9
+        for span in self.spans:
+            span.start -= shift
+            span.end -= shift
+        self.clock.reset(origin_ns)
+
     def add_listener(self, fn: Callable[[Span], None]) -> None:
         if fn not in self._listeners:
             self._listeners.append(fn)
@@ -138,11 +171,9 @@ class Tracer:
                req_id: Optional[int] = None,
                parent: Optional[int] = None,
                attrs: Optional[dict] = None) -> Span:
-        # hot path (once per sim/engine iteration): build the Span via
-        # __new__ + direct slot stores instead of Span(...) — skipping
-        # the __init__ call and kwarg re-binding is a ~25% saving on the
-        # whole record cost, which is what keeps tracing-on inside the
-        # <3% throughput budget (benchmarks/bench_obs.py)
+        # hot path (several times per sim/engine iteration): build the
+        # Span via __new__ + direct slot stores instead of Span(...),
+        # skipping the __init__ call and kwarg re-binding
         span = Span.__new__(Span)
         span.name = name
         span.cat = cat

@@ -400,22 +400,33 @@ class EngineBackend:
         self._page_pool_factory = page_pool_factory
         self.engines: List[Optional[object]] = [None] * n_servers
         self._remote: List[set] = [set() for _ in range(n_servers)]
-        self._t0 = time.monotonic()
+        self.tracer = None
+        self._anchor()
         self._timed_out: List[ServeRequest] = []
         self.failed: set = set()
-        self.tracer = None
 
     def set_tracer(self, tracer) -> None:
         """Attach an ``obs.Tracer``; engines (built lazily) emit
-        iteration spans on the shared wall clock."""
+        iteration and step spans on the shared wall clock, whose origin
+        the tracer's wall clock takes (``Tracer.origin_ns``), here and
+        again at ``start``."""
         self.tracer = tracer
+        self._anchor(self._t0_ns)
         for eng in self.engines:
             if eng is not None:
                 eng.tracer = tracer
 
     # -- clock ----------------------------------------------------------
+    def _anchor(self, t0_ns: Optional[int] = None) -> None:
+        from repro.obs import WallClock
+        self._t0_ns = time.monotonic_ns() if t0_ns is None else t0_ns
+        self._t0 = self._t0_ns * 1e-9
+        if self.tracer is not None and isinstance(self.tracer.clock,
+                                                  WallClock):
+            self.tracer.rebase(self._t0_ns)
+
     def start(self) -> None:
-        self._t0 = time.monotonic()
+        self._anchor()
 
     def wall_now(self) -> float:
         return time.monotonic() - self._t0

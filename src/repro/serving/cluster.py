@@ -346,6 +346,21 @@ class LoRAServeCluster:
             return self.backend.wall_now()
         return time.monotonic() - self._wall0
 
+    def _stamp(self, now: float) -> float:
+        """A step span's clock (tracer attached): the wall clock on a
+        realtime backend; a virtual one stands still inside a poll."""
+        return self.backend.wall_now() if self.backend.realtime else now
+
+    def _span(self, name: str, start: float, now: float,
+              attrs: Optional[dict] = None, req_id: Optional[int] = None
+              ) -> float:
+        """Record a ``step`` span from ``start`` to a fresh stamp on the
+        control track; returns that stamp."""
+        end = self._stamp(now)
+        self.tracer.record(name, start, end, cat="step", track="control",
+                           req_id=req_id, attrs=attrs)
+        return end
+
     def pending(self) -> int:
         return self.backend.pending()
 
@@ -369,6 +384,7 @@ class LoRAServeCluster:
         return self.routed[req.req_id]
 
     def _dispatch(self, req: ServeRequest, now: float) -> None:
+        t0 = self._stamp(now) if self.tracer is not None else 0.0
         aid = req.adapter_id
         if req.rank == 0 and aid in self.meta:
             req.rank = self.meta[aid].rank
@@ -392,12 +408,10 @@ class LoRAServeCluster:
                 # promotes it at plan.eta
                 self.backend.load_adapter_remote(sid, aid, req.rank,
                                                  plan.read_peer)
-        if self.tracer is not None:
-            # zero-width instant: the routing decision itself
-            self.tracer.record("route", now, now, cat="gateway",
-                               track="control", req_id=req.req_id,
-                               attrs={"server": sid, "adapter_id": aid})
         self.backend.submit(sid, req, now)
+        if self.tracer is not None:
+            self._span("submit", t0, now, {"server": sid, "adapter_id": aid},
+                       req_id=req.req_id)
         self.per_server_counts[sid] += 1
         self.routed[req.req_id] = sid
         self.hub.observe_arrival(aid, sid,
@@ -761,10 +775,22 @@ class LoRAServeCluster:
 
     def _rebalance(self, period: float, now: float,
                    periodic: bool = True) -> None:
+        tracing = self.tracer is not None
+        if tracing:
+            t0 = self._stamp(now)
+            old = servers_to_adapters(self.orch.placement)
         new = self.orch.end_of_timestep(max(period, 1e-9), now=now)
         if periodic:
             self.rebalances += 1
         self._sync_banks(new)
+        if tracing:
+            cur = servers_to_adapters(new)
+            added = sum(len(set(cur[s]) - set(old.get(s, ()))) for s in cur)
+            removed = sum(len(set(old[s]) - set(cur.get(s, ())))
+                          for s in old)
+            self._span("rebalance", t0, now,
+                       {"added": added, "removed": removed,
+                        "periodic": periodic})
 
     # -- controller actions (controlplane tick) --------------------------
     def _control_tick(self, now: float) -> None:
@@ -858,6 +884,9 @@ class LoRAServeCluster:
             now = self.clock()
         if self._tracer_adv is not None:
             self._tracer_adv(now)
+        tracing = self.tracer is not None
+        if tracing:
+            t_poll = self._stamp(now)
         events: List[ClusterEvent] = []
         ctrl = self.controller
         # chaos plane first: due faults land, then heartbeats + the
@@ -869,7 +898,11 @@ class LoRAServeCluster:
         if self._pending_events:
             events.extend(self._pending_events)
             self._pending_events = []
+        if tracing:
+            t = self._stamp(now)
         self._poll_store(now)
+        if tracing:
+            t = self._span("poll.store", t, now)
         if self.orch.policy.dynamic and now + 1e-12 >= self._next_reb:
             self._rebalance(now - self._last_reb, now)
             self._last_reb = now
@@ -877,7 +910,11 @@ class LoRAServeCluster:
         if ctrl is not None and now + 1e-12 >= self._next_ctick:
             self._control_tick(now)
             self._next_ctick = now + ctrl.config.tick_period
+        if tracing:
+            t = self._stamp(now)
         self.backend.step(now)
+        if tracing:
+            t = self._span("poll.step", t, now)
         if self.track_tokens:
             for req in self.backend.live_requests():
                 toks = self._new_tokens(req)
@@ -920,9 +957,13 @@ class LoRAServeCluster:
                      "adapter_id": req.adapter_id,
                      "server": req.server, "arrival": req.arrival})
             events.append(ClusterEvent("timeout", req, (), now))
+        if tracing:
+            self._span("poll.drain", t, now)
         self._finish_retiring(now)
         self._now = max(self._now, now)
         self._end_time = max(self._end_time, self._now)
+        if tracing:
+            self._span("poll", t_poll, now)
         return events
 
     def _next_time(self, now: float, arrivals_left: bool,

@@ -69,7 +69,10 @@ class ServingEngine:
         self.cfg = cfg
         # obs.Tracer: per-iteration spans (prefill groups, decode
         # dispatches) stamped on the engine clock, carrying the batch
-        # shape so the drift meter can price them with ServerModel
+        # shape so the drift meter can price them with ServerModel, and
+        # their ``step`` children (where the host dispatches, waits on
+        # the device, merges, and hands out tokens); with no tracer the
+        # engine reads its clock no more often than it stamps requests
         self.tracer = tracer
         self._track = f"server:{server_id}"
         self.bank_mode = bank_mode
@@ -143,8 +146,15 @@ class ServingEngine:
             return contextlib.nullcontext()
         return self.sharding.ctx()
 
+    def _span(self, name: str, start: float, end: float,
+              attrs: Optional[dict] = None) -> None:
+        """A ``step`` span on this server's track (tracer attached)."""
+        self.tracer.record(name, start, end, cat="step", track=self._track,
+                           attrs=attrs)
+
     # -- placement-aware bank management --------------------------------
     def _rebuild_bank(self, adapter_ranks: Dict[str, int]) -> None:
+        t0 = self._clock() if self.tracer is not None else 0.0
         self.adapter_ranks = adapter_ranks
         n_layers = 1 if self.cfg.family == "hybrid" else self.cfg.n_layers
         self.lora_bank = build_bank(self.cfg, adapter_ranks, self._bank_key,
@@ -171,6 +181,11 @@ class ServingEngine:
                for r in self.slots]
         self.slot_adapter = jnp.asarray(idx, jnp.int32)
         self._slot_lora = self.lora_bank.lora_idx(self.slot_adapter)
+        if self.tracer is not None:
+            self._span("bank.rebuild", t0, self._clock(), {
+                "n_adapters": len(self.adapter_ids),
+                "max_rank": self.max_rank,
+                "bytes": sum(a.nbytes for a in jax.tree.leaves(self.bank))})
 
     def load_adapters(self, adapter_ranks: Dict[str, int]) -> bool:
         """Add adapters to this server's bank (placement update or pool
@@ -275,6 +290,9 @@ class ServingEngine:
         # slot -> (bucket, local) bank indices recomputed ONCE per admit
         # pass, not once per admitted slot
         self._slot_lora = self.lora_bank.lora_idx(self.slot_adapter)
+        if self.tracer is not None:
+            self._span("admit", now, self._clock(),
+                       {"admitted": len(take), "groups": len(groups)})
 
     def _batch_shape_attrs(self, reqs, value) -> dict:
         """Span attrs describing a batch's rank shape: ``max_rank`` plus,
@@ -321,6 +339,7 @@ class ServingEngine:
         if self.cfg.family == "audio":
             frontend = jnp.zeros(
                 (n, self.cfg.encoder.n_frames, self.cfg.d_model))
+        n_programs = len(self._prefill_cache)
         fn = self._prefill_fn(length)
         lidx = self.lora_bank.lora_idx(jnp.asarray(aidx, jnp.int32))
         with self._ctx():
@@ -330,7 +349,14 @@ class ServingEngine:
             else:
                 logits, cache1 = fn(self.params, toks, self.bank, lidx)
         self.prefill_dispatches += 1
+        if self.tracer is not None:
+            t_sync = self._clock()
+            self._span("prefill.dispatch", t0, t_sync, {
+                "new_program": len(self._prefill_cache) > n_programs})
         firsts = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        if self.tracer is not None:
+            t_merge = self._clock()
+            self._span("prefill.sync", t_sync, t_merge)
         slots = jnp.asarray([slot for slot, _ in grp], jnp.int32)
         with self._ctx():
             self.cache = self._merge_many(self.cache, cache1, slots,
@@ -350,6 +376,7 @@ class ServingEngine:
             req.prefill_done = t
             self.slots[slot] = req
         if self.tracer is not None:
+            self._span("prefill.merge", t_merge, t)
             reqs = [req for _, req in grp]
             attrs = self._batch_shape_attrs(reqs, lambda r: length)
             attrs.update(tokens=n * length, batch=n)
@@ -391,19 +418,31 @@ class ServingEngine:
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         self.last_token = nxt
         self.decode_dispatches += 1
+        if self.tracer is not None:
+            t_sync = self._clock()
         # analysis: ignore[host-sync] the iteration's single sync point
         nxt_np = np.asarray(nxt)
         now = self._clock()
         if self.tracer is not None:
-            attrs = self._batch_shape_attrs(active, lambda r: 1)
-            attrs.update(batch=len(active), steps=1, iters=1)
-            self.tracer.record("decode", t0, now, cat="iteration",
-                               track=self._track, attrs=attrs)
+            self._decode_spans(active, t0, t_sync, now, 1)
         for slot, req in enumerate(self.slots):
             if req is None:
                 continue
             self._finish_token(slot, req, int(nxt_np[slot]), now)
         self._iter += 1
+        if self.tracer is not None:
+            self._span("decode.tokens", now, self._clock())
+
+    def _decode_spans(self, active, t0: float, t_sync: float, now: float,
+                      k: int) -> None:
+        """The ``decode`` iteration span of one dispatch of ``k`` fused
+        steps, and its ``decode.dispatch`` / ``decode.sync`` children."""
+        self._span("decode.dispatch", t0, t_sync)
+        self._span("decode.sync", t_sync, now)
+        attrs = self._batch_shape_attrs(active, lambda r: 1)
+        attrs.update(batch=len(active), steps=k, iters=k)
+        self.tracer.record("decode", t0, now, cat="iteration",
+                           track=self._track, attrs=attrs)
 
     # -- multi-token decode steps ---------------------------------------
     def _decode_k_fn(self, k: int):
@@ -466,15 +505,14 @@ class ServingEngine:
                 self.params, self.cache, self.last_token, self.bank,
                 self._slot_lora, jnp.asarray(left, jnp.int32))
         self.decode_dispatches += 1
+        if self.tracer is not None:
+            t_sync = self._clock()
         # analysis: ignore[host-sync] ONE sync per k tokens, by design
         toks_np = np.asarray(toks)
         now = self._clock()
         if self.tracer is not None:
-            active = [r for r in self.slots if r is not None]
-            attrs = self._batch_shape_attrs(active, lambda r: 1)
-            attrs.update(batch=len(active), steps=k, iters=k)
-            self.tracer.record("decode", t0, now, cat="iteration",
-                               track=self._track, attrs=attrs)
+            self._decode_spans([r for r in self.slots if r is not None],
+                               t0, t_sync, now, k)
         for step in range(k):
             for slot, req in enumerate(self.slots):
                 if req is None or step >= left[slot]:
@@ -482,17 +520,22 @@ class ServingEngine:
                 self._finish_token(slot, req, int(toks_np[step, slot]),
                                    now)
         self._iter += k
+        if self.tracer is not None:
+            self._span("decode.tokens", now, self._clock())
         return k
 
     def step(self) -> None:
         """One engine iteration: admit then decode (prefill-prioritized).
         With ``decode_block > 1`` each step decodes up to that many
         tokens per slot in a single fused host dispatch."""
-        self._admit(self._clock())
+        t0 = self._clock()
+        self._admit(t0)
         if self.decode_block > 1:
             self.decode_steps(self.decode_block)
         else:
             self._decode_once()
+        if self.tracer is not None:
+            self._span("engine.step", t0, self._clock())
 
     def drain_completed(self) -> List[ServeRequest]:
         done, self.completed = self.completed, []

@@ -1,5 +1,6 @@
 """Real-JAX serving engine: determinism vs direct decode, co-batching
 isolation, drain behavior."""
+import re
 import time
 
 import jax
@@ -90,3 +91,27 @@ def test_bank_max_rank_padding(setup):
     # bank A tensors padded to max rank
     a = eng.bank["q"]["A"]
     assert a.shape[-1] == 64
+
+
+def test_every_dot_is_scoped(setup):
+    """The device trace reads each op's scope from its op_name: every
+    matmul of the decode and prefill programs carries one of the five
+    names."""
+    cfg, params = setup
+    eng = _mk_engine(cfg, params)
+    scopes = {"proj", "lora", "attention", "mlp", "lm_head"}
+    dec = eng._decode.lower(eng.params, eng.cache, eng.last_token,
+                            eng.bank, eng._slot_lora).compile().as_text()
+    idx = eng.lora_bank.lora_idx(jnp.zeros((2,), jnp.int32))
+    pre = eng._prefill_fn(8).lower(
+        eng.params, jnp.ones((2, 8), jnp.int32), eng.bank,
+        idx).compile().as_text()
+    for text in (dec, pre):
+        names = [re.search(r'op_name="([^"]*)"', line).group(1)
+                 for line in text.splitlines() if " dot(" in line]
+        assert names
+        for name in names:
+            assert scopes & set(name.split("/")), name
+        found = {s for name in names for s in scopes
+                 if s in name.split("/")}
+        assert found == scopes

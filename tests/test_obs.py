@@ -161,10 +161,154 @@ def test_sim_vs_engine_span_name_parity(engine_setup):
     _run_facade(be, copy.deepcopy(adapters),
                 _facade_trace(adapters, cfg), t_eng)
 
-    names_sim = {s.name for s in t_sim.spans}
-    names_eng = {s.name for s in t_eng.spans}
+    def vocab(tracer):
+        # an engine's own step spans name its dispatches and device
+        # waits, which a simulated server does not have
+        return {s.name for s in tracer.spans
+                if not (s.cat == "step" and s.track.startswith("server:"))}
+
+    names_sim = vocab(t_sim)
+    names_eng = vocab(t_eng)
     assert names_sim == names_eng
-    assert {"request", "route", *REQUEST_PHASES} <= names_sim
+    assert {"request", "submit", "poll", "poll.store", "poll.step",
+            "poll.drain", *REQUEST_PHASES} <= names_sim
+
+
+# ---------------------------------------------------------------------
+# step spans: the work inside one serving step
+# ---------------------------------------------------------------------
+def _within(child, parent, eps=1e-9):
+    return parent.start - eps <= child.start and child.end <= parent.end + eps
+
+
+def _only_child(spans, name, parent):
+    kids = [s for s in spans if s.name == name and s.track == parent.track
+            and _within(s, parent)]
+    assert len(kids) == 1, (name, parent, kids)
+    return kids[0]
+
+
+def test_engine_step_spans_nest(engine_setup):
+    cfg, params = engine_setup
+    adapters = _facade_adapters()
+    be = EngineBackend(cfg, params, 2, max_batch=2, max_len=40, seed=0)
+    tracer = Tracer(clock=WallClock())
+    report, cluster = _run_facade(be, adapters,
+                                  _facade_trace(adapters, cfg), tracer)
+    assert report.completed() > 0
+    spans = tracer.spans
+    # one clock: the tracer's zero is the backend's
+    assert tracer.origin_ns == be._t0_ns
+    assert abs(tracer.now() - be.wall_now()) < 1e-3
+
+    def iteration(name):
+        out = [s for s in spans if s.name == name and s.cat == "iteration"]
+        assert out
+        return out
+
+    for dec in iteration("decode"):
+        disp = _only_child(spans, "decode.dispatch", dec)
+        sync = _only_child(spans, "decode.sync", dec)
+        assert dec.start == disp.start <= disp.end == sync.start
+        assert sync.end == dec.end
+        toks = [s for s in spans if s.name == "decode.tokens"
+                and s.track == dec.track and s.start == dec.end]
+        assert len(toks) == 1 and toks[0].end >= dec.end
+    for pre in iteration("prefill"):
+        disp = _only_child(spans, "prefill.dispatch", pre)
+        sync = _only_child(spans, "prefill.sync", pre)
+        merge = _only_child(spans, "prefill.merge", pre)
+        assert pre.start == disp.start <= disp.end == sync.start
+        assert sync.end == merge.start <= merge.end == pre.end
+        assert isinstance(disp.attrs["new_program"], bool)
+        admit = [s for s in spans if s.name == "admit"
+                 and s.track == pre.track and _within(pre, s)]
+        assert len(admit) == 1
+    # every engine span lies inside one of the cluster's polls
+    polls = [s for s in spans if s.name == "poll"]
+    engine = [s for s in spans if s.track.startswith("server:")
+              and s.name != "bank.rebuild"]
+    assert polls and engine
+    for s in engine:
+        assert any(_within(s, p) for p in polls), s
+    for name in ("poll.store", "poll.step", "poll.drain"):
+        assert len([s for s in spans if s.name == name]) == len(polls)
+    subs = [s for s in spans if s.name == "submit"]
+    assert len(subs) == len(report.results)
+    assert all({"server", "adapter_id"} <= set(s.attrs) for s in subs)
+    # the drift meter reads iteration spans only: nothing new to price
+    assert cluster.cost_drift.unmatched == 0
+
+    # a forced rebalance: an adapter the placement does not put on a
+    # server is loaded there, and the rebalance evicts it again
+    placed = cluster.orch.placement
+    sid, aid = next((s, a.adapter_id) for s in range(be.n_servers)
+                    for a in adapters if s not in placed[a.adapter_id])
+    be.load_adapters(sid, {aid: cluster.meta[aid].rank})
+    cluster._rebalance(1.0, cluster.clock())
+    reb = tracer.named("rebalance")
+    assert len(reb) == 1 and reb[0].attrs["removed"] >= 0
+    rebuilt = [s for s in tracer.named("bank.rebuild")
+               if _within(s, reb[0])]
+    assert len(rebuilt) == 1
+    assert aid not in be.hosted_adapters(sid)
+    assert {"n_adapters", "max_rank", "bytes"} <= set(rebuilt[0].attrs)
+    assert rebuilt[0].attrs["bytes"] > 0
+
+
+def test_start_rebases_spans_recorded_before_it(engine_setup):
+    """Engine work before the cluster starts (a warm-up) keeps its
+    monotonic time when ``start`` moves the shared zero, so it lands
+    before the run's zero and not inside its window."""
+    import time
+    cfg, params = engine_setup
+    adapters = _facade_adapters()
+    be = EngineBackend(cfg, params, 2, max_batch=2, max_len=40, seed=0)
+    tracer = Tracer(clock=WallClock())
+    cluster = LoRAServeCluster(be, adapters, policy="loraserve",
+                               network=NetworkModel(), rebalance_period=1e9,
+                               seed=0, tracer=tracer)
+    assert tracer.origin_ns == be._t0_ns
+    eng = next(e for e in be.engines if e is not None)
+    aid = eng.adapter_ids[0]
+    eng.submit(ServeRequest(req_id=-1, adapter_id=aid,
+                            rank=eng.adapter_ranks[aid], prompt_len=4,
+                            output_len=2, prompt=[1, 2, 3, 4]))
+    eng.step()
+    warm = {id(s): tracer.origin_ns + s.start * 1e9 for s in tracer.spans}
+    assert tracer.named("decode")
+    time.sleep(0.01)
+    cluster.start()
+    assert tracer.origin_ns == be._t0_ns
+    assert all(s.end < 0 for s in tracer.spans)
+    for s in tracer.spans:
+        assert abs(tracer.origin_ns + s.start * 1e9 - warm[id(s)]) < 1e3
+
+
+def test_engine_clock_reads_without_tracer(engine_setup):
+    """Without a tracer the engine reads its clock as it always has:
+    once per step for admission, twice per prefill group and twice per
+    decode dispatch (the stamps requests carry)."""
+    from repro.serving import ServingEngine
+    cfg, params = engine_setup
+    reads = [0]
+
+    def clock():
+        reads[0] += 1
+        return 0.0
+
+    for block in (1, 2):
+        eng = ServingEngine(cfg, params, {"a": 8}, max_batch=2, max_len=40,
+                            decode_block=block, clock=clock)
+        reads[0] = 0
+        eng.submit(ServeRequest(req_id=0, adapter_id="a", rank=8,
+                                prompt_len=4, output_len=8,
+                                prompt=[1, 2, 3, 4]))
+        eng.step()
+        assert reads[0] == 1 + 2 + 2
+        reads[0] = 0
+        eng.step()
+        assert reads[0] == 1 + 2
 
 
 # ---------------------------------------------------------------------
@@ -260,7 +404,7 @@ def test_predict_span_seconds_shapes():
     assert predict_span_seconds(model, pre) == 0.123
     # non-iteration shapes yield None
     assert predict_span_seconds(
-        model, Span("route", 0.0, 0.0, cat="gateway",
+        model, Span("submit", 0.0, 0.0, cat="step",
                     track="control")) is None
 
 
