@@ -4,7 +4,9 @@
 Every projection accepts an optional ``lora`` hook: a callable
 ``lora(name, x) -> delta`` used by the serving engine to add batched
 heterogeneous-adapter deltas on the Q/K/V/O projections (the paper's
-attach points).
+attach points). GQA's base projections go through a ``proj`` hook,
+``proj(name, x) -> x @ p[name]`` unless the caller gives its own (the
+decode step's weight stream, ``kernels/stream.py``).
 
 GQA names its work for the device trace: ``proj`` (the q/k/v/o
 matmuls and biases) and ``attention`` (rope, the KV write, attention
@@ -22,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from .common import (SHARDING_MODE, apply_rope, attend_cache, constrain,
                      constrain_resid, current_axis_env, dense_init,
-                     flash_attention, rmsnorm)
+                     dense_proj, flash_attention, rmsnorm)
 
 
 def _zero_lora(name, x):
@@ -93,13 +95,13 @@ def init_gqa(cfg, key, dtype=jnp.float32):
     return p
 
 
-def _qkv(cfg, p, x, positions, lora, rope: bool = True):
+def _qkv(cfg, p, x, positions, lora, proj, rope: bool = True):
     B, S, d = x.shape
     H, Kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     with jax.named_scope("proj"):
-        q = x @ p["wq"] + lora("q", x)
-        k = x @ p["wk"] + lora("k", x)
-        v = x @ p["wv"] + lora("v", x)
+        q = proj("wq", x) + lora("q", x)
+        k = proj("wk", x) + lora("k", x)
+        v = proj("wv", x) + lora("v", x)
         if cfg.qkv_bias:
             q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     with jax.named_scope("attention"):
@@ -161,7 +163,7 @@ def gqa_full(cfg, p, x, positions, *, causal=True, window=0,
              lora: Optional[Callable] = None):
     """Full-sequence attention. Returns (out, (k, v)) for cache seeding."""
     lora = lora or _zero_lora
-    q, k, v = _qkv(cfg, p, x, positions, lora)
+    q, k, v = _qkv(cfg, p, x, positions, lora, dense_proj(p))
     H, Kv = cfg.n_heads, cfg.n_kv_heads
     plan = _regroup_plan(H, Kv, _mesh_model_size())
     B, S = x.shape[:2]
@@ -189,14 +191,16 @@ def gqa_full(cfg, p, x, positions, *, causal=True, window=0,
 
 
 def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, window=0,
-               lora: Optional[Callable] = None):
+               lora: Optional[Callable] = None,
+               proj: Optional[Callable] = None):
     """Single-token decode. x: (B,1,d); caches (B,S,Kv,hd); pos: (B,) int32
     current position of the new token per row. Returns (out, (k_cache,
     v_cache)) with the new token written (ring-indexed when window>0)."""
     lora = lora or _zero_lora
+    proj = proj or dense_proj(p)
     B = x.shape[0]
     S = k_cache.shape[1]
-    q, k, v = _qkv(cfg, p, x, pos[:, None], lora)
+    q, k, v = _qkv(cfg, p, x, pos[:, None], lora, proj)
     with jax.named_scope("attention"):
         if SHARDING_MODE != "baseline":
             # opt (§Perf iter 1): the cache is sequence-sharded over the
@@ -215,7 +219,7 @@ def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, window=0,
         o = attend_cache(q, k_cache, v_cache, valid)
         o = o.reshape(B, 1, -1)
     with jax.named_scope("proj"):
-        out = o @ p["wo"] + lora("o", o)
+        out = proj("wo", o) + lora("o", o)
     return constrain_resid(out), (k_cache, v_cache)
 
 

@@ -219,6 +219,12 @@ def dense_init(key, shape, fan_in=None, dtype=jnp.float32):
     return (jax.random.normal(key, shape) / jnp.sqrt(fan_in)).astype(dtype)
 
 
+def dense_proj(p):
+    """The plain projection hook ``proj(name, x) -> x @ p[name]`` over
+    one layer's weights ``p``."""
+    return lambda name, x: x @ p[name]
+
+
 # ---------------------------------------------------------------------------
 # Flash attention (pure JAX, chunked online softmax). Bounds peak memory to
 # O(B * H * chunk_q * chunk_k) so 32k prefill lowers within HBM.

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .common import (SHARDING_MODE, constrain, constrain_resid,
-                     current_axis_env, dense_init)
+                     current_axis_env, dense_init, dense_proj)
 
 
 def init_swiglu(d: int, ff: int, key, dtype=jnp.float32, prefix=""):
@@ -35,14 +35,15 @@ def init_swiglu(d: int, ff: int, key, dtype=jnp.float32, prefix=""):
     }
 
 
-def swiglu(p, x, shared: bool = False):
-    w1 = p["ws1" if shared else "w1"]
-    w3 = p["ws3" if shared else "w3"]
-    w2 = p["ws2" if shared else "w2"]
+def swiglu(p, x, shared: bool = False, proj=None):
+    """SwiGLU over ``p``'s w1/w3/w2 (ws* when ``shared``); ``proj(name,
+    x)``, when given, computes the plain projections in their place."""
+    s = "ws" if shared else "w"
+    proj = proj or dense_proj(p)
     with jax.named_scope("mlp"):
-        h = jax.nn.silu(x @ w1) * (x @ w3)
+        h = jax.nn.silu(proj(s + "1", x)) * proj(s + "3", x)
         h = constrain(h, "batch", None, "model")
-        out = h @ w2
+        out = proj(s + "2", h)
         return constrain_resid(out)
 
 

@@ -22,13 +22,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
+from repro.kernels.stream import stream_matmul
 from repro.lora.batched import make_lora_cb
 
 from .attention import (cross_attend, cross_kv, gqa_decode, gqa_full,
                         init_cross_attn, init_gqa, init_mla, mla_decode,
                         mla_full)
 from .common import (chunked_cross_entropy, constrain, constrain_resid,
-                     dense_init, rmsnorm)
+                     current_axis_env, dense_init, rmsnorm)
 from .ffn import init_moe, init_swiglu, moe_ffn, swiglu
 from .ssm import (init_mamba2, init_rwkv6, mamba2_full, mamba2_state,
                   mamba2_step, rwkv6_channel_mix, rwkv6_state, rwkv6_time_mix,
@@ -168,7 +170,9 @@ def _dense_block_full(cfg, bp, x, positions, window, lora):
 
 
 def _dense_block_decode(cfg, bp, x, kc, vc, pos, window, lora,
-                        mla_absorbed=False):
+                        mla_absorbed=False, proj=None):
+    """``proj``, when given, computes the GQA and SwiGLU projections
+    whose weights ``bp`` then leaves out (see ``decode_step``)."""
     if cfg.mla:
         h, (kc, vc) = mla_decode(cfg, bp["attn"],
                                  rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
@@ -177,13 +181,14 @@ def _dense_block_decode(cfg, bp, x, kc, vc, pos, window, lora,
     else:
         h, (kc, vc) = gqa_decode(cfg, bp["attn"],
                                  rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
-                                 kc, vc, pos, window=window, lora=lora)
+                                 kc, vc, pos, window=window, lora=lora,
+                                 proj=proj)
     x = x + h
     xn = rmsnorm(x, bp["ln2"], cfg.rmsnorm_eps)
     if cfg.moe is not None:
         f, _ = moe_ffn(cfg, bp["ffn"], xn)
     else:
-        f = swiglu(bp["ffn"], xn)
+        f = swiglu(bp["ffn"], xn, proj=proj)
     return x + f, kc, vc
 
 
@@ -559,6 +564,34 @@ def prefill(cfg, params, tokens, *, frontend=None, bank=None, lora_idx=None,
     return logits, cache
 
 
+# the dense block's matmul weights, which the decode step can stream
+_STREAMED = ("wq", "wk", "wv", "wo", "w1", "w3", "w2")
+
+
+def streams_weights(cfg, params) -> bool:
+    """Whether ``decode_step`` streams the stacked block weights through
+    ``stream_matmul`` in place of ``x @ w``: a dense or MoE block with
+    GQA attention (the MLA, VLM, audio, hybrid and SSM steps and the
+    experts keep their dots), fp32 weights, compiled kernels (a TPU,
+    whose default-precision dot takes bf16 operands; the CPU's f32 dot
+    does not), and no mesh (the sharded engine's weights are split
+    across chips)."""
+    return (cfg.family in ("dense", "moe") and cfg.mla is None
+            and params["blocks"]["attn"]["wq"].dtype == jnp.float32
+            and not kernels.default_interpret()
+            and current_axis_env().mesh is None)
+
+
+def _stream_proj(weights, i):
+    """The projection hook over layer ``i`` of the stacked ``weights``,
+    each read once and in place by ``stream_matmul``."""
+    def proj(name, x):
+        w = weights[name]
+        y = stream_matmul(x.reshape(-1, x.shape[-1]), w, i)
+        return y.reshape(x.shape[:-1] + w.shape[-1:])
+    return proj
+
+
 def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
                 window: Optional[int] = None, mla_absorbed=False,
                 lora_kernel="einsum"):
@@ -579,17 +612,27 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
         # double-buffering the full (L,B,S,...) arrays (§Perf iter 1c).
         # Weights and bank are sliced per layer in the body, as scan
         # would slice xs, so that each slice carries the scope of the
-        # work that reads it.
+        # work that reads it. Streamed weights are not sliced: the
+        # kernel takes the whole stack and the layer index (a slice fed
+        # to it would be materialised).
         blocks = params["blocks"]
+        streamed = {}
+        if streams_weights(cfg, params):
+            streamed = {n: w for g in ("attn", "ffn")
+                        for n, w in blocks[g].items() if n in _STREAMED}
+
+        def kept(group):
+            return {n: w for n, w in group.items() if n not in streamed}
 
         def body(carry, _):
             x, ck, cv, i = carry
             bp = _layer_slice({k: v for k, v in blocks.items()
                                if k not in ("attn", "ffn")}, i)
             with jax.named_scope("proj"):
-                bp["attn"] = _layer_slice(blocks["attn"], i)
+                bp["attn"] = _layer_slice(kept(blocks["attn"]), i)
             with jax.named_scope("mlp"):
-                bp["ffn"] = _layer_slice(blocks["ffn"], i)
+                bp["ffn"] = _layer_slice(kept(blocks["ffn"]), i)
+            proj = _stream_proj(streamed, i) if streamed else None
             lora = None
             if bank is not None:
                 with jax.named_scope("lora"):
@@ -599,7 +642,8 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
                 kc = jax.lax.dynamic_index_in_dim(ck, i, 0, keepdims=False)
                 vc = jax.lax.dynamic_index_in_dim(cv, i, 0, keepdims=False)
             x, kc, vc = _dense_block_decode(cfg, bp, x, kc, vc, pos,
-                                            window, lora, mla_absorbed)
+                                            window, lora, mla_absorbed,
+                                            proj)
             with jax.named_scope("attention"):
                 ck = jax.lax.dynamic_update_index_in_dim(
                     ck, kc.astype(ck.dtype), i, 0)

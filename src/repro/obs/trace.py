@@ -122,7 +122,9 @@ class Tracer:
     cluster's ``poll`` (``poll.store``, ``rebalance``, ``poll.step``,
     ``poll.drain``, ``submit``; per engine ``engine.step``, ``admit``,
     ``prefill.dispatch`` / ``.sync`` / ``.merge``, ``decode.dispatch``
-    / ``.sync`` / ``.tokens`` and ``bank.rebuild``).
+    (attr ``weights``: ``stream`` or ``xla``, how the decode program
+    reads the block weights) / ``.sync`` / ``.tokens`` and
+    ``bank.rebuild``).
 
     Keeps the full span list in memory by default (bounded by
     ``max_spans`` — oldest dropped first) and fans every span out to

@@ -137,6 +137,15 @@ class ServingEngine:
 
         self._merge_many = jax.jit(_merge_many, donate_argnums=(0,))
         self._prefill_cache = {}
+        with self._ctx():
+            self._weight_stream = M.streams_weights(cfg, params)
+
+    @property
+    def weight_stream(self) -> bool:
+        """Whether the decode program streams the block weights through
+        ``kernels.stream`` (fp32 dense weights on an unsharded TPU
+        engine) rather than leaving the dots to XLA."""
+        return self._weight_stream
 
     def _ctx(self):
         """Mesh + axis-env context every jitted call runs under (tracing
@@ -437,7 +446,8 @@ class ServingEngine:
                       k: int) -> None:
         """The ``decode`` iteration span of one dispatch of ``k`` fused
         steps, and its ``decode.dispatch`` / ``decode.sync`` children."""
-        self._span("decode.dispatch", t0, t_sync)
+        self._span("decode.dispatch", t0, t_sync, {
+            "weights": "stream" if self._weight_stream else "xla"})
         self._span("decode.sync", t_sync, now)
         attrs = self._batch_shape_attrs(active, lambda r: 1)
         attrs.update(batch=len(active), steps=k, iters=k)

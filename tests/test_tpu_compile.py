@@ -10,20 +10,29 @@ longer has. Widths are fp32 InternLM2-1.8B (d_model 2048; q/o project to
 the single-chip kernels and cut to d/4 for the per-shard halves the
 mesh-sharded engine runs at tp=4.
 
+The dense decode step is compiled whole at InternLM2-1.8B and
+StableLM-2-1.6B widths and depth, to check that its weight stream
+(``kernels/stream.py``) leaves no bf16 copy of the stacked weights.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
 worker imports this file.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import kernels
+from repro.configs import get_config
 from repro.kernels.sgmv import (sgmv_fused_blocks, sgmv_multibank_blocks,
                                 sgmv_multibank_expand,
                                 sgmv_multibank_shrink)
+from repro.models import model as M
 
 WIDTHS = {                       # name -> (d_model, d_out)
     "internlm2-qo": (2048, 2048),
@@ -119,3 +128,39 @@ def test_sgmv_multibank_shard_halves_compile(one_chip, width, resident):
                                resident=res, interpret=False)
     assert "tpu_custom_call" in _compiled_text(shrink, x, a_banks, bkt, row)
     assert "tpu_custom_call" in _compiled_text(expand, h, b_banks, bkt, row)
+
+
+# the served configurations (chipbench/configs), batch 4 over 1024 slots
+DECODE = {"internlm2-1.8b": {},
+          "stablelm-1.6b": dict(head_dim=64, qkv_bias=True)}
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE))
+def test_decode_step_streams_the_stacked_weights(one_chip, arch,
+                                                 monkeypatch):
+    """fp32 weights on a TPU take the weight stream: the step holds the
+    kernel, no convert writes a bf16 copy of a stacked block weight (the
+    XLA path writes seven, 3.0 GB / 2.6 GB of temporaries), and the
+    temporaries stay small."""
+    cfg = dataclasses.replace(get_config(arch), **DECODE[arch])
+    # code that asks for the backend sees the CPU here: steer it to the
+    # TPU's branch, as the described chip is one
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = shapes(jax.eval_shape(lambda: M.init_cache(cfg, 4, 1024)))
+    (tokens,) = _shapes(one_chip, (4,), dtype=jnp.int32)
+    step = functools.partial(M.decode_step, cfg)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    stack_copy = re.compile(rf"= bf16\[{cfg.n_layers},\S* convert\(")
+    assert not [line for line in text.splitlines()
+                if stack_copy.search(line)]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
