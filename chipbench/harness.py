@@ -10,15 +10,20 @@ first token includes any time the poll loop held it back.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
 import math
 import random
+import resource
 import sys
 import time
 from pathlib import Path
 from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
 
 from . import flops as F
 from . import generator, weights
@@ -26,6 +31,8 @@ from .peaks import peaks
 
 ROOT = Path(__file__).resolve().parents[1]
 TRACE_SECONDS = 4.0
+# a poll longer than this is logged with what the process did meanwhile
+STALL_S = 0.5
 # the plain reference's matmul precision in the check
 REFERENCE_MATMUL = "highest"
 
@@ -43,6 +50,7 @@ def log(*a) -> None:
 class Cell:
     name: str
     config: dict
+    arch: object                        # the configuration's architecture
     mix: dict
     chips: int
     end_to_end: List[dict]
@@ -63,20 +71,39 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     cfg = {c["name"]: c for c in bench["configs"]}[w["config"]]
     config = json.loads((root / cfg["file"]).read_text())
+    if "architecture" not in config:
+        raise KeyError(f"{cfg['file']} names no 'architecture': the "
+                       f"module chipbench/arch/<architecture>.py that "
+                       f"builds, counts and checks its model")
+    arch = architecture(root, config["architecture"])
     mix = generator.load_mix(generator.mix_path(root, w["traffic"]))
-    return Cell(name, config, mix, int(w["chips"]),
+    return Cell(name, config, arch, mix, int(w["chips"]),
                 _for_cell(bench["end_to_end"], name),
                 _for_cell(bench["per_layer"], name))
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: Path, name: str):
+    """The Python file ``path``, loaded once per process."""
+    if not path.is_file():
+        raise FileNotFoundError(f"no {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def architecture(root: Path, name: str):
+    """The module ``chipbench/arch/<name>.py`` of the checkout ``root``
+    (``chipbench/arch/dense.py`` lists what it defines)."""
+    path = (root / "chipbench" / "arch" / f"{name}.py").resolve()
+    return _module(path, f"chipbench_arch_{name.replace('.', '_')}")
 
 
 def reader(metric: str):
     """``read(run)`` of ``chipbench/metrics/<metric>.py``."""
     path = ROOT / "chipbench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"chipbench_metric_{metric.replace('.', '_')}").read
 
 
 # ---------------------------------------------------------------------------
@@ -169,22 +196,6 @@ class CompileCounter:
 # ---------------------------------------------------------------------------
 
 
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` from the configuration file's own
-    numbers."""
-    from repro.configs.base import LoRAConfig, ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family=cfg["family"], n_layers=cfg["n_layers"],
-        d_model=cfg["d_model"], n_heads=cfg["n_heads"],
-        n_kv_heads=cfg["n_kv_heads"], d_ff=cfg["d_ff"],
-        vocab_size=cfg["vocab_size"], head_dim=cfg["head_dim"],
-        qkv_bias=cfg["qkv_bias"], rope_theta=cfg["rope_theta"],
-        rmsnorm_eps=cfg["rmsnorm_eps"],
-        tie_embeddings=cfg["tie_embeddings"],
-        lora=LoRAConfig(targets=tuple(cfg["lora_targets"])),
-        source=cfg["source"])
-
-
 def deployment(cell: Cell) -> dict:
     return {**cell.config["deployment"], **cell.mix.get("deployment", {})}
 
@@ -196,9 +207,11 @@ def build_cluster(cell: Cell, params, seed: int, tracer=None):
     dep = deployment(cell)
     ranks = generator.adapters_of(cell.mix)
     item = F.DTYPE_BYTES[cfg["precision"]["lora_banks"]]
-    infos = [AdapterInfo(a, r, F.adapter_params(cfg, r) * item)
-             for a, r in ranks.items()]
-    backend = EngineBackend(model_config(cfg), params, dep["servers"],
+    infos = [AdapterInfo(a, r,
+                         F.adapter_params(cell.arch.target_dims, cfg, r)
+                         * item) for a, r in ranks.items()]
+    backend = EngineBackend(cell.arch.model_config(cfg), params,
+                            dep["servers"],
                             max_batch=dep["max_batch"],
                             max_len=dep["max_len"], seed=seed)
     return LoRAServeCluster(backend, infos, policy=dep["policy"],
@@ -213,7 +226,6 @@ def warm_shapes(cluster, lengths) -> None:
     length is prefilled at every group size up to ``max_batch``, each
     followed by a decode. A group the chip has no memory for is
     skipped: traffic that made it would fail the run in any case."""
-    import jax
     from repro.core.request import ServeRequest
     rid = -1
     for eng in cluster.backend.engines:
@@ -241,6 +253,28 @@ def warm_shapes(cluster, lengths) -> None:
         eng.drain_completed()
 
 
+def decode_hlo(cluster, scopes) -> Dict[str, str]:
+    """Instruction name -> scope over each engine's compiled decode
+    program (found in the compile cache once ``warm_shapes`` ran)."""
+    from . import scopes as S
+    hlo: Dict[str, str] = {}
+    clash = 0
+    for eng in cluster.backend.engines:
+        if eng is None:
+            continue
+        with eng._ctx():
+            text = eng._decode.lower(
+                eng.params, eng.cache, eng.last_token, eng.bank,
+                eng._slot_lora).compile().as_text()
+        got = S.hlo_scopes(text, scopes)
+        clash += sum(1 for k, v in got.items() if hlo.get(k, v) != v)
+        hlo.update(got)
+    if clash:
+        log(f"trace: {clash} decode instructions named alike in two "
+            f"engines' programs take different scopes")
+    return hlo
+
+
 # ---------------------------------------------------------------------------
 # the window
 # ---------------------------------------------------------------------------
@@ -259,10 +293,11 @@ class Profiler:
         self.model_flops = 0
         self.decode_least_s = 0.0
         self.decode_steps = 0
+        self.scope_least_s: Dict[str, float] = {}
+        self.hlo: Dict[str, str] = {}   # decode instruction -> scope
         self.host: List[tuple] = []      # (label, mono_start, mono_end)
 
     def start(self):
-        import jax
         jax.profiler.start_trace(self.dir)
         self.mark_mono_ns = time.monotonic_ns()
         with jax.profiler.TraceAnnotation("chipbench.mark"):
@@ -271,7 +306,6 @@ class Profiler:
         self.on = True
 
     def stop(self):
-        import jax
         self.t1 = time.monotonic()
         self.on = False
         self.done = True
@@ -287,7 +321,7 @@ class Profiler:
 
     def account(self, cluster, before: dict, finished) -> None:
         """Price each engine's work in the poll that just ran."""
-        cfg = self.cell.config
+        cfg, arch = self.cell.config, self.cell.arch
         done_by = {}
         for r in finished:
             done_by.setdefault(r.server, []).append(r)
@@ -307,16 +341,20 @@ class Profiler:
                     dec.append((r.adapter_id, r.rank,
                                 len(r.prompt) + len(r.output) - 1))
             if pre:
-                self.model_flops += F.prefill_flops(cfg, pre)
+                self.model_flops += arch.prefill_flops(cfg, pre)
             if dec:
-                fl, nb = F.decode_cost(cfg, dec)
+                fl, nb = arch.decode_cost(cfg, dec)
                 self.model_flops += fl
                 self.decode_least_s += F.least_seconds(fl, nb, self.peak)
                 self.decode_steps += 1
+                for scope, (fl, nb) in arch.scope_cost(cfg, dec).items():
+                    self.scope_least_s[scope] = self.scope_least_s.get(
+                        scope, 0.0) + F.least_seconds(fl, nb, self.peak)
 
     def reduce(self, cluster, tracer) -> Optional[dict]:
+        from . import scopes as S
         from . import trace as T
-        kept = T.load(self.dir)
+        kept = S.load(self.dir, self.hlo)
         if kept["mark_ns"] is None:
             log("trace: no chipbench.mark event; cannot align clocks")
             return None
@@ -338,8 +376,14 @@ class Profiler:
         red.update(model_flops=self.model_flops,
                    decode_least_s=self.decode_least_s,
                    decode_steps=self.decode_steps,
-                   decode_device_s=T.module_seconds(red, "jit__decode"),
+                   decode_device_s=T.module_seconds(red, S.DECODE),
+                   scope_device_s=S.decode_scopes(kept, (lo, hi)),
+                   scope_least_s=dict(self.scope_least_s),
                    span_s=self.t1 - self.t0, peak_flops=self.peak["flops"])
+        log(f"trace: {kept['unnamed']} op events outside the decode "
+            f"program's HLO; decode device s by scope "
+            + " ".join(f"{k}={v:.4f}"
+                       for k, v in sorted(red["scope_device_s"].items())))
         return red
 
 
@@ -360,6 +404,8 @@ def drive(cell: Cell, cluster, plans, *, seconds: float, counter,
     finished = set()
     marks: Dict[str, dict] = {}
     cancelled = set()
+    polls: List[float] = []             # each poll's host time in the window
+    stalls: List[str] = []              # the window's polls over STALL_S
     i, n = 0, len(plans)
 
     def mark(now):
@@ -402,7 +448,18 @@ def drive(cell: Cell, cluster, plans, *, seconds: float, counter,
             i += 1
         before = profiler.snapshot(cluster) if profiler and profiler.on \
             else None
+        t_poll = time.monotonic()
+        ru = resource.getrusage(resource.RUSAGE_SELF)
         events = host("poll", cluster.poll)
+        if "start" in marks and "end" not in marks:
+            polls.append(time.monotonic() - t_poll)
+            if polls[-1] > STALL_S:
+                ru1 = resource.getrusage(resource.RUSAGE_SELF)
+                stalls.append(
+                    f"{polls[-1]:.3f} s at {t_poll - t_start:.3f} s of "
+                    f"the run, wall {time.time() - polls[-1]:.3f}, "
+                    f"major faults {ru1.ru_majflt - ru.ru_majflt}, "
+                    f"involuntary switches {ru1.ru_nivcsw - ru.ru_nivcsw}")
         for ev in events:
             if ev.kind in ("finish", "timeout"):
                 ended[ev.req.req_id] = ev.now
@@ -435,6 +492,13 @@ def drive(cell: Cell, cluster, plans, *, seconds: float, counter,
         if cluster.pending() == 0 and i < n:
             host("sleep", time.sleep, min(max(0.0, plans[i].t - now), 0.005))
 
+    if polls:
+        log(f"window polls: {len(polls)}, median "
+            f"{1e3 * percentile(polls, 50):.2f} ms, p99 "
+            f"{1e3 * percentile(polls, 99):.2f} ms, max "
+            f"{1e3 * max(polls):.2f} ms, {sum(polls):.2f} s in all")
+    for line in stalls:
+        log(f"window poll stalled: {line}")
     if backlog:
         # served inside the window: admitted, and not done before it
         measured = [rid for rid, r in reqs.items() if rid not in cancelled
@@ -495,6 +559,14 @@ def sample_served(cell: Cell, served: list, seed: int) -> list:
     return out
 
 
+@jax.jit
+def gaps(ref_logits, ids):
+    """How far the logit of ``ids`` (S,) lies below the reference's best
+    at each position."""
+    picked = jnp.take_along_axis(ref_logits, ids[:, None], axis=-1)[:, 0]
+    return jnp.max(ref_logits, axis=-1) - picked
+
+
 def compare(cell: Cell, seed: int, sample, controls=(), refs=None) -> dict:
     """Widest gap by which a served token's logit lies below the plain
     reference's best, over ``sample`` [(adapter, rank, prompt, output)],
@@ -504,15 +576,13 @@ def compare(cell: Cell, seed: int, sample, controls=(), refs=None) -> dict:
     (``by_ref``). For each precision in ``controls`` the same two
     numbers of the tokens that the reference computed in that precision
     puts first at the same positions (``control``)."""
-    import jax.numpy as jnp
     import numpy as np
-    from .reference import dense
     t0 = time.monotonic()
-    cfg = cell.config
+    cfg, arch = cell.config, cell.arch
     stated = REFERENCE_MATMUL
     refs = (stated,) + tuple(r for r in (refs or ()) if r != stated)
     max_len = deployment(cell)["max_len"]
-    params = weights.make_params(cfg, seed)
+    params = weights.make_params(arch, cfg, seed)
     by_ref = {m: {"widest_gap": 0.0, "flips": 0,
                   "control": {c: {"widest_gap": 0.0, "flips": 0}
                               for c in controls}} for m in refs}
@@ -523,7 +593,7 @@ def compare(cell: Cell, seed: int, sample, controls=(), refs=None) -> dict:
 
     n_tok = 0
     for aid, rank, prompt, output in sample:
-        ad = weights.make_adapter(cfg, seed, aid, rank)
+        ad = weights.make_adapter(arch, cfg, seed, aid, rank)
         seq = list(prompt) + list(output[:-1])
         toks = np.zeros(max_len, np.int32)
         toks[:len(seq)] = seq
@@ -534,15 +604,15 @@ def compare(cell: Cell, seed: int, sample, controls=(), refs=None) -> dict:
         n_tok += len(output)
         low = {}
         for c in controls:
-            lg = dense.logits(cfg, params, ad, toks, precision=c)
+            lg = arch.logits(cfg, params, ad, toks, precision=c)
             low[c] = jnp.argmax(lg, axis=-1).astype(jnp.int32)
             del lg
         for m in refs:
-            ref = dense.logits(cfg, params, ad, toks, matmul=m)
-            tally(by_ref[m], np.asarray(dense.gaps(ref, jnp.asarray(tgt)))[sl])
+            ref = arch.logits(cfg, params, ad, toks, matmul=m)
+            tally(by_ref[m], np.asarray(gaps(ref, jnp.asarray(tgt)))[sl])
             for c in controls:
                 tally(by_ref[m]["control"][c],
-                      np.asarray(dense.gaps(ref, low[c]))[sl])
+                      np.asarray(gaps(ref, low[c]))[sl])
             del ref
         del ad, low
     log(f"reference over {len(sample)} requests, {n_tok} tokens: "
@@ -579,14 +649,13 @@ def serve(cell: Cell, seed: int, seconds: float, *, trace: bool = False,
     is a context manager entered around the served path to plant a fault
     in it. Off the TPU there is no peak table, so no device trace."""
     import contextlib
-    import jax
     t_start = time.monotonic() if t_start is None else t_start
     counter = CompileCounter()
     cfg = cell.config
     devices = jax.devices()[:cell.chips]
     peak = peaks(devices[0].device_kind) \
         if devices[0].platform == "tpu" else None
-    params = weights.make_params(cfg, seed)
+    params = weights.make_params(cell.arch, cfg, seed)
     jax.block_until_ready(params)
     dep = deployment(cell)
     plans = generator.schedule(cell.mix, seed=seed,
@@ -600,9 +669,11 @@ def serve(cell: Cell, seed: int, seconds: float, *, trace: bool = False,
         tracer = Tracer()
     profiler = Profiler(cell, peak, tmpdir) if (trace and peak) else None
     fault = break_program() if break_program else contextlib.nullcontext()
-    with weights.served_adapters(cfg, seed), fault:
+    with weights.served_adapters(cell.arch, cfg, seed), fault:
         cluster = build_cluster(cell, params, seed, tracer)
         warm_shapes(cluster, {p.prompt_len for p in plans})
+        if profiler is not None:
+            profiler.hlo = decode_hlo(cluster, cell.arch.SCOPES)
         log(f"set-up before traffic {time.monotonic() - t_start:.1f}s, "
             f"compiles={counter.compiles} cache_hits={counter.hits}")
         got = drive(cell, cluster, plans, seconds=seconds, counter=counter,

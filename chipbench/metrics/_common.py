@@ -31,6 +31,26 @@ def mfu(run):
     return 100.0 * p["model_flops"] / (p["span_s"] * p["peak_flops"])
 
 
+def scope_ms(run, scope):
+    """Device time of ``scope`` in the decode program
+    (``scope_device_s``) per traced decode step, in ms."""
+    p = run.profile
+    if not p or not p["decode_steps"] or not p["scope_device_s"].get(scope):
+        return None
+    return 1e3 * p["scope_device_s"][scope] / p["decode_steps"]
+
+
+def scope_roofline(run, scope):
+    """Least time of ``scope`` over the traced decode steps
+    (``scope_least_s``, the architecture's ``scope_cost``) over its
+    device time in the decode program, in percent."""
+    p = run.profile
+    if not p or not p["scope_device_s"].get(scope) \
+            or not p["scope_least_s"].get(scope):
+        return None
+    return 100.0 * p["scope_least_s"][scope] / p["scope_device_s"][scope]
+
+
 def device_idle(run):
     """Share of the traced span in which no operation ran on the device,
     in percent."""

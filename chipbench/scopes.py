@@ -2,55 +2,52 @@
 the decode program, the program's own step spans as idle-gap labels,
 and the per-layer numbers read from them.
 
-The program names its device work with ``jax.named_scope`` (``SCOPES``)
-and its host work with ``step`` spans of its ``repro.obs`` tracer
-(``poll``, ``decode.dispatch``, ``decode.sync``, ...). ``load`` keeps
-the form ``trace.load`` keeps, with each op labelled by its scope, so
-``trace.reduce`` reads it unchanged. On v5e an op event carries no
-``op_name`` (its stats are its device offset and duration); its name
-is the op's HLO instruction, so the scope comes from the compiled
-program's HLO text (``hlo_scopes``).
+The program names its device work with ``jax.named_scope`` (the
+architecture module's ``SCOPES``) and its host work with ``step`` spans
+of its ``repro.obs`` tracer (``poll``, ``decode.dispatch``,
+``decode.sync``, ...). ``load`` keeps the form ``trace.load`` keeps,
+with each op labelled by its scope, so ``trace.reduce`` reads it
+unchanged. On v5e an op event carries no ``op_name`` (its stats are its
+device offset and duration); its name is the op's HLO instruction, so
+the scope comes from the compiled program's HLO text (``hlo_scopes``).
 
-Nothing in the harness calls this module yet: the per-layer metrics it
-computes wait for the harness to keep the labelled trace and the
-window's program spans (PERF.md, Open questions).
+A traced run keeps the decode program's device time by scope
+(``decode_scopes``). The readings of the program's host spans
+(``gap_labels``, ``poll_p95_ms``, ``host_gap_ms``) wait for the harness
+to keep the window's program spans.
 """
 from __future__ import annotations
 
 import bisect
-import glob
-import os
 import re
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from . import trace as T
-from .flops import DTYPE_BYTES
 from .harness import percentile
-from .weights import target_dims
 
-SCOPES = ("proj", "lora", "attention", "mlp", "lm_head")
 OTHER = "other"
 DECODE = "jit__decode"
 _HLO_LINE = re.compile(
     r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?metadata=\{[^}]*op_name="([^"]*)"')
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost of ``SCOPES`` in a ``/``-separated op_name path."""
+def scope_of(op_name: str, scopes: Tuple[str, ...]) -> str:
+    """The innermost of ``scopes`` (the architecture module's
+    ``SCOPES``) in a ``/``-separated op_name path."""
     for part in reversed(op_name.split("/")):
-        if part in SCOPES:
+        if part in scopes:
             return part
     return OTHER
 
 
-def hlo_scopes(hlo_text: str) -> Dict[str, str]:
-    """Instruction name -> scope, over every computation of a compiled
-    program's HLO text."""
+def hlo_scopes(hlo_text: str, scopes: Tuple[str, ...]) -> Dict[str, str]:
+    """Instruction name -> scope of ``scopes``, over every computation
+    of a compiled program's HLO text."""
     out = {}
     for line in hlo_text.splitlines():
         m = _HLO_LINE.match(line)
         if m:
-            out[m.group(1)] = scope_of(m.group(2))
+            out[m.group(1)] = scope_of(m.group(2), scopes)
     return out
 
 
@@ -65,37 +62,18 @@ def load(trace_dir: str, hlo: Dict[str, str]) -> dict:
     ``trace_dir``, each op labelled with its scope from ``hlo``
     (``hlo_scopes``); ``unnamed`` counts the ops ``hlo`` does not
     hold, labelled ``other``."""
-    from jax.profiler import ProfileData
-    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    pd = ProfileData.from_file(paths[-1])
-    out = {"devices": [], "mark_ns": None, "unnamed": 0}
-    for plane in pd.planes:
-        if plane.name.startswith("/device:"):
-            lines = {}
-            for line in plane.lines:
-                if line.name == T.OPS_LINE:
-                    ops = []
-                    for ev in line.events:
-                        scope = event_scope(ev.name, hlo)
-                        if scope is None:
-                            out["unnamed"] += 1
-                            scope = OTHER
-                        ops.append([scope, ev.start_ns, ev.duration_ns])
-                    lines[T.OPS_LINE] = ops
-                elif line.name == T.MODULES_LINE:
-                    lines[T.MODULES_LINE] = [
-                        [ev.name, ev.start_ns, ev.duration_ns]
-                        for ev in line.events]
-            out["devices"].append({"name": plane.name, "lines": lines})
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name == T.MARK and out["mark_ns"] is None:
-                        out["mark_ns"] = ev.start_ns
-    out["devices"] = [d for d in out["devices"] if d["lines"]]
+    unnamed = 0
+
+    def label(name):
+        nonlocal unnamed
+        scope = event_scope(name, hlo)
+        if scope is None:
+            unnamed += 1
+            return OTHER
+        return scope
+
+    out = T.load(trace_dir, label)
+    out["unnamed"] = unnamed
     return out
 
 
@@ -214,24 +192,3 @@ def host_gap_ms(spans, window) -> Optional[float]:
     if not steps:
         return None
     return 1e3 * ((w1 - w0) - covered) / steps
-
-
-# ---------------------------------------------------------------------------
-# the LoRA work of a decode step
-# ---------------------------------------------------------------------------
-
-
-def lora_cost(cfg: dict, rows: Iterable[Tuple[str, int, int]]
-              ) -> Tuple[int, int]:
-    """(operations, bytes) of the LoRA deltas of one decode step over
-    ``rows`` of (adapter id, rank, context): each row at its adapter's
-    true rank, and each distinct adapter's A and B read once at that
-    rank."""
-    rows = list(rows)
-    per_rank = cfg["n_layers"] * sum(sum(target_dims(cfg, t))
-                                     for t in cfg["lora_targets"])
-    flops = sum(2 * r * per_rank for _, r, _ in rows)
-    adapters = {a: r for a, r, _ in rows}
-    nbytes = sum(r * per_rank for r in adapters.values()) \
-        * DTYPE_BYTES[cfg["precision"]["lora_banks"]]
-    return flops, nbytes

@@ -7,9 +7,10 @@ import pytest
 
 from chipbench import flops as F
 from chipbench import generator, trace
-from chipbench.harness import ROOT, percentile
+from chipbench.harness import ROOT, architecture, percentile
 
 CONFIGS = ROOT / "chipbench" / "configs"
+dense = architecture(ROOT, "dense")
 
 
 def _cfg(name):
@@ -35,11 +36,11 @@ def test_counts_match_hand_counts(name):
     cfg = _cfg(name)
     mm, lora, kv = HAND[name]
     V = cfg["vocab_size"]
-    assert F.block_matmul_params(cfg) == mm
-    assert F.adapter_params(cfg, 16) == 24 * 16 * lora
-    assert F.kv_bytes_per_token(cfg) == kv
+    assert dense.block_matmul_params(cfg) == mm
+    assert F.adapter_params(dense.target_dims, cfg, 16) == 24 * 16 * lora
+    assert dense.kv_bytes_per_token(cfg) == kv
     # one decode row of rank 8 whose new token is the 100th in context
-    flops, nbytes = F.decode_cost(cfg, [("a", 8, 100)])
+    flops, nbytes = dense.decode_cost(cfg, [("a", 8, 100)])
     assert flops == (2 * 24 * (mm + 8 * lora) + 2 * 24 * 2 * 100 * 2048
                      + 2 * 2048 * V)
     bias = (4096 if name == "internlm2-1.8b" else 2048 * 3) \
@@ -48,17 +49,19 @@ def test_counts_match_hand_counts(name):
     assert nbytes == (weights + 4 * 2048 + 4 * 24 * 8 * lora + 100 * kv)
     # a prefill of 3 tokens: causal attention over 1+2+3 keys, last
     # position's logits only
-    pf = F.prefill_flops(cfg, [("a", 8, 3)])
+    pf = dense.prefill_flops(cfg, [("a", 8, 3)])
     assert pf == (2 * 24 * 3 * (mm + 8 * lora) + 2 * 24 * 2 * 2048 * 6
                   + 2 * 2048 * V)
 
 
 def test_published_sizes():
     # the sizes the configuration files quote
-    assert F.weight_bytes(_cfg("internlm2-1.8b")) == pytest.approx(
+    assert dense.weight_bytes(_cfg("internlm2-1.8b")) == pytest.approx(
         6.80e9, rel=0.01)
-    assert F.adapter_params(_cfg("internlm2-1.8b"), 1) * 4 == 1376256
-    assert F.adapter_params(_cfg("stablelm-1.6b"), 1) * 4 == 1572864
+    assert F.adapter_params(dense.target_dims, _cfg("internlm2-1.8b"),
+                            1) * 4 == 1376256
+    assert F.adapter_params(dense.target_dims, _cfg("stablelm-1.6b"),
+                            1) * 4 == 1572864
 
 
 def test_roofline_bound():

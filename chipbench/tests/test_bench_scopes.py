@@ -10,8 +10,10 @@ import pytest
 from chipbench import flops as F
 from chipbench import scopes as S
 from chipbench import trace
-from chipbench.harness import ROOT
+from chipbench.harness import ROOT, architecture
 from repro.obs import Span, Tracer
+
+dense = architecture(ROOT, "dense")
 
 
 def _span(name, start, end, cat="step", track="server:0"):
@@ -19,14 +21,17 @@ def _span(name, start, end, cat="step", track="server:0"):
 
 
 def test_scope_of_takes_the_innermost_name():
+    names = dense.SCOPES
     assert S.scope_of("jit(_decode)/while/body/proj/lora/bsd,bdr->bsr/"
-                      "dot_general") == "lora"
-    assert S.scope_of("jit(_decode)/while/body/attention/add") == \
-        "attention"
-    assert S.scope_of("jit(_decode)/lm_head/dot_general") == "lm_head"
-    assert S.scope_of("jit(_decode)/while/body/dynamic_slice") == "other"
+                      "dot_general", names) == "lora"
+    assert S.scope_of("jit(_decode)/while/body/attention/add",
+                      names) == "attention"
+    assert S.scope_of("jit(_decode)/lm_head/dot_general", names) == \
+        "lm_head"
+    assert S.scope_of("jit(_decode)/while/body/dynamic_slice", names) == \
+        "other"
     # a name is a whole path component, not a substring
-    assert S.scope_of("jit(_decode)/projection/mlps") == "other"
+    assert S.scope_of("jit(_decode)/projection/mlps", names) == "other"
 
 
 def test_hlo_scopes_reads_every_computation():
@@ -43,7 +48,7 @@ def test_hlo_scopes_reads_every_computation():
         '  ROOT %dot.9 = f32[4]{0} dot(%x, %x), metadata={op_name='
         '"jit(_decode)/lm_head/dot_general"}',
         "}"])
-    hlo = S.hlo_scopes(text)
+    hlo = S.hlo_scopes(text, dense.SCOPES)
     assert hlo == {"fusion.7": "mlp", "dot.3": "lora", "dot.9": "lm_head"}
     # a v5e op event is named by its instruction's HLO text
     assert S.event_scope("%dot.3 = f32[4]{0:T(128)} dot(f32[4] %a, "
@@ -139,7 +144,7 @@ def test_lora_cost_matches_hand_counts():
     # A+B per unit rank per layer: q and o 2048+2048, k and v 2048+1024
     per = (2048 + 2048) + 2 * (2048 + 1024) + (2048 + 2048)
     rows = [("a", 8, 100), ("b", 16, 50), ("a", 8, 20)]
-    flops, nbytes = S.lora_cost(cfg, rows)
+    (flops, nbytes), = dense.scope_cost(cfg, rows).values()
     assert flops == 2 * 24 * per * (8 + 16 + 8)
     assert nbytes == 4 * 24 * per * (8 + 16)
     # a few rows' LoRA work is bound by its bytes

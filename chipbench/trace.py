@@ -24,8 +24,10 @@ def module_name(event_name: str) -> str:
     return event_name.split("(", 1)[0].strip()
 
 
-def load(trace_dir: str) -> dict:
-    """The kept form of the newest trace under ``trace_dir``."""
+def load(trace_dir: str, label=None) -> dict:
+    """The kept form of the newest trace under ``trace_dir``. Each op
+    keeps ``label(event name)`` as its name, or none: busy time needs
+    only the intervals, and the HLO text that names an op is long."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -37,11 +39,10 @@ def load(trace_dir: str) -> dict:
         if plane.name.startswith("/device:"):
             lines = {}
             for line in plane.lines:
-                # ops keep no name (HLO text, long): busy time needs
-                # only their intervals
                 if line.name == OPS_LINE:
-                    lines[OPS_LINE] = [["", ev.start_ns, ev.duration_ns]
-                                       for ev in line.events]
+                    lines[OPS_LINE] = [
+                        [label(ev.name) if label else "", ev.start_ns,
+                         ev.duration_ns] for ev in line.events]
                 elif line.name == MODULES_LINE:
                     lines[MODULES_LINE] = [
                         [ev.name, ev.start_ns, ev.duration_ns]
