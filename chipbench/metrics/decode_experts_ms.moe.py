@@ -1,0 +1,7 @@
+"""Device ms of the decode program's ``experts`` scope per traced
+decode step."""
+from chipbench.metrics._common import scope_ms
+
+
+def read(run):
+    return scope_ms(run, "experts")

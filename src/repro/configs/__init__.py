@@ -3,7 +3,7 @@ from __future__ import annotations
 
 from .base import (INPUT_SHAPES, LONG_CONTEXT_WINDOW, EncoderConfig,
                    InputShape, LoRAConfig, MLAConfig, ModelConfig, MoEConfig,
-                   SSMConfig)
+                   RopeScaling, SSMConfig)
 
 _REGISTRY = {}
 
@@ -47,6 +47,7 @@ def get_smoke_config(arch_id: str) -> ModelConfig:
 
 __all__ = [
     "ModelConfig", "MoEConfig", "MLAConfig", "SSMConfig", "EncoderConfig",
-    "LoRAConfig", "InputShape", "INPUT_SHAPES", "LONG_CONTEXT_WINDOW",
+    "LoRAConfig", "RopeScaling", "InputShape", "INPUT_SHAPES",
+    "LONG_CONTEXT_WINDOW",
     "ARCH_IDS", "ASSIGNED_ARCH_IDS", "get_config", "get_smoke_config",
 ]

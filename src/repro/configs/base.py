@@ -13,10 +13,34 @@ from typing import Optional, Tuple
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int                 # routed experts
+    n_experts: int                 # routed experts (the router's width)
     top_k: int
     d_ff_expert: int
     n_shared_experts: int = 0      # always-on shared experts (DeepSeek style)
+    # the experts this chip holds, ids first_held .. first_held+n_held-1:
+    # the router still scores all n_experts, and only pairs routed to a
+    # held expert contribute (expert parallelism without the exchange);
+    # 0, like n_experts, holds the whole layer
+    n_held: int = 0
+    first_held: int = 0
+    norm_topk: bool = True         # renormalise the top-k gate weights
+    routed_scale: float = 1.0      # routed output multiplier
+
+    @property
+    def holds_share(self) -> bool:
+        """Whether this chip holds a part of the experts, and not all."""
+        return 0 < self.n_held < self.n_experts
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -77,6 +101,10 @@ class ModelConfig:
     encoder: Optional[EncoderConfig] = None
     n_frontend_tokens: int = 0     # vlm: number of stub patch embeddings
     sliding_window: int = 0        # 0 = full attention; >0 = ring-buffer window
+    # leading dense layers (a SwiGLU of dense_d_ff) before the MoE stack
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    rope_scaling: Optional[RopeScaling] = None
     lora: LoRAConfig = dataclasses.field(default_factory=LoRAConfig)
     source: str = ""               # citation for the exact numbers
 
@@ -121,6 +149,8 @@ class ModelConfig:
                 + d * e.n_experts
         else:
             ffp = 3 * d * ff
+        # leading dense layers: a SwiGLU in place of the MoE layer
+        dense_ff = self.n_dense_layers * (3 * d * self.dense_d_ff - ffp)
         ssmp = 0
         if self.ssm is not None:
             if self.ssm.kind == "mamba2":
@@ -137,7 +167,7 @@ class ModelConfig:
         elif self.ssm is not None and self.ssm.kind == "rwkv6":
             blocks = self.n_layers * ssmp
         else:
-            blocks = n_attn * attn + n_ssm * ssmp + n_ff * ffp
+            blocks = n_attn * attn + n_ssm * ssmp + n_ff * ffp + dense_ff
         if self.cross_attn_every and self.n_heads:
             blocks += (self.n_layers // self.cross_attn_every) * attn
         if self.encoder is not None:

@@ -18,11 +18,21 @@ as XLA's convert does) and accumulates ``dot(..., f32)`` into a VMEM
 scratch: the arithmetic of a default-precision f32 dot on the TPU,
 bf16 operands and f32 accumulation, without the copy.
 
+An expert stack ``(L, E, K, N)`` is read the same way, every expert of
+layer ``layer`` in one call: the grid gains a leading expert axis and
+gives ``(E, M, N)``, from one x shared by every expert ``(M, K)`` or one
+per expert ``(E, M, K)``.
+
 VMEM per grid step (fp32 storage): the weight tile is the only large
-block, ``tk * tn * 4 <= TILE_BYTES``, double-buffered; x, the output
-block and the accumulator are ``M`` rows wide.
+block, double-buffered; x, the output block and the accumulator are
+``M`` rows wide. A tile is at most ``TILE_BYTES`` where both dims are
+multiples of the 128-wide lane; a dim that is not (576, 10944) is
+taken whole, the other as narrow as one lane, and the call's VMEM
+limit is raised to what its blocks need.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -52,14 +62,26 @@ def pick_tile(dim: int, cap: int) -> int:
 
 def stream_tiles(K: int, N: int, itemsize: int = 4):
     """(tk, tn) for a (K, N) weight: the output tile as wide as
-    ``MAX_TILE_N`` allows, then as many rows as ``TILE_BYTES`` holds."""
+    ``MAX_TILE_N`` allows, then as many rows as ``TILE_BYTES`` holds. A
+    K that is no multiple of the lane is taken whole (x's block spans
+    K), with as many output columns as ``TILE_BYTES`` then holds."""
+    if K % LANE:
+        return K, pick_tile(N, max(LANE, TILE_BYTES // (K * itemsize)))
     tn = pick_tile(N, MAX_TILE_N)
     tk = pick_tile(K, max(LANE, TILE_BYTES // (tn * itemsize)))
     return tk, tn
 
 
-def _stream_kernel(layer_ref, x_ref, w_ref, o_ref, acc_ref):
-    k = pl.program_id(1)
+def vmem_bytes(M: int, tk: int, tn: int, itemsize: int = 4) -> int:
+    """VMEM a grid step holds: the weight, x and output blocks double
+    buffered, and the f32 accumulator."""
+    return 2 * (tk * tn + M * tk + M * tn) * itemsize + M * tn * 4
+
+
+def _stream_kernel(layer_ref, x_ref, w_ref, o_ref, acc_ref, k_axis=1):
+    """One (n, k) step: accumulate x tile @ weight tile; ``k_axis`` is
+    the grid axis over K (the last)."""
+    k = pl.program_id(k_axis)
 
     @pl.when(k == 0)
     def _():
@@ -69,20 +91,35 @@ def _stream_kernel(layer_ref, x_ref, w_ref, o_ref, acc_ref):
                             w_ref[...].astype(jnp.bfloat16),
                             preferred_element_type=jnp.float32)
 
-    @pl.when(k == pl.num_programs(1) - 1)
+    @pl.when(k == pl.num_programs(k_axis) - 1)
     def _():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+# scoped VMEM a kernel may take without asking (v5e)
+VMEM_DEFAULT = 16 * 2**20
+
+
+def _compiler_params(semantics, need: int):
+    limit = None if need <= VMEM_DEFAULT * 3 // 4 else need + 4 * 2**20
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
 
 
 def stream_matmul(x, w_stack, layer, *, interpret=None):
     """``x @ w_stack[layer]`` with bfloat16 operands and f32
     accumulation, reading layer ``layer`` of the stack once and in
     place. x: (M, K); w_stack: (L, K, N); layer: int32 scalar (may be
-    traced). Returns (M, N) in x's dtype."""
+    traced). Returns (M, N) in x's dtype. For an expert stack
+    ``(L, E, K, N)`` and x (M, K) or (E, M, K), every expert's product:
+    (E, M, N)."""
+    if w_stack.ndim == 4:
+        return _stream_experts(x, w_stack, layer, interpret)
     interpret = resolve_interpret(interpret)
     M, K = x.shape
     _, _, N = w_stack.shape
-    tk, tn = stream_tiles(K, N, w_stack.dtype.itemsize)
+    item = w_stack.dtype.itemsize
+    tk, tn = stream_tiles(K, N, item)
     return pl.pallas_call(
         _stream_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -96,7 +133,39 @@ def stream_matmul(x, w_stack, layer, *, interpret=None):
             scratch_shapes=[pltpu.VMEM((M, tn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "arbitrary"),
+                                         vmem_bytes(M, tk, tn, item)),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), x, w_stack)
+
+
+def _stream_experts(x, w_stack, layer, interpret):
+    interpret = resolve_interpret(interpret)
+    _, E, K, N = w_stack.shape
+    M = x.shape[-2]
+    item = w_stack.dtype.itemsize
+    tk, tn = stream_tiles(K, N, item)
+    if x.ndim == 2:             # one x for every expert
+        x_spec = pl.BlockSpec((M, tk), lambda e, n, k, l: (0, k))
+    else:
+        x_spec = pl.BlockSpec((None, M, tk), lambda e, n, k, l: (e, 0, k))
+    return pl.pallas_call(
+        functools.partial(_stream_kernel, k_axis=2),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E, N // tn, K // tk),
+            in_specs=[
+                x_spec,
+                pl.BlockSpec((None, None, tk, tn),
+                             lambda e, n, k, l: (l[0], e, k, n)),
+            ],
+            out_specs=pl.BlockSpec((None, M, tn),
+                                   lambda e, n, k, l: (e, 0, n)),
+            scratch_shapes=[pltpu.VMEM((M, tn), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((E, M, N), x.dtype),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            vmem_bytes(M, tk, tn, item)),
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), x, w_stack)

@@ -42,15 +42,19 @@ def _target_out_dim(cfg, target: str) -> int:
     if cfg.ssm is not None and cfg.ssm.kind == "rwkv6":
         return cfg.d_model
     if cfg.mla is not None:
+        # the release's q_proj, kv_a_proj_with_mqa (latent and rope key),
+        # kv_b_proj (per head [k_nope | v]) and o_proj
         m = cfg.mla
         return {"q": H * (m.qk_nope_head_dim + m.qk_rope_head_dim),
-                "k": m.kv_lora_rank + m.qk_rope_head_dim,
-                "v": m.kv_lora_rank + m.qk_rope_head_dim,
+                "kv_a": m.kv_lora_rank + m.qk_rope_head_dim,
+                "kv_b": H * (m.qk_nope_head_dim + m.v_head_dim),
                 "o": cfg.d_model}[target]
     return {"q": H * hd, "k": Kv * hd, "v": Kv * hd, "o": cfg.d_model}[target]
 
 
 def _target_in_dim(cfg, target: str) -> int:
+    if target == "kv_b":
+        return cfg.mla.kv_lora_rank
     if target != "o":
         return cfg.d_model
     if cfg.ssm is not None and cfg.ssm.kind == "rwkv6":

@@ -23,7 +23,9 @@ so a device trace can tell the LoRA work of a step from the rest.
 padded path with ``idx: (Bt,)`` global adapter rows; a tuple of per-
 bucket slices selects the bucketed path with ``idx: (Bt, 2)`` carrying
 (bucket, local-row) per request — the shape ``LoRABank.lora_idx``
-produces.
+produces. The hook also gives each row's own factors of a target
+(``factors(name)``), for a delta that enters the model other than as
+``x A B`` (MLA's absorbed ``kv_b``).
 """
 from __future__ import annotations
 
@@ -241,6 +243,18 @@ def make_lora_cb(bank_layer, idx, scaling: float = 1.0, *,
                         x, targets, idx, scaling, block_t, interpret)
                 return lora_delta_bucketed(x, targets, idx, scaling)
 
+        def factors_bucketed(name):
+            if any(bk.get(name) is None for bk in bank_layer):
+                return []
+            bucket, local = idx[..., 0], idx[..., 1]
+            out = []
+            for b, bk in enumerate(bank_layer):
+                rows = jnp.where(bucket == b, local, 0)
+                out.append((bk[name]["A"][rows],
+                            bk[name]["B"][rows] * scaling, bucket == b))
+            return out
+
+        cb_bucketed.factors = factors_bucketed
         return cb_bucketed
 
     def cb(name, x):
@@ -253,6 +267,15 @@ def make_lora_cb(bank_layer, idx, scaling: float = 1.0, *,
                                         interpret)
             return lora_delta(x, t["A"], t["B"], idx, scaling)
 
+    def factors(name):
+        """[(A (Bt, in, r), B (Bt, r, out), row mask or None)]: each
+        row's adapter factors of target ``name``, B scaled."""
+        t = bank_layer.get(name)
+        if t is None:
+            return []
+        return [(t["A"][idx], t["B"][idx] * scaling, None)]
+
+    cb.factors = factors
     return cb
 
 

@@ -24,7 +24,8 @@ from jax.sharding import PartitionSpec as P
 
 from .common import (SHARDING_MODE, apply_rope, attend_cache, constrain,
                      constrain_resid, current_axis_env, dense_init,
-                     dense_proj, flash_attention, rmsnorm)
+                     dense_proj, flash_attention, rmsnorm, yarn_freqs,
+                     yarn_mscale)
 
 
 def _zero_lora(name, x):
@@ -224,7 +225,10 @@ def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, window=0,
 
 
 # ---------------------------------------------------------------------------
-# MLA (DeepSeek-V2): compressed KV cache of (c_kv, k_rope).
+# MLA (DeepSeek-V2): compressed KV cache of (c_kv, k_rope). LoRA targets
+# the release's projections: q (q_proj), kv_a (kv_a_proj_with_mqa: the
+# latent and the rope key), kv_b (kv_b_proj: W_UK and W_UV, per head
+# [k_nope | v]) and o.
 # ---------------------------------------------------------------------------
 
 
@@ -246,105 +250,182 @@ def init_mla(cfg, key, dtype=jnp.float32):
     }
 
 
-def _mla_q(cfg, p, x, positions, lora):
+def _mla_rope(cfg, x, positions):
+    """The rope dims rotated as DeepSeek-V2 rotates them: YaRN's
+    frequencies where the configuration scales them, each even dim
+    against the odd dim after it."""
+    m = cfg.mla
+    freqs = None
+    if cfg.rope_scaling is not None:
+        freqs = yarn_freqs(m.qk_rope_head_dim, cfg.rope_theta,
+                           cfg.rope_scaling)
+    return apply_rope(x, positions, cfg.rope_theta, freqs=freqs,
+                      interleaved=True)
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: 1/sqrt(q head dim), times YaRN's mscale squared."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    s = cfg.rope_scaling
+    if s is not None and s.mscale_all_dim:
+        scale *= yarn_mscale(s.factor, s.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_proj(cfg, p, x, positions, lora, proj):
+    """q (B,S,H,qd) with its rope dims rotated, and the new latent c
+    (B,S,kv_lora) after ``kv_a_layernorm`` and rope key kr (B,S,1,rope).
+    LoRA targets ``q`` and ``kv_a`` (the latent and rope key
+    projection)."""
     m = cfg.mla
     B, S, _ = x.shape
     H = cfg.n_heads
     qd = m.qk_nope_head_dim + m.qk_rope_head_dim
-    q = (x @ p["wq"] + lora("q", x)).reshape(B, S, H, qd)
-    qn, qr = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    qr = apply_rope(qr, positions, cfg.rope_theta)
-    return jnp.concatenate([qn, qr], axis=-1)
+    with jax.named_scope("proj"):
+        q = proj("wq", x) + lora("q", x)
+        dkv = proj("w_dkv", x) + lora("kv_a", x)
+    with jax.named_scope("attention"):
+        qn, qr = jnp.split(q.reshape(B, S, H, qd), [m.qk_nope_head_dim],
+                           axis=-1)
+        q = jnp.concatenate([qn, _mla_rope(cfg, qr, positions)], axis=-1)
+        c, kr = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
+        c = rmsnorm(c, p["ln_kv"], cfg.rmsnorm_eps)
+        kr = _mla_rope(cfg, kr.reshape(B, S, 1, m.qk_rope_head_dim),
+                       positions)
+    return q, c, kr
 
 
-def _mla_ckv(cfg, p, x, positions, lora):
-    m = cfg.mla
-    B, S, _ = x.shape
-    dkv = x @ p["w_dkv"] + lora("k", x)
-    c, kr = jnp.split(dkv, [m.kv_lora_rank], axis=-1)
-    c = rmsnorm(c, p["ln_kv"], cfg.rmsnorm_eps)
-    kr = apply_rope(kr.reshape(B, S, 1, m.qk_rope_head_dim), positions,
-                    cfg.rope_theta)
-    return c, kr
-
-
-def _mla_expand(cfg, p, c):
-    """Expand compressed cache into per-head K_nope and V."""
+def _mla_expand(cfg, p, c, lora):
+    """Expand a latent (B,S,kv_lora) into per-head K_nope and V; the
+    ``kv_b`` LoRA delta is laid out per head as [k_nope | v]."""
     m = cfg.mla
     B, S, _ = c.shape
     H = cfg.n_heads
     kn = (c @ p["w_uk"]).reshape(B, S, H, m.qk_nope_head_dim)
     v = (c @ p["w_uv"]).reshape(B, S, H, m.v_head_dim)
+    delta = lora("kv_b", c)
+    if not isinstance(delta, float):
+        dk, dv = jnp.split(
+            delta.reshape(B, S, H, m.qk_nope_head_dim + m.v_head_dim),
+            [m.qk_nope_head_dim], axis=-1)
+        kn, v = kn + dk, v + dv
     return kn, v
+
+
+def _lora_factors(lora, name):
+    """The per-row (A, B, row mask) factors of target ``name`` that a
+    LoRA hook holds (``lora/batched.py``); none without a bank."""
+    factors = getattr(lora, "factors", None)
+    return factors(name) if factors is not None else []
 
 
 def mla_full(cfg, p, x, positions, *, causal=True, window=0, lora=None):
     lora = lora or _zero_lora
     m = cfg.mla
     B, S, _ = x.shape
-    H = cfg.n_heads
-    q = _mla_q(cfg, p, x, positions, lora)
-    c, kr = _mla_ckv(cfg, p, x, positions, lora)
-    kn, v = _mla_expand(cfg, p, c)
-    # score = q_nope.k_nope + q_rope.k_rope computed as two einsums — the
-    # shared rope key never gets broadcast+concat'd into a per-head K
-    # (§Perf iter 2d: that concat reshards scores inside the kv scan)
-    qn, qr = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    scale = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
-    o = run_flash(qn, kn, v, causal=causal, q_positions=positions,
-                  k_positions=positions, window=window, scale=scale,
-                  extra_qk=(qr, kr[:, :, 0, :]))
-    o = o.reshape(B, S, -1)
-    out = o @ p["wo"] + lora("o", o)
+    q, c, kr = _mla_proj(cfg, p, x, positions, lora, dense_proj(p))
+    with jax.named_scope("attention"):
+        kn, v = _mla_expand(cfg, p, c, lora)
+        # score = q_nope.k_nope + q_rope.k_rope computed as two einsums —
+        # the shared rope key never gets broadcast+concat'd into a
+        # per-head K (§Perf iter 2d: that concat reshards scores inside
+        # the kv scan)
+        qn, qr = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+        o = run_flash(qn, kn, v, causal=causal, q_positions=positions,
+                      k_positions=positions, window=window,
+                      scale=mla_scale(cfg), extra_qk=(qr, kr[:, :, 0, :]))
+        o = o.reshape(B, S, -1)
+    with jax.named_scope("proj"):
+        out = o @ p["wo"] + lora("o", o)
     return constrain_resid(out), (c, kr[:, :, 0, :])
 
 
+def _absorbed(cfg, p, qn, qr, c_cache, kr_cache, valid, lora):
+    """Attention of one query per row over the latent cache, never
+    expanding it: score = (q_nope W_UK^T) . c + q_rope . k_rope and
+    out = (p . c) W_UV per head. A ``kv_b`` adapter adds, per query,
+    (q_nope B_k^T) A^T to the absorbed query and (o_lat A) B_v to the
+    output: two low-rank terms per head, nothing per cached token.
+
+    Every product is fp32 at HIGHEST precision: a few rows against
+    per-layer weights and the cache, so the passes cost nothing beside
+    the bytes, and the fp32 operands are read in place (a default-
+    precision dot would make bf16 copies of W_UK, W_UV and the bank's
+    kv_b A, hoisted out of the decode loop as whole stacks)."""
+    m = cfg.mla
+    B, H = qn.shape[:2]
+    R, n, dv = m.kv_lora_rank, m.qk_nope_head_dim, m.v_head_dim
+    f32 = jnp.float32
+
+    def einsum(spec, a, b):
+        return jnp.einsum(spec, a.astype(f32), b.astype(f32),
+                          precision=jax.lax.Precision.HIGHEST)
+
+    factors = []
+    for A, Bm, mask in _lora_factors(lora, "kv_b"):
+        with jax.named_scope("lora"):
+            Bm = Bm.reshape(B, -1, H, n + dv)
+            factors.append((A, Bm[..., :n], Bm[..., n:], mask))
+    q_lat = einsum("bhn,rhn->bhr", qn, p["w_uk"].reshape(R, H, n))
+    for A, Bk, _, mask in factors:
+        with jax.named_scope("lora"):
+            t = einsum("bhr,bkhr->bhk", qn, Bk)
+            q_lat = q_lat + _masked(einsum("bhk,bck->bhc", t, A), mask)
+    s = einsum("bhr,bsr->bhs", q_lat, c_cache)
+    s += einsum("bhr,bsr->bhs", qr, kr_cache)
+    s = jnp.where(valid[:, None, :], s * mla_scale(cfg), -1e30)
+    pr = jax.nn.softmax(s, axis=-1)
+    o_lat = einsum("bhs,bsr->bhr", pr, c_cache)
+    o = einsum("bhr,rhv->bhv", o_lat, p["w_uv"].reshape(R, H, dv))
+    for A, _, Bv, mask in factors:
+        with jax.named_scope("lora"):
+            u = einsum("bhc,bck->bhk", o_lat, A)
+            o = o + _masked(einsum("bhk,bkhv->bhv", u, Bv), mask)
+    return o
+
+
+def _masked(y, mask):
+    return y if mask is None else jnp.where(mask[:, None, None], y, 0.0)
+
+
 def mla_decode(cfg, p, x, c_cache, kr_cache, pos, *, window=0, lora=None,
-               absorbed: bool = False):
+               absorbed: bool = False, proj: Optional[Callable] = None):
     """c_cache: (B,S,kv_lora); kr_cache: (B,S,rope_dim).
 
     ``absorbed=False`` is the paper-faithful naive path: expand the full
-    cached latent into per-head K/V each step. ``absorbed=True`` applies the
-    W_UK/W_UV absorption identity (beyond-paper optimization, §Perf):
-    score = (q_nope @ W_UK^T) · c  — never materializes per-head K/V.
-    """
+    cached latent into per-head K/V each step. ``absorbed=True`` applies
+    the W_UK/W_UV absorption identity (``_absorbed``): the latent cache
+    is read once and never expanded. ``proj`` computes the q, latent and
+    output projections (the decode step's weight stream)."""
     lora = lora or _zero_lora
+    proj = proj or dense_proj(p)
     m = cfg.mla
     B = x.shape[0]
     S = c_cache.shape[1]
     H = cfg.n_heads
-    q = _mla_q(cfg, p, x, pos[:, None], lora)          # (B,1,H,qd)
-    c_t, kr_t = _mla_ckv(cfg, p, x, pos[:, None], lora)
-    write_idx = pos % S if window else pos
-    bidx = jnp.arange(B)
-    c_cache = c_cache.at[bidx, write_idx].set(c_t[:, 0])
-    kr_cache = kr_cache.at[bidx, write_idx].set(kr_t[:, 0, 0])
-    slots = jnp.arange(S)[None, :]
-    valid = slots <= jnp.minimum(pos, S - 1)[:, None]
-    qn, qr = jnp.split(q[:, 0], [m.qk_nope_head_dim], axis=-1)  # (B,H,*)
-    scale = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
-    if absorbed:
-        wuk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
-        q_lat = jnp.einsum("bhn,rhn->bhr", qn.astype(jnp.float32),
-                           wuk.astype(jnp.float32))
-        s = jnp.einsum("bhr,bsr->bhs", q_lat,
-                       c_cache.astype(jnp.float32))
-        s += jnp.einsum("bhr,bsr->bhs", qr.astype(jnp.float32),
-                        kr_cache.astype(jnp.float32))
-        s = jnp.where(valid[:, None, :], s * scale, -1e30)
-        pr = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("bhs,bsr->bhr", pr, c_cache.astype(jnp.float32))
-        wuv = p["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
-        o = jnp.einsum("bhr,rhv->bhv", o_lat, wuv.astype(jnp.float32))
-        o = o.reshape(B, 1, H * m.v_head_dim).astype(x.dtype)
-    else:
-        kn, v = _mla_expand(cfg, p, c_cache)           # (B,S,H,*)
-        k = jnp.concatenate(
-            [kn, jnp.broadcast_to(kr_cache[:, :, None, :],
-                                  (B, S, H, m.qk_rope_head_dim))], axis=-1)
-        o = attend_cache(q, k, v, valid, scale=scale)
-        o = o.reshape(B, 1, -1)
-    out = o @ p["wo"] + lora("o", o)
+    q, c_t, kr_t = _mla_proj(cfg, p, x, pos[:, None], lora, proj)
+    with jax.named_scope("attention"):
+        write_idx = pos % S if window else pos
+        bidx = jnp.arange(B)
+        c_cache = c_cache.at[bidx, write_idx].set(c_t[:, 0])
+        kr_cache = kr_cache.at[bidx, write_idx].set(kr_t[:, 0, 0])
+        slots = jnp.arange(S)[None, :]
+        valid = slots <= jnp.minimum(pos, S - 1)[:, None]
+        if absorbed:
+            qn, qr = jnp.split(q[:, 0], [m.qk_nope_head_dim], axis=-1)
+            o = _absorbed(cfg, p, qn, qr, c_cache, kr_cache, valid, lora)
+            o = o.reshape(B, 1, H * m.v_head_dim).astype(x.dtype)
+        else:
+            kn, v = _mla_expand(cfg, p, c_cache, lora)     # (B,S,H,*)
+            k = jnp.concatenate(
+                [kn, jnp.broadcast_to(kr_cache[:, :, None, :],
+                                      (B, S, H, m.qk_rope_head_dim))],
+                axis=-1)
+            o = attend_cache(q, k, v, valid, scale=mla_scale(cfg))
+            o = o.reshape(B, 1, -1)
+    with jax.named_scope("proj"):
+        out = proj("wo", o) + lora("o", o)
     return constrain_resid(out), (c_cache, kr_cache)
 
 

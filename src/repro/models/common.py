@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 import os
 import threading
 from typing import Optional, Tuple
@@ -202,14 +203,52 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
                             / head_dim))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_correction_range(dim: int, theta: float, scaling):
+    """(low, high) rotary dims of YaRN's blend: below ``low`` the
+    frequencies are kept, above ``high`` divided by the factor."""
+    def corr(rotations):
+        return dim * math.log(scaling.original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr(scaling.beta_slow)), dim - 1)
+    return low, high
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, scaling) -> jax.Array:
+    """YaRN's inverse frequencies: each of the ``dim/2`` pairs blends
+    the plain frequency (weight ``mask``) with the plain one over the
+    factor, the mask ramping from 1 to 0 across the correction range."""
+    plain = rope_freqs(dim, theta)
+    low, high = yarn_correction_range(dim, theta, scaling)
+    ramp = (jnp.arange(dim // 2, dtype=jnp.float32) - low) \
+        / max(high - low, 0.001)
+    mask = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return plain / scaling.factor * (1.0 - mask) + plain * mask
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float, *,
+               freqs: Optional[jax.Array] = None,
+               interleaved: bool = False) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+    ``freqs`` replaces the plain inverse frequencies (YaRN). The halves
+    of each head rotate against each other ("rotate half"), or, with
+    ``interleaved``, each even dim against the odd dim after it, the
+    result laid out evens first (DeepSeek-V2's de-interleave)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                      # (hd/2,)
+    freqs = rope_freqs(hd, theta) if freqs is None else freqs  # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (...,S,hd/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 

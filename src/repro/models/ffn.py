@@ -48,16 +48,19 @@ def swiglu(p, x, shared: bool = False, proj=None):
 
 
 def init_moe(cfg, key, dtype=jnp.float32):
+    """The router over all ``n_experts``, and the experts this chip
+    holds (``n_held`` where the configuration gives a share)."""
     e = cfg.moe
     d = cfg.d_model
+    E = e.n_held or e.n_experts
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (d, e.n_experts), dtype=jnp.float32),
-        "we1": dense_init(ks[1], (e.n_experts, d, e.d_ff_expert),
+        "we1": dense_init(ks[1], (E, d, e.d_ff_expert),
                           fan_in=d, dtype=dtype),
-        "we3": dense_init(ks[2], (e.n_experts, d, e.d_ff_expert),
+        "we3": dense_init(ks[2], (E, d, e.d_ff_expert),
                           fan_in=d, dtype=dtype),
-        "we2": dense_init(ks[3], (e.n_experts, e.d_ff_expert, d),
+        "we2": dense_init(ks[3], (E, e.d_ff_expert, d),
                           fan_in=e.d_ff_expert, dtype=dtype),
     }
     if e.n_shared_experts:
@@ -66,16 +69,28 @@ def init_moe(cfg, key, dtype=jnp.float32):
     return p
 
 
+def _gate(e, probs):
+    """Greedy top-k of the gate probabilities: (weights, expert ids),
+    the weights renormalised over the k where ``norm_topk`` says so,
+    then scaled by ``routed_scale``."""
+    topw, topi = jax.lax.top_k(probs, e.top_k)
+    if e.norm_topk:
+        topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    if e.routed_scale != 1.0:
+        topw = topw * e.routed_scale
+    return topw, topi
+
+
 def _route_pack(cfg, router, xf, capacity_factor, exact_small=True):
     """Shared routing: top-k, aux loss, sort-pack into (E, C, d).
     Returns (xg, tok_s, w_s, keep, dest, C, aux)."""
     e = cfg.moe
     N, d = xf.shape
     K, E = e.top_k, e.n_experts
-    logits = xf.astype(jnp.float32) @ router
+    logits = jnp.dot(xf.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, K)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    topw, topi = _gate(e, probs)
     me = jnp.mean(probs, axis=0)
     ce = jnp.mean(jax.nn.one_hot(topi[:, 0], E, dtype=jnp.float32), axis=0)
     aux = E * jnp.sum(me * ce)
@@ -160,17 +175,68 @@ def _ep_applicable(cfg, x) -> bool:
         return False
     n = env.mesh.shape[env.model]
     return (cfg.moe.n_experts % n == 0 and x.shape[1] % n == 0
-            and x.shape[1] > 1)
+            and x.shape[1] > 1
+            and not cfg.moe.holds_share)
+
+
+def held_moe_ffn(cfg, p, x, proj=None):
+    """The expert layer of one chip's share: x (B,S,d) -> (y, (pairs,
+    touched)).
+
+    The router keeps its published width: a softmax over all
+    ``n_experts`` gate logits, in fp32 at HIGHEST precision, and the
+    greedy top-k over all of them. Only the (token, expert) pairs whose
+    expert is held here (ids ``first_held`` .. ``first_held + n_held -
+    1``) contribute, each weighted by its gate weight; the shared
+    experts run for every token. Nothing stands in for the absent
+    experts or their exchange. Each held expert computes every token,
+    and the combine gives the tokens not routed to it weight 0, so no
+    pair is ever dropped, at any N (C = N). ``pairs`` counts the routed
+    pairs that land on held experts and ``touched`` the held experts
+    with at least one (int32 scalars). ``proj(name, x)`` computes the
+    expert and shared projections (the decode step's weight stream;
+    ``we*`` take (N, d) or (E, N, f) and give (E, N, ·))."""
+    e = cfg.moe
+    proj = proj or dense_proj(p)
+    B, S, d = x.shape
+    N = B * S
+    E = e.n_held
+    xf = x.reshape(N, d)
+    with jax.named_scope("router"):
+        logits = jnp.dot(xf.astype(jnp.float32),
+                         p["router"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        topw, topi = _gate(e, jax.nn.softmax(logits, axis=-1))
+        onehot = (topi - e.first_held)[..., None] == jnp.arange(E)
+        w = jnp.sum(jnp.where(onehot, topw[..., None], 0.0), axis=1)
+        pairs = jnp.sum(onehot, dtype=jnp.int32)
+        touched = jnp.sum(jnp.any(onehot, axis=(0, 1)), dtype=jnp.int32)
+    with jax.named_scope("experts"):
+        h = jax.nn.silu(proj("we1", xf)) * proj("we3", xf)   # (E,N,f)
+        y = proj("we2", h)                                     # (E,N,d)
+    with jax.named_scope("router"):
+        out = jnp.sum(y.astype(jnp.float32) * w.T[:, :, None], axis=0)
+        out = out.astype(x.dtype).reshape(B, S, d)
+    if e.n_shared_experts:
+        out = out + swiglu(p, x, shared=True, proj=proj)
+    return constrain_resid(out), (pairs, touched)
 
 
 def moe_ffn(cfg, p, x, capacity_factor: float = 1.25):
     """Sort-based ragged MoE. x: (B,S,d) -> (y, aux_loss).
 
     Dispatches to the shard_map expert-parallel path when the ambient
-    mesh allows it (see module docstring), else the GSPMD scatter path.
+    mesh allows it (see module docstring), to ``held_moe_ffn`` where the
+    configuration gives this chip a part of the experts, else the
+    GSPMD scatter path. A part has no load-balance term (aux 0): the
+    balance is the whole layer's, across the chips that share it, so a
+    model is trained with all its experts held.
     """
     if cfg.moe is not None and _ep_applicable(cfg, x):
         return moe_ffn_ep(cfg, p, x, capacity_factor)
+    if cfg.moe.holds_share:
+        y, _ = held_moe_ffn(cfg, p, x)
+        return y, jnp.zeros((), jnp.float32)
     e = cfg.moe
     B, S, d = x.shape
     N = B * S
@@ -178,10 +244,11 @@ def moe_ffn(cfg, p, x, capacity_factor: float = 1.25):
     E = e.n_experts
     xf = x.reshape(N, d)
 
-    logits = xf.astype(jnp.float32) @ p["router"]          # (N,E)
+    logits = jnp.dot(xf.astype(jnp.float32),
+                     p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)  # (N,E)
     probs = jax.nn.softmax(logits, axis=-1)
-    topw, topi = jax.lax.top_k(probs, K)                   # (N,K)
-    topw = topw / jnp.maximum(topw.sum(-1, keepdims=True), 1e-9)
+    topw, topi = _gate(e, probs)                           # (N,K)
 
     # aux load-balance loss (Switch-style)
     me = jnp.mean(probs, axis=0)                            # (E,)

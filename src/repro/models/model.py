@@ -10,6 +10,9 @@ Cache dict keys (present depending on family):
   pos    : (B,) int32 — tokens currently in the cache per row
   k, v   : (L_attn, B, S, Kv, hd) self-attention KV
   c, kr  : (L, B, S, kv_lora) / (L, B, S, rope) MLA compressed cache
+  expert_counts : {"pairs", "touched": (L_moe,), "steps": ()} int32,
+           what the decode steps' held-expert layers routed (no batch
+           axis; the engine's merge keeps its own)
   xk, xv : (L_cross, B, M, Kv, hd) cross-attn KV (computed at prefill)
   ssm    : (L, B, H, hd, N) mamba2 state
   wkv/x_tm/x_cm : RWKV6 state (stacked over layers)
@@ -31,7 +34,7 @@ from .attention import (cross_attend, cross_kv, gqa_decode, gqa_full,
                         mla_full)
 from .common import (chunked_cross_entropy, constrain, constrain_resid,
                      current_axis_env, dense_init, rmsnorm)
-from .ffn import init_moe, init_swiglu, moe_ffn, swiglu
+from .ffn import held_moe_ffn, init_moe, init_swiglu, moe_ffn, swiglu
 from .ssm import (init_mamba2, init_rwkv6, mamba2_full, mamba2_state,
                   mamba2_step, rwkv6_channel_mix, rwkv6_state, rwkv6_time_mix,
                   rwkv_dims, mamba_dims)
@@ -45,12 +48,16 @@ def _init_attn(cfg, key, dtype):
     return init_mla(cfg, key, dtype) if cfg.mla else init_gqa(cfg, key, dtype)
 
 
-def _init_dense_block(cfg, key, dtype):
+def _init_dense_block(cfg, key, dtype, leading=False):
+    """One block; a ``leading`` dense layer of a MoE model has a SwiGLU
+    of ``dense_d_ff`` in place of the experts."""
     d = cfg.d_model
     k1, k2 = jax.random.split(key)
     p = {"ln1": jnp.ones((d,), dtype), "ln2": jnp.ones((d,), dtype),
          "attn": _init_attn(cfg, k1, dtype)}
-    if cfg.moe is not None:
+    if leading:
+        p["ffn"] = init_swiglu(d, cfg.dense_d_ff, k2, dtype)
+    elif cfg.moe is not None:
         p["ffn"] = init_moe(cfg, k2, dtype)
     else:
         p["ffn"] = init_swiglu(d, cfg.d_ff, k2, dtype)
@@ -96,8 +103,14 @@ def init_params(cfg, key, dtype=jnp.float32):
 
     fam = cfg.family
     if fam in ("dense", "moe"):
+        nd = cfg.n_dense_layers
+        if nd:
+            kb, kd = jax.random.split(kb)
+            p["dense_blocks"] = _stacked(
+                lambda k: _init_dense_block(cfg, k, dtype, leading=True),
+                nd, kd)
         p["blocks"] = _stacked(lambda k: _init_dense_block(cfg, k, dtype),
-                               cfg.n_layers, kb)
+                               cfg.n_layers - nd, kb)
     elif fam == "vlm":
         n_cross = cfg.n_layers // cfg.cross_attn_every
         n_self = cfg.n_layers - n_cross
@@ -162,7 +175,7 @@ def _dense_block_full(cfg, bp, x, positions, window, lora):
                     positions, window=window, lora=lora)
     x = x + h
     xn = rmsnorm(x, bp["ln2"], cfg.rmsnorm_eps)
-    if cfg.moe is not None:
+    if "router" in bp["ffn"]:
         f, aux = moe_ffn(cfg, bp["ffn"], xn)
     else:
         f, aux = swiglu(bp["ffn"], xn), jnp.zeros((), jnp.float32)
@@ -171,13 +184,15 @@ def _dense_block_full(cfg, bp, x, positions, window, lora):
 
 def _dense_block_decode(cfg, bp, x, kc, vc, pos, window, lora,
                         mla_absorbed=False, proj=None):
-    """``proj``, when given, computes the GQA and SwiGLU projections
-    whose weights ``bp`` then leaves out (see ``decode_step``)."""
+    """``proj``, when given, computes the attention, SwiGLU and expert
+    projections whose weights ``bp`` then leaves out (see
+    ``decode_step``). Returns (x, kc, vc, stats): ``stats`` the held
+    expert layer's (pairs, touched), else None."""
     if cfg.mla:
         h, (kc, vc) = mla_decode(cfg, bp["attn"],
                                  rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
                                  kc, vc, pos, window=window, lora=lora,
-                                 absorbed=mla_absorbed)
+                                 absorbed=mla_absorbed, proj=proj)
     else:
         h, (kc, vc) = gqa_decode(cfg, bp["attn"],
                                  rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
@@ -185,11 +200,14 @@ def _dense_block_decode(cfg, bp, x, kc, vc, pos, window, lora,
                                  proj=proj)
     x = x + h
     xn = rmsnorm(x, bp["ln2"], cfg.rmsnorm_eps)
-    if cfg.moe is not None:
-        f, _ = moe_ffn(cfg, bp["ffn"], xn)
-    else:
+    stats = None
+    if "router" not in bp["ffn"]:
         f = swiglu(bp["ffn"], xn, proj=proj)
-    return x + f, kc, vc
+    elif cfg.moe.holds_share:
+        f, stats = held_moe_ffn(cfg, bp["ffn"], xn, proj=proj)
+    else:
+        f, _ = moe_ffn(cfg, bp["ffn"], xn)
+    return x + f, kc, vc, stats
 
 
 def _cross_block(cfg, bp, x, kc, vc, lora):
@@ -233,18 +251,40 @@ def _run_dense_full(cfg, params, x, positions, *, window, bank, lora_idx,
                     remat, collect, lora_kernel="einsum"):
     has_bank = bank is not None
 
-    def body(carry, inp):
-        x, aux = carry
-        bp, bk = inp if has_bank else (inp, None)
+    def layer(x, aux, bp, bk):
         lora = make_lora_cb(bk, lora_idx, kernel=lora_kernel) \
             if bk is not None else None
         x, kv, a = _dense_block_full(cfg, bp, x, positions, window, lora)
-        return (x, aux + a), (kv if collect else 0)
+        return x, aux + a, kv
 
-    body_fn = jax.checkpoint(body) if remat else body
-    xs = (params["blocks"], bank) if has_bank else params["blocks"]
-    (x, aux), kvs = jax.lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
-                                 xs)
+    if not cfg.n_dense_layers:
+        def body(carry, inp):
+            x, aux = carry
+            bp, bk = inp if has_bank else (inp, None)
+            x, aux, kv = layer(x, aux, bp, bk)
+            return (x, aux), (kv if collect else 0)
+
+        body_fn = jax.checkpoint(body) if remat else body
+        xs = (params["blocks"], bank) if has_bank else params["blocks"]
+        (x, aux), kvs = jax.lax.scan(body_fn,
+                                     (x, jnp.zeros((), jnp.float32)), xs)
+        return x, kvs, aux
+
+    # the leading dense stack, then the MoE stack: the bank spans both,
+    # so each layer takes its slice by index (never a copy of a part)
+    def stack_body(carry, bp):
+        x, aux, i = carry
+        bk = _layer_slice(bank, i) if has_bank else None
+        x, aux, kv = layer(x, aux, bp, bk)
+        return (x, aux, i + 1), (kv if collect else 0)
+
+    body_fn = jax.checkpoint(stack_body) if remat else stack_body
+    carry = (x, jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
+    carry, kv_dense = jax.lax.scan(body_fn, carry, params["dense_blocks"])
+    (x, aux, _), kvs = jax.lax.scan(body_fn, carry, params["blocks"])
+    if collect:
+        kvs = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                           kv_dense, kvs)
     return x, kvs, aux
 
 
@@ -457,6 +497,10 @@ def loss_fn(cfg, params, batch, *, remat=True, aux_coef=0.01):
     return loss + aux_coef * aux
 
 
+# the cache key of the held-expert layers' decode counters
+COUNTERS = "expert_counts"
+
+
 def init_cache(cfg, batch: int, max_len: int, dtype=jnp.float32,
                enc_len: Optional[int] = None):
     """Zeroed cache pytree. max_len should already account for any sliding
@@ -482,6 +526,11 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.float32,
     if cfg.family == "hybrid":
         cache["ssm"] = jnp.zeros((cfg.n_layers,) +
                                  mamba2_state(cfg, batch).shape, dtype)
+    if cfg.moe is not None and cfg.moe.holds_share:
+        n_moe = cfg.n_layers - cfg.n_dense_layers
+        cache[COUNTERS] = {"pairs": jnp.zeros((n_moe,), jnp.int32),
+                           "touched": jnp.zeros((n_moe,), jnp.int32),
+                           "steps": jnp.zeros((), jnp.int32)}
     if cfg.family == "ssm":
         st = rwkv6_state(cfg, batch, dtype)
         cache["wkv"] = jnp.zeros((cfg.n_layers,) + st["wkv"].shape,
@@ -564,19 +613,26 @@ def prefill(cfg, params, tokens, *, frontend=None, bank=None, lora_idx=None,
     return logits, cache
 
 
-# the dense block's matmul weights, which the decode step can stream
-_STREAMED = ("wq", "wk", "wv", "wo", "w1", "w3", "w2")
+# the block matmul weights the decode step can stream: attention (GQA
+# and MLA) and the dense SwiGLU, and, in a held-expert layer, the held
+# experts and the shared ones
+_STREAMED = ("wq", "wk", "wv", "wo", "w_dkv", "w1", "w3", "w2")
+_STREAMED_HELD = ("we1", "we3", "we2", "ws1", "ws3", "ws2")
+# the scope that slices each kept ffn weight (the rest: "mlp")
+_FFN_SCOPES = {"router": "router", "we1": "experts", "we3": "experts",
+               "we2": "experts"}
 
 
 def streams_weights(cfg, params) -> bool:
     """Whether ``decode_step`` streams the stacked block weights through
-    ``stream_matmul`` in place of ``x @ w``: a dense or MoE block with
-    GQA attention (the MLA, VLM, audio, hybrid and SSM steps and the
-    experts keep their dots), fp32 weights, compiled kernels (a TPU,
-    whose default-precision dot takes bf16 operands; the CPU's f32 dot
-    does not), and no mesh (the sharded engine's weights are split
-    across chips)."""
-    return (cfg.family in ("dense", "moe") and cfg.mla is None
+    ``stream_matmul`` in place of ``x @ w``: a dense or MoE block (GQA
+    or MLA attention, a dense SwiGLU, held experts; the sort-scatter
+    expert layer and the VLM, audio, hybrid and SSM steps keep their
+    dots), fp32 weights, compiled kernels (a TPU, whose
+    default-precision dot takes bf16 operands; the CPU's f32 dot does
+    not), and no mesh (the sharded engine's weights are split across
+    chips)."""
+    return (cfg.family in ("dense", "moe")
             and params["blocks"]["attn"]["wq"].dtype == jnp.float32
             and not kernels.default_interpret()
             and current_axis_env().mesh is None)
@@ -584,12 +640,84 @@ def streams_weights(cfg, params) -> bool:
 
 def _stream_proj(weights, i):
     """The projection hook over layer ``i`` of the stacked ``weights``,
-    each read once and in place by ``stream_matmul``."""
+    each read once and in place by ``stream_matmul``; an expert stack
+    (L, E, K, N) gives every held expert's product (E, M, N)."""
     def proj(name, x):
         w = weights[name]
+        if w.ndim == 4:
+            return stream_matmul(x, w, i)
         y = stream_matmul(x.reshape(-1, x.shape[-1]), w, i)
         return y.reshape(x.shape[:-1] + w.shape[-1:])
     return proj
+
+
+def _decode_stack(cfg, blocks, carry, pos, *, first, stream, bank,
+                  lora_idx, window, mla_absorbed, lora_kernel):
+    """One decode step through a stack of blocks whose layer ``i`` is
+    layer ``first + i`` of the cache and the bank. ``carry`` is (x, ck,
+    cv) or, for a held-expert stack, (x, ck, cv, pairs, touched) with
+    the counters per layer of the stack."""
+    # The stacked caches ride the scan CARRY (read/update one layer
+    # slice per step) rather than xs/ys: while-loop carry state is
+    # aliased in place by XLA, so the donated cache is updated without
+    # double-buffering the full (L,B,S,...) arrays (§Perf iter 1c).
+    # Weights and bank are sliced per layer in the body, as scan
+    # would slice xs, so that each slice carries the scope of the
+    # work that reads it. Streamed weights are not sliced: the
+    # kernel takes the whole stack and the layer index (a slice fed
+    # to it would be materialised).
+    counting = len(carry) == 5
+    streamed = {}
+    if stream:
+        names = _STREAMED + (_STREAMED_HELD if cfg.moe is not None
+                             and cfg.moe.holds_share else ())
+        streamed = {n: w for g in ("attn", "ffn")
+                    for n, w in blocks[g].items() if n in names}
+
+    def kept(group):
+        return {n: w for n, w in group.items() if n not in streamed}
+
+    ffn_kept = {}                       # scope -> the kept weights it reads
+    for n, w in kept(blocks["ffn"]).items():
+        ffn_kept.setdefault(_FFN_SCOPES.get(n, "mlp"), {})[n] = w
+
+    def body(carry, _):
+        x, ck, cv, *counts, i = carry
+        li = i + first if first else i
+        bp = _layer_slice({k: v for k, v in blocks.items()
+                           if k not in ("attn", "ffn")}, i)
+        with jax.named_scope("proj"):
+            bp["attn"] = _layer_slice(kept(blocks["attn"]), i)
+        bp["ffn"] = {}
+        for scope, group in sorted(ffn_kept.items()):
+            with jax.named_scope(scope):
+                bp["ffn"].update(_layer_slice(group, i))
+        proj = _stream_proj(streamed, i) if streamed else None
+        lora = None
+        if bank is not None:
+            with jax.named_scope("lora"):
+                bk = _layer_slice(bank, li)
+            lora = make_lora_cb(bk, lora_idx, kernel=lora_kernel)
+        with jax.named_scope("attention"):
+            kc = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
+            vc = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
+        x, kc, vc, stats = _dense_block_decode(cfg, bp, x, kc, vc, pos,
+                                               window, lora, mla_absorbed,
+                                               proj)
+        with jax.named_scope("attention"):
+            ck = jax.lax.dynamic_update_index_in_dim(
+                ck, kc.astype(ck.dtype), li, 0)
+            cv = jax.lax.dynamic_update_index_in_dim(
+                cv, vc.astype(cv.dtype), li, 0)
+        if counting:
+            with jax.named_scope("router"):
+                counts = [c.at[i].add(v) for c, v in zip(counts, stats)]
+        return (x, ck, cv, *counts, i + 1), None
+
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    out, _ = jax.lax.scan(body, (*carry, jnp.zeros((), jnp.int32)), None,
+                          length=n)
+    return out[:-1]
 
 
 def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
@@ -603,61 +731,25 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
     new_cache = dict(cache)
 
     if fam in ("dense", "moe"):
-        ck = cache["c"] if cfg.mla is not None else cache["k"]
-        cv = cache["kr"] if cfg.mla is not None else cache["v"]
-
-        # The stacked caches ride the scan CARRY (read/update one layer
-        # slice per step) rather than xs/ys: while-loop carry state is
-        # aliased in place by XLA, so the donated cache is updated without
-        # double-buffering the full (L,B,S,...) arrays (§Perf iter 1c).
-        # Weights and bank are sliced per layer in the body, as scan
-        # would slice xs, so that each slice carries the scope of the
-        # work that reads it. Streamed weights are not sliced: the
-        # kernel takes the whole stack and the layer index (a slice fed
-        # to it would be materialised).
-        blocks = params["blocks"]
-        streamed = {}
-        if streams_weights(cfg, params):
-            streamed = {n: w for g in ("attn", "ffn")
-                        for n, w in blocks[g].items() if n in _STREAMED}
-
-        def kept(group):
-            return {n: w for n, w in group.items() if n not in streamed}
-
-        def body(carry, _):
-            x, ck, cv, i = carry
-            bp = _layer_slice({k: v for k, v in blocks.items()
-                               if k not in ("attn", "ffn")}, i)
-            with jax.named_scope("proj"):
-                bp["attn"] = _layer_slice(kept(blocks["attn"]), i)
-            with jax.named_scope("mlp"):
-                bp["ffn"] = _layer_slice(kept(blocks["ffn"]), i)
-            proj = _stream_proj(streamed, i) if streamed else None
-            lora = None
-            if bank is not None:
-                with jax.named_scope("lora"):
-                    bk = _layer_slice(bank, i)
-                lora = make_lora_cb(bk, lora_idx, kernel=lora_kernel)
-            with jax.named_scope("attention"):
-                kc = jax.lax.dynamic_index_in_dim(ck, i, 0, keepdims=False)
-                vc = jax.lax.dynamic_index_in_dim(cv, i, 0, keepdims=False)
-            x, kc, vc = _dense_block_decode(cfg, bp, x, kc, vc, pos,
-                                            window, lora, mla_absorbed,
-                                            proj)
-            with jax.named_scope("attention"):
-                ck = jax.lax.dynamic_update_index_in_dim(
-                    ck, kc.astype(ck.dtype), i, 0)
-                cv = jax.lax.dynamic_update_index_in_dim(
-                    cv, vc.astype(cv.dtype), i, 0)
-            return (x, ck, cv, i + 1), None
-
-        (x, ck2, cv2, _), _ = jax.lax.scan(
-            body, (x, ck, cv, jnp.zeros((), jnp.int32)), None,
-            length=cfg.n_layers)
-        if cfg.mla is not None:
-            new_cache["c"], new_cache["kr"] = ck2, cv2
-        else:
-            new_cache["k"], new_cache["v"] = ck2, cv2
+        names = ("c", "kr") if cfg.mla is not None else ("k", "v")
+        carry = (x,) + tuple(cache[n] for n in names)
+        kw = dict(pos=pos, stream=streams_weights(cfg, params), bank=bank,
+                  lora_idx=lora_idx, window=window,
+                  mla_absorbed=mla_absorbed, lora_kernel=lora_kernel)
+        if cfg.n_dense_layers:
+            carry = _decode_stack(cfg, params["dense_blocks"], carry,
+                                  first=0, **kw)
+        counts = cache.get(COUNTERS)
+        if counts is not None:
+            carry += (counts["pairs"], counts["touched"])
+        carry = _decode_stack(cfg, params["blocks"], carry,
+                              first=cfg.n_dense_layers, **kw)
+        x = carry[0]
+        for n, c in zip(names, carry[1:3]):
+            new_cache[n] = c
+        if counts is not None:
+            new_cache[COUNTERS] = {"pairs": carry[3], "touched": carry[4],
+                                   "steps": counts["steps"] + 1}
     elif fam == "vlm":
         n_cross = cfg.n_layers // cfg.cross_attn_every
         per = cfg.cross_attn_every - 1
@@ -669,8 +761,8 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
 
         def self_body(x, inp):
             bp, kc, vc = inp
-            x, kc, vc = _dense_block_decode(cfg, bp, x, kc, vc, pos, window,
-                                            None)
+            x, kc, vc, _ = _dense_block_decode(cfg, bp, x, kc, vc, pos,
+                                               window, None)
             return x, (kc, vc)
 
         def period_body(x, inp):
@@ -715,7 +807,7 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
             return x + out, st2
 
         for i, (start, size) in enumerate(segs):
-            x, kc, vc = _dense_block_decode(
+            x, kc, vc, _ = _dense_block_decode(
                 cfg, params["shared_attn"], x, cache["k"][i], cache["v"][i],
                 pos, window, lora)
             kv_k.append(kc)

@@ -116,10 +116,14 @@ class ServingEngine:
 
         cfgc = cfg
         kern = lora_kernel
+        # MLA decodes through the absorbed path: the latent cache is read
+        # once and never expanded per head (prefill expands it)
+        absorbed = cfg.mla is not None
 
         def _decode(params, cache, tokens, bank, idx):
             return M.decode_step(cfgc, params, cache, tokens, bank=bank,
-                                 lora_idx=idx, lora_kernel=kern)
+                                 lora_idx=idx, lora_kernel=kern,
+                                 mla_absorbed=absorbed)
 
         self._decode = jax.jit(_decode, donate_argnums=(1,))
         self._decode_k_cache = {}
@@ -131,6 +135,8 @@ class ServingEngine:
             for k, v in cache.items():
                 if k == "pos":
                     out[k] = v.at[slots].set(pos)
+                elif k == M.COUNTERS:           # the engine's, not a row's
+                    out[k] = v
                 else:
                     out[k] = v.at[:, slots].set(cache1[k].astype(v.dtype))
             return out
@@ -139,6 +145,21 @@ class ServingEngine:
         self._prefill_cache = {}
         with self._ctx():
             self._weight_stream = M.streams_weights(cfg, params)
+        # the dispatch spans of a MoE engine name how many experts it holds
+        self._experts_attr = {} if cfg.moe is None else {
+            "experts_held": (cfg.moe.n_held if cfg.moe.holds_share
+                             else cfg.moe.n_experts)}
+
+    def expert_counters(self) -> Optional[dict]:
+        """What the decode steps' held-expert layers routed, summed over
+        every decode step so far (host ints; None without held experts):
+        ``steps``, and per MoE layer ``pairs`` (routed pairs that landed
+        on a held expert) and ``touched`` (held experts with at least
+        one). Rows of free slots route too. Reading them syncs."""
+        counts = self.cache.get(M.COUNTERS)
+        if counts is None:
+            return None
+        return {k: np.asarray(v).tolist() for k, v in counts.items()}
 
     @property
     def weight_stream(self) -> bool:
@@ -361,7 +382,8 @@ class ServingEngine:
         if self.tracer is not None:
             t_sync = self._clock()
             self._span("prefill.dispatch", t0, t_sync, {
-                "new_program": len(self._prefill_cache) > n_programs})
+                "new_program": len(self._prefill_cache) > n_programs,
+                **self._experts_attr})
         firsts = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         if self.tracer is not None:
             t_merge = self._clock()
@@ -447,7 +469,8 @@ class ServingEngine:
         """The ``decode`` iteration span of one dispatch of ``k`` fused
         steps, and its ``decode.dispatch`` / ``decode.sync`` children."""
         self._span("decode.dispatch", t0, t_sync, {
-            "weights": "stream" if self._weight_stream else "xla"})
+            "weights": "stream" if self._weight_stream else "xla",
+            **self._experts_attr})
         self._span("decode.sync", t_sync, now)
         attrs = self._batch_shape_attrs(active, lambda r: 1)
         attrs.update(batch=len(active), steps=k, iters=k)

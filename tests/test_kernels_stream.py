@@ -2,6 +2,7 @@
 mode: the kernel against a bf16-operand dot, its tile picker, the dense
 decode step with the streaming path forced on, and the engine's report
 of which path its decode program takes."""
+import dataclasses
 import functools
 import time
 
@@ -10,9 +11,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_smoke_config
 from repro.configs.base import ModelConfig
 from repro.kernels.stream import (LANE, MAX_TILE_N, TILE_BYTES, pick_tile,
-                                  stream_matmul, stream_tiles)
+                                  stream_matmul, stream_tiles, vmem_bytes)
 from repro.models import model as M
 from repro.obs import Tracer, WallClock
 from repro.serving import Request, ServingEngine
@@ -21,10 +23,11 @@ LAYERS = 3
 
 
 def _bf16_dot(x, w_stack, layer):
-    """What a default-precision f32 dot computes on the TPU."""
+    """What a default-precision f32 dot computes on the TPU (an expert
+    stack's layer gives every expert's product)."""
     w = w_stack[layer]
-    return jnp.dot(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                   preferred_element_type=jnp.float32)
+    return jnp.matmul(x.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
 
 
 def _f32_dot(x, w_stack, layer):
@@ -67,6 +70,63 @@ def test_pick_tile(dim, tile):
             assert tk * tn * 4 <= TILE_BYTES
 
 
+@pytest.mark.parametrize("kn", [(2048, 576), (10944, 256), (256, 10944)],
+                         ids=lambda kn: f"{kn[0]}x{kn[1]}")
+def test_stream_matmul_off_the_lane(kn):
+    """Dims that are no multiple of 128 (MLA's latent and rope key, 576;
+    DeepSeek-V2-Lite's dense layer, 10944) are taken whole."""
+    K, N = kn
+    tk, tn = stream_tiles(K, N)
+    assert K % tk == 0 and N % tn == 0
+    assert (tk == K) if K % LANE else (tk % LANE == 0)
+    assert (tn == N) if N % LANE else (tn % LANE == 0)
+    kx, kw = jax.random.split(jax.random.PRNGKey(K + 3 * N))
+    x = jax.random.normal(kx, (4, K), jnp.float32)
+    w = jax.random.normal(kw, (2, K, N), jnp.float32) / np.sqrt(K)
+    got = jax.jit(stream_matmul)(x, w, jnp.int32(1))
+    want = _bf16_dot(x, w, 1)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("kn", [(2048, 576), (2048, 10944), (10944, 2048),
+                                (2048, 1408), (1408, 2048), (2048, 2816),
+                                (2816, 2048), (2048, 3072)],
+                         ids=lambda kn: f"{kn[0]}x{kn[1]}")
+def test_served_deepseek_tiles_fit_vmem(kn):
+    """Every DeepSeek-V2-Lite weight the decode step streams: the
+    tiles divide it, and a tile over ``TILE_BYTES`` (a whole dim off
+    the lane) is one lane wide; the blocks at batch 8 fit v5e's VMEM
+    (128 MiB), within the limit the call then asks for."""
+    K, N = kn
+    tk, tn = stream_tiles(K, N)
+    assert K % tk == 0 and N % tn == 0
+    if tk * tn * 4 > TILE_BYTES:
+        assert (K % LANE and tn == LANE) or (N % LANE and tk == LANE)
+    assert vmem_bytes(8, tk, tn) < 32 * 2**20
+
+
+@pytest.mark.parametrize("shared_x", [True, False],
+                         ids=["x-shared", "x-per-expert"])
+def test_stream_matmul_expert_stack(shared_x):
+    """An expert stack (L, E, K, N): every expert of the layer asked
+    for, in one call, from one x or one x per expert."""
+    L, E, K, N, M = 2, 3, 256, 1408, 4
+    kx, kw = jax.random.split(jax.random.PRNGKey(7))
+    w = jax.random.normal(kw, (L, E, K, N), jnp.float32) / np.sqrt(K)
+    shape = (M, K) if shared_x else (E, M, K)
+    x = jax.random.normal(kx, shape, jnp.float32)
+    for layer in range(L):
+        got = jax.jit(stream_matmul)(x, w, jnp.int32(layer))
+        assert got.shape == (E, M, N)
+        for e in range(E):
+            xe = x if shared_x else x[e]
+            want = _bf16_dot(xe, w[:, e], layer)
+            np.testing.assert_allclose(
+                got[e], want, rtol=0,
+                atol=1e-5 * float(jnp.abs(want).max()))
+
+
 def _tiny(name, n_kv_heads, qkv_bias):
     return ModelConfig(name=name, family="dense", n_layers=LAYERS,
                        d_model=256, n_heads=4, n_kv_heads=n_kv_heads,
@@ -74,8 +134,13 @@ def _tiny(name, n_kv_heads, qkv_bias):
                        qkv_bias=qkv_bias)
 
 
+_DS = get_smoke_config("deepseek-v2-lite-16b")
 TINY = {"gqa": _tiny("tiny-gqa", 2, False),
-        "mha-bias": _tiny("tiny-mha-bias", 4, True)}
+        "mha-bias": _tiny("tiny-mha-bias", 4, True),
+        # MLA, a leading dense layer and two layers holding 2 of 4 experts
+        "mla-moe": dataclasses.replace(
+            _DS, n_layers=LAYERS,
+            moe=dataclasses.replace(_DS.moe, n_held=2, first_held=1))}
 
 
 def _decode_steps(cfg, params, n_steps=3):

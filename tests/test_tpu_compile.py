@@ -164,3 +164,48 @@ def test_decode_step_streams_the_stacked_weights(one_chip, arch,
     assert not [line for line in text.splitlines()
                 if stack_copy.search(line)]
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_deepseek_decode_streams_every_block_weight(one_chip, monkeypatch):
+    """DeepSeek-V2-Lite's decode step as the benchmark serves it (its
+    widths, 9 layers with the dense one first, 8 of 64 experts held,
+    batch 8 over 1024 slots, the 16-adapter bank padded to rank 128,
+    absorbed MLA): every streamed block weight takes the kernel (wq,
+    w_dkv, wo and the dense SwiGLU in the dense stack; those, the held
+    experts and the shared ones in the expert stack), and no convert
+    writes a bf16 copy of a weight, a bank or the cache: none of a
+    million elements or more."""
+    from repro.lora.bank import build_bank
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+    base = get_config("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(base, n_layers=9, moe=dataclasses.replace(
+        base.moe, n_held=8))
+    ranks = dict(enumerate((8,) * 6 + (16,) * 4 + (32,) * 3 + (64,) * 2
+                           + (128,)))
+    ranks = {f"a{i}": r for i, r in ranks.items()}
+
+    def shapes(fn):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), jax.eval_shape(fn))
+
+    params = shapes(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: M.init_cache(cfg, 8, 1024))
+    bank = shapes(lambda: build_bank(cfg, ranks, jax.random.PRNGKey(1),
+                                     n_layers=9).data)
+    tokens, idx = _shapes(one_chip, (8,), (8,), dtype=jnp.int32)
+
+    def step(params, cache, tokens, bank, idx):
+        return M.decode_step(cfg, params, cache, tokens, bank=bank,
+                             lora_idx=idx, mla_absorbed=True)
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, tokens, bank, idx).compile().as_text()
+    assert text.count("tpu_custom_call") == 6 + 9
+    big = []
+    for m in re.finditer(r"= bf16\[([\d,]+)\]\S* convert\(", text):
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d)
+        if n >= 2 ** 20:
+            big.append(m.group(0))
+    assert not big

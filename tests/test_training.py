@@ -102,3 +102,22 @@ def test_data_pipeline_deterministic():
     np.testing.assert_array_equal(a1[0], a2[0])
     # labels are next-token shifted
     np.testing.assert_array_equal(a1[0][:, 1:], a1[1][:, :-1])
+
+
+def test_moe_training_loss_carries_the_balance_term():
+    """DeepSeek-V2-Lite holds its whole expert layer when trained: the
+    loss is the cross entropy plus the Switch load-balance term of every
+    MoE layer, and that term is not zero."""
+    cfg = get_smoke_config("deepseek-v2-lite-16b")
+    assert not cfg.moe.holds_share
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    t, l = next(SyntheticLM(DataConfig(cfg.vocab_size, 16, 2)).batches())
+    batch = {"tokens": jnp.asarray(t), "labels": jnp.asarray(l)}
+    _, aux = M.forward(cfg, params, batch["tokens"], remat=False)
+    # one term per MoE layer, E * sum(mean prob * top-1 share): about 1
+    # for a router near uniform
+    assert float(aux) > 0.5 * (cfg.n_layers - cfg.n_dense_layers)
+    with_aux = M.loss_fn(cfg, params, batch, remat=False)
+    without = M.loss_fn(cfg, params, batch, remat=False, aux_coef=0.0)
+    np.testing.assert_allclose(float(with_aux - without), 0.01 * float(aux),
+                               rtol=1e-4)
