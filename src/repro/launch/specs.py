@@ -90,13 +90,14 @@ def _cache_sharding(mesh, cfg, cache, batch):
     from repro.models.common import SHARDING_MODE
     ba = _bs(mesh, batch)
     b = ba if ba else None
+    # k/v: (L, B, S, Kv*hd), a token's KV heads side by side
     if SHARDING_MODE == "baseline":
-        kv_spec = P(None, b, None, "model", None)     # kv-head sharded
+        kv_spec = P(None, b, None, "model")     # kv-head sharded
     else:
         # §Perf iter 1: shard the sequence dim over the model axis
         # (context-parallel decode) — always divisible, cuts per-device
         # cache 16x and removes the kv-head reshard storm.
-        kv_spec = P(None, b, "model", None, None)
+        kv_spec = P(None, b, "model", None)
     by_key = {
         "pos": P(b),
         "k": kv_spec,

@@ -10,7 +10,9 @@ decode step's weight stream, ``kernels/stream.py``).
 
 GQA names its work for the device trace: ``proj`` (the q/k/v/o
 matmuls and biases) and ``attention`` (rope, the KV write, attention
-over the cache or flash prefill); the hook names its own ``lora``.
+over the cache or flash prefill); the hook names its own ``lora``. Its
+decode cache is stacked over layers, ``(L, B, S, Kv*hd)``, and written
+and read in place (``kernels/decode_attention.py`` on the TPU).
 """
 from __future__ import annotations
 
@@ -21,6 +23,8 @@ import jax.numpy as jnp
 
 import numpy as _np
 from jax.sharding import PartitionSpec as P
+
+from repro.kernels.decode_attention import decode_attention
 
 from .common import (SHARDING_MODE, apply_rope, attend_cache, constrain,
                      constrain_resid, current_axis_env, dense_init,
@@ -191,16 +195,22 @@ def gqa_full(cfg, p, x, positions, *, causal=True, window=0,
     return constrain_resid(out), (k, v)
 
 
-def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, window=0,
+def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, layer=0, window=0,
                lora: Optional[Callable] = None,
-               proj: Optional[Callable] = None):
-    """Single-token decode. x: (B,1,d); caches (B,S,Kv,hd); pos: (B,) int32
-    current position of the new token per row. Returns (out, (k_cache,
-    v_cache)) with the new token written (ring-indexed when window>0)."""
+               proj: Optional[Callable] = None, kernel: bool = False):
+    """Single-token decode. x: (B,1,d); caches: the stacked
+    (L,B,S,Kv*hd) caches, this layer's being ``layer``; pos: (B,) int32
+    current position of the new token per row (S or more: a free slot,
+    whose write drops). The new token's k/v line is written in place at
+    (layer, row, pos) (ring-indexed when window>0), then the layer is
+    read: with ``kernel``, its live blocks in place by
+    ``decode_attention``, else the whole layer by ``attend_cache``.
+    Returns (out, (k_cache, v_cache))."""
     lora = lora or _zero_lora
     proj = proj or dense_proj(p)
     B = x.shape[0]
-    S = k_cache.shape[1]
+    S = k_cache.shape[2]
+    Kv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     q, k, v = _qkv(cfg, p, x, pos[:, None], lora, proj)
     with jax.named_scope("attention"):
         if SHARDING_MODE != "baseline":
@@ -213,11 +223,18 @@ def gqa_decode(cfg, p, x, k_cache, v_cache, pos, *, window=0,
             v = constrain(v, "batch", None, None, None)
         write_idx = pos % S if window else pos
         bidx = jnp.arange(B)
-        k_cache = k_cache.at[bidx, write_idx].set(k[:, 0])
-        v_cache = v_cache.at[bidx, write_idx].set(v[:, 0])
-        slots = jnp.arange(S)[None, :]
-        valid = slots <= jnp.minimum(pos, S - 1)[:, None]
-        o = attend_cache(q, k_cache, v_cache, valid)
+        k_cache = k_cache.at[layer, bidx, write_idx].set(
+            k.reshape(B, -1).astype(k_cache.dtype))
+        v_cache = v_cache.at[layer, bidx, write_idx].set(
+            v.reshape(B, -1).astype(v_cache.dtype))
+        if kernel:
+            live = jnp.where(pos < S, pos + 1, 0)
+            o = decode_attention(q[:, 0], k_cache, v_cache, layer, live)
+        else:
+            slots = jnp.arange(S)[None, :]
+            valid = slots <= jnp.minimum(pos, S - 1)[:, None]
+            o = attend_cache(q, k_cache[layer].reshape(B, S, Kv, hd),
+                             v_cache[layer].reshape(B, S, Kv, hd), valid)
         o = o.reshape(B, 1, -1)
     with jax.named_scope("proj"):
         out = proj("wo", o) + lora("o", o)

@@ -8,7 +8,8 @@ layer axis) so they thread through jit/pjit and can be donated.
 
 Cache dict keys (present depending on family):
   pos    : (B,) int32 — tokens currently in the cache per row
-  k, v   : (L_attn, B, S, Kv, hd) self-attention KV
+  k, v   : (L_attn, B, S, Kv*hd) self-attention KV, a token one
+           lane-dense line of every KV head side by side
   c, kr  : (L, B, S, kv_lora) / (L, B, S, rope) MLA compressed cache
   expert_counts : {"pairs", "touched": (L_moe,), "steps": ()} int32,
            what the decode steps' held-expert layers routed (no batch
@@ -183,11 +184,14 @@ def _dense_block_full(cfg, bp, x, positions, window, lora):
 
 
 def _dense_block_decode(cfg, bp, x, kc, vc, pos, window, lora,
-                        mla_absorbed=False, proj=None):
+                        mla_absorbed=False, proj=None, layer=0,
+                        kernel=False):
     """``proj``, when given, computes the attention, SwiGLU and expert
     projections whose weights ``bp`` then leaves out (see
-    ``decode_step``). Returns (x, kc, vc, stats): ``stats`` the held
-    expert layer's (pairs, touched), else None."""
+    ``decode_step``). GQA's ``kc``/``vc`` are the stacked caches, this
+    block being their ``layer`` (``gqa_decode``); MLA's are the layer's
+    own. Returns (x, kc, vc, stats): ``stats`` the held expert layer's
+    (pairs, touched), else None."""
     if cfg.mla:
         h, (kc, vc) = mla_decode(cfg, bp["attn"],
                                  rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
@@ -196,8 +200,8 @@ def _dense_block_decode(cfg, bp, x, kc, vc, pos, window, lora,
     else:
         h, (kc, vc) = gqa_decode(cfg, bp["attn"],
                                  rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
-                                 kc, vc, pos, window=window, lora=lora,
-                                 proj=proj)
+                                 kc, vc, pos, layer=layer, window=window,
+                                 lora=lora, proj=proj, kernel=kernel)
     x = x + h
     xn = rmsnorm(x, bp["ln2"], cfg.rmsnorm_eps)
     stats = None
@@ -515,8 +519,8 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.float32,
         cache["kr"] = jnp.zeros((cfg.n_layers, batch, max_len,
                                  m.qk_rope_head_dim), dtype)
     elif n_attn:
-        cache["k"] = jnp.zeros((n_attn, batch, max_len, Kv, hd), dtype)
-        cache["v"] = jnp.zeros((n_attn, batch, max_len, Kv, hd), dtype)
+        cache["k"] = jnp.zeros((n_attn, batch, max_len, Kv * hd), dtype)
+        cache["v"] = jnp.zeros((n_attn, batch, max_len, Kv * hd), dtype)
     n_cross = n_cross_applications(cfg)
     if n_cross:
         M = enc_len or (cfg.encoder.n_frames if cfg.encoder
@@ -541,9 +545,11 @@ def init_cache(cfg, batch: int, max_len: int, dtype=jnp.float32,
 
 
 def _write_prefill_kv(kvs, cache_arr, window):
-    """kvs: (L, B, S, ...) computed at prefill; write into cache (L,B,Smax,...)
-    honoring ring layout when window > 0."""
+    """kvs: (L, B, S, ...) computed at prefill; write into cache (L,B,Smax,W)
+    honoring ring layout when window > 0 (a token's trailing dims, e.g.
+    (Kv, hd), flatten to the cache's line)."""
     L, B, S = kvs.shape[:3]
+    kvs = kvs.reshape(L, B, S, -1)
     Smax = cache_arr.shape[2]
     if window and S > Smax:
         # keep the last `Smax` entries at their ring slots
@@ -638,6 +644,20 @@ def streams_weights(cfg, params) -> bool:
             and current_axis_env().mesh is None)
 
 
+def kernel_attention(cfg, window) -> bool:
+    """Whether ``decode_step`` attends through ``decode_attention``,
+    which reads each layer's live KV blocks in place in the stacked
+    cache: GQA attention in a dense or MoE block (MLA keeps its latent
+    cache and absorbed attention; the VLM, audio and hybrid steps keep
+    ``attend_cache``), no sliding window (the ring is not handled),
+    compiled kernels (a TPU), and no mesh (the sharded engine's cache
+    is split across chips)."""
+    return (cfg.family in ("dense", "moe") and cfg.mla is None
+            and not window
+            and not kernels.default_interpret()
+            and current_axis_env().mesh is None)
+
+
 def _stream_proj(weights, i):
     """The projection hook over layer ``i`` of the stacked ``weights``,
     each read once and in place by ``stream_matmul``; an expert stack
@@ -651,20 +671,21 @@ def _stream_proj(weights, i):
     return proj
 
 
-def _decode_stack(cfg, blocks, carry, pos, *, first, stream, bank,
+def _decode_stack(cfg, blocks, carry, pos, *, first, stream, kernel, bank,
                   lora_idx, window, mla_absorbed, lora_kernel):
     """One decode step through a stack of blocks whose layer ``i`` is
     layer ``first + i`` of the cache and the bank. ``carry`` is (x, ck,
     cv) or, for a held-expert stack, (x, ck, cv, pairs, touched) with
     the counters per layer of the stack."""
-    # The stacked caches ride the scan CARRY (read/update one layer
-    # slice per step) rather than xs/ys: while-loop carry state is
-    # aliased in place by XLA, so the donated cache is updated without
-    # double-buffering the full (L,B,S,...) arrays (§Perf iter 1c).
-    # Weights and bank are sliced per layer in the body, as scan
-    # would slice xs, so that each slice carries the scope of the
-    # work that reads it. Streamed weights are not sliced: the
-    # kernel takes the whole stack and the layer index (a slice fed
+    # The stacked caches ride the scan CARRY rather than xs/ys:
+    # while-loop carry state is aliased in place by XLA, so the donated
+    # cache is updated without double-buffering the full (L,B,S,...)
+    # arrays (§Perf iter 1c). GQA writes the new token's line into the
+    # stack and reads the layer there; MLA reads and writes back its
+    # layer's latent slice. Weights and bank are sliced per layer in
+    # the body, as scan would slice xs, so that each slice carries the
+    # scope of the work that reads it. Streamed weights are not sliced:
+    # the kernel takes the whole stack and the layer index (a slice fed
     # to it would be materialised).
     counting = len(carry) == 5
     streamed = {}
@@ -698,17 +719,21 @@ def _decode_stack(cfg, blocks, carry, pos, *, first, stream, bank,
             with jax.named_scope("lora"):
                 bk = _layer_slice(bank, li)
             lora = make_lora_cb(bk, lora_idx, kernel=lora_kernel)
-        with jax.named_scope("attention"):
-            kc = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
-            vc = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
-        x, kc, vc, stats = _dense_block_decode(cfg, bp, x, kc, vc, pos,
-                                               window, lora, mla_absorbed,
-                                               proj)
-        with jax.named_scope("attention"):
-            ck = jax.lax.dynamic_update_index_in_dim(
-                ck, kc.astype(ck.dtype), li, 0)
-            cv = jax.lax.dynamic_update_index_in_dim(
-                cv, vc.astype(cv.dtype), li, 0)
+        if cfg.mla is None:
+            x, ck, cv, stats = _dense_block_decode(
+                cfg, bp, x, ck, cv, pos, window, lora, proj=proj,
+                layer=li, kernel=kernel)
+        else:
+            with jax.named_scope("attention"):
+                kc = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
+                vc = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
+            x, kc, vc, stats = _dense_block_decode(
+                cfg, bp, x, kc, vc, pos, window, lora, mla_absorbed, proj)
+            with jax.named_scope("attention"):
+                ck = jax.lax.dynamic_update_index_in_dim(
+                    ck, kc.astype(ck.dtype), li, 0)
+                cv = jax.lax.dynamic_update_index_in_dim(
+                    cv, vc.astype(cv.dtype), li, 0)
         if counting:
             with jax.named_scope("router"):
                 counts = [c.at[i].add(v) for c, v in zip(counts, stats)]
@@ -733,7 +758,8 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
     if fam in ("dense", "moe"):
         names = ("c", "kr") if cfg.mla is not None else ("k", "v")
         carry = (x,) + tuple(cache[n] for n in names)
-        kw = dict(pos=pos, stream=streams_weights(cfg, params), bank=bank,
+        kw = dict(pos=pos, stream=streams_weights(cfg, params),
+                  kernel=kernel_attention(cfg, window), bank=bank,
                   lora_idx=lora_idx, window=window,
                   mla_absorbed=mla_absorbed, lora_kernel=lora_kernel)
         if cfg.n_dense_layers:
@@ -761,9 +787,9 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
 
         def self_body(x, inp):
             bp, kc, vc = inp
-            x, kc, vc, _ = _dense_block_decode(cfg, bp, x, kc, vc, pos,
-                                               window, None)
-            return x, (kc, vc)
+            x, kc, vc, _ = _dense_block_decode(cfg, bp, x, kc[None],
+                                               vc[None], pos, window, None)
+            return x, (kc[0], vc[0])
 
         def period_body(x, inp):
             blocks_i, cross_bp, kci, vci, xk, xv = inp
@@ -781,20 +807,20 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
             bp, kc, vc, xk, xv = inp
             h, (kc, vc) = gqa_decode(cfg, bp["attn"],
                                      rmsnorm(x, bp["ln1"], cfg.rmsnorm_eps),
-                                     kc, vc, pos, window=window)
+                                     kc[None], vc[None], pos, window=window)
             x = x + h
             x = x + cross_attend(cfg, bp["cross"],
                                  rmsnorm(x, bp["lnc"], cfg.rmsnorm_eps),
                                  xk, xv)
             x = x + swiglu(bp["ffn"], rmsnorm(x, bp["ln2"], cfg.rmsnorm_eps))
-            return x, (kc, vc)
+            return x, (kc[0], vc[0])
 
         x, (k2, v2) = jax.lax.scan(
             body, x, (params["dec_blocks"], cache["k"], cache["v"],
                       cache["xk"], cache["xv"]))
         new_cache["k"], new_cache["v"] = k2, v2
     elif fam == "hybrid":
-        kv_k, kv_v = [], []
+        ck, cv = cache["k"], cache["v"]
         states = []
         lora = make_lora_cb(_bank_slice(bank, 0) if bank is not None else
                             None, lora_idx, kernel=lora_kernel)
@@ -807,19 +833,16 @@ def decode_step(cfg, params, cache, tokens, *, bank=None, lora_idx=None,
             return x + out, st2
 
         for i, (start, size) in enumerate(segs):
-            x, kc, vc, _ = _dense_block_decode(
-                cfg, params["shared_attn"], x, cache["k"][i], cache["v"][i],
-                pos, window, lora)
-            kv_k.append(kc)
-            kv_v.append(vc)
+            x, ck, cv, _ = _dense_block_decode(
+                cfg, params["shared_attn"], x, ck, cv, pos, window, lora,
+                layer=i)
             sub = jax.tree.map(
                 lambda t: jax.lax.slice_in_dim(t, start, start + size),
                 params["mamba_blocks"])
             st_in = jax.lax.slice_in_dim(cache["ssm"], start, start + size)
             x, st_out = jax.lax.scan(mamba_body, x, (sub, st_in))
             states.append(st_out)
-        new_cache["k"] = jnp.stack(kv_k)
-        new_cache["v"] = jnp.stack(kv_v)
+        new_cache["k"], new_cache["v"] = ck, cv
         new_cache["ssm"] = jnp.concatenate(states, axis=0).astype(
             cache["ssm"].dtype)
     elif fam == "ssm":

@@ -11,8 +11,9 @@ fuses k of them into a single host dispatch (``jax.lax.scan`` over the
 decode step with on-device argmax and per-slot remaining-token
 bookkeeping, cache donated through the scan), so decode costs one host
 round-trip per k tokens instead of per token. Each slot row carries its
-own cache position; free slots drop their writes (out-of-bounds scatter
-semantics).
+own cache position; a free slot's position is parked past the cache, so
+its writes drop (out-of-bounds scatter semantics) and the decode
+attention reads none of its blocks.
 
 The engine is *placement-aware*: its bank holds only the adapters the
 orchestrator placed (or fetched) onto this server, padded to that
@@ -142,9 +143,19 @@ class ServingEngine:
             return out
 
         self._merge_many = jax.jit(_merge_many, donate_argnums=(0,))
+
+        def _park(cache, free):
+            return {**cache, "pos": jnp.where(free, max_len, cache["pos"])}
+
+        self._park = jax.jit(_park, donate_argnums=(0,))
+        # slots whose position is parked (every slot, from the start)
+        self._parked = set(range(max_batch))
         self._prefill_cache = {}
         with self._ctx():
+            self.cache = self._park(self.cache, np.ones(max_batch, bool))
             self._weight_stream = M.streams_weights(cfg, params)
+            self._kv_attention = "kernel" if M.kernel_attention(
+                cfg, cfg.sliding_window) else "xla"
         # the dispatch spans of a MoE engine name how many experts it holds
         self._experts_attr = {} if cfg.moe is None else {
             "experts_held": (cfg.moe.n_held if cfg.moe.holds_share
@@ -167,6 +178,14 @@ class ServingEngine:
         ``kernels.stream`` (fp32 dense weights on an unsharded TPU
         engine) rather than leaving the dots to XLA."""
         return self._weight_stream
+
+    @property
+    def kv_attention(self) -> str:
+        """``"kernel"`` where the decode program attends through
+        ``kernels.decode_attention`` (GQA without a window on an
+        unsharded TPU engine: each layer's live KV blocks read in place),
+        else ``"xla"``."""
+        return self._kv_attention
 
     def _ctx(self):
         """Mesh + axis-env context every jitted call runs under (tracing
@@ -393,6 +412,7 @@ class ServingEngine:
             self.cache = self._merge_many(self.cache, cache1, slots,
                                           jnp.full((n,), length,
                                                    jnp.int32))
+        self._parked.difference_update(slot for slot, _ in grp)
         self.slot_adapter = self.slot_adapter.at[slots].set(
             jnp.asarray(aidx, jnp.int32))
         self.last_token = self.last_token.at[slots].set(
@@ -437,9 +457,22 @@ class ServingEngine:
                            for r in self.slots):
                     self.page_pool.pin_adapter(req.adapter_id, False)
 
+    def _park_free(self) -> None:
+        """Park the positions of the slots freed since the last decode
+        past the cache (one small program for any set of slots)."""
+        free = [s for s, r in enumerate(self.slots)
+                if r is None and s not in self._parked]
+        if free:
+            mask = np.zeros(self.max_batch, bool)
+            mask[free] = True
+            with self._ctx():
+                self.cache = self._park(self.cache, mask)
+            self._parked.update(free)
+
     def _decode_once(self) -> None:
         if not any(s is not None for s in self.slots):
             return
+        self._park_free()
         t0 = self._clock()
         active = [r for r in self.slots if r is not None]
         with self._ctx():
@@ -467,9 +500,15 @@ class ServingEngine:
     def _decode_spans(self, active, t0: float, t_sync: float, now: float,
                       k: int) -> None:
         """The ``decode`` iteration span of one dispatch of ``k`` fused
-        steps, and its ``decode.dispatch`` / ``decode.sync`` children."""
+        steps, and its ``decode.dispatch`` / ``decode.sync`` children.
+        ``kv_live``: the live tokens of the dispatch's first step (those
+        its attention reads) over ``max_batch * max_len``."""
+        live = sum(min(len(r.prompt) + len(r.output), self.max_len)
+                   for r in active)
         self._span("decode.dispatch", t0, t_sync, {
             "weights": "stream" if self._weight_stream else "xla",
+            "attention": self._kv_attention,
+            "kv_live": live / (self.max_batch * self.max_len),
             **self._experts_attr})
         self._span("decode.sync", t_sync, now)
         attrs = self._batch_shape_attrs(active, lambda r: 1)
@@ -518,6 +557,7 @@ class ServingEngine:
         timestamp granularity are coarser."""
         if not any(s is not None for s in self.slots):
             return 0
+        self._park_free()
         t0 = self._clock()
         left = [0] * self.max_batch
         for slot, req in enumerate(self.slots):

@@ -5,10 +5,12 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import model as M
+from repro.obs import Tracer, WallClock
 from repro.serving import Request, ServingEngine
 
 ADAPTERS = {"a-r8": 8, "b-r64": 64}
@@ -115,3 +117,40 @@ def test_every_dot_is_scoped(setup):
         found = {s for name in names for s in scopes
                  if s in name.split("/")}
         assert found == scopes
+
+
+@pytest.mark.parametrize("decode_block", [1, 3])
+def test_dispatch_reports_the_live_kv(setup, decode_block):
+    """Each ``decode.dispatch`` names the attention path (``xla`` on the
+    CPU) and ``kv_live``: the slots' live tokens over max_batch x
+    max_len, which is what the device's positions say the attention
+    reads (a free slot's position is parked past the cache: none)."""
+    cfg, params = setup
+    tracer = Tracer(clock=WallClock())
+    eng = _mk_engine(cfg, params, max_batch=3, tracer=tracer,
+                     decode_block=decode_block)
+    assert eng.kv_attention == "xla"
+    seen = []
+    decode_k = eng._decode_k_fn(decode_block)
+
+    def watch(fn):
+        def call(params, cache, *args):
+            pos = np.asarray(cache["pos"])
+            seen.append(int(np.where(pos < eng.max_len, pos + 1, 0).sum()))
+            return fn(params, cache, *args)
+        return call
+
+    eng._decode = watch(eng._decode)
+    eng._decode_k_cache[decode_block] = watch(decode_k)
+    for i, (n, out) in enumerate([(8, 3), (13, 9), (5, 4), (21, 2)]):
+        eng.submit(Request(i, "a-r8", list(range(1, n + 1)),
+                           max_new_tokens=out, arrival=time.monotonic()))
+    eng.run_until_drained()
+    disp = [s for s in tracer.spans if s.name == "decode.dispatch"]
+    assert len(disp) == len(seen) > 2
+    assert all(s.attrs["attention"] == "xla" for s in disp)
+    assert [s.attrs["kv_live"] for s in disp] == [
+        n / (3 * eng.max_len) for n in seen]
+    # and some dispatches ran with a slot free
+    assert any(s.attrs["batch"] < 3 for s in tracer.spans
+               if s.name == "decode" and s.cat == "iteration")

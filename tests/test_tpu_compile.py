@@ -12,7 +12,9 @@ mesh-sharded engine runs at tp=4.
 
 The dense decode step is compiled whole at InternLM2-1.8B and
 StableLM-2-1.6B widths and depth, to check that its weight stream
-(``kernels/stream.py``) leaves no bf16 copy of the stacked weights.
+(``kernels/stream.py``) leaves no bf16 copy of the stacked weights and
+that its attention (``kernels/decode_attention.py``) reads each layer's
+KV in place, with no slice of the cache made.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
@@ -166,6 +168,70 @@ def test_decode_step_streams_the_stacked_weights(one_chip, arch,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
+def _padded_bytes(dims, layout):
+    """Device bytes of an f32 array of ``dims`` in an HLO ``layout``
+    (``3,2,1,0:T(8,128)``): the two most-minor dims rounded up to the
+    tile."""
+    order, _, tile = layout.partition(":")
+    mtm = [int(i) for i in order.split(",")]
+    size = [dims[i] for i in mtm]
+    if tile:
+        t = [int(x) for x in re.findall(r"\d+", tile)[:2]]
+        size[1] = -(-size[1] // t[0]) * t[0]
+        size[0] = -(-size[0] // t[1]) * t[1]
+    n = 4
+    for d in size:
+        n *= d
+    return n
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE))
+def test_decode_step_reads_the_kv_in_place(one_chip, arch, monkeypatch):
+    """GQA on a TPU attends through ``decode_attention`` over the
+    stacked (L, B, S, Kv*hd) cache: the step holds the kernel, no copy,
+    dynamic-slice or dynamic-update-slice makes an f32 array of a
+    layer's KV or more (the parent sliced, relaid and wrote back each
+    layer's k and v: eight slice-sized passes each), the cache
+    arguments take their logical bytes (no padded tile), and no
+    temporary is as large as a layer's KV."""
+    cfg = dataclasses.replace(get_config(arch), **DECODE[arch])
+    monkeypatch.setattr(kernels, "default_interpret", lambda: False)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    B, S = 4, 1024
+    params = shapes(jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = shapes(jax.eval_shape(lambda: M.init_cache(cfg, B, S)))
+    (tokens,) = _shapes(one_chip, (B,), dtype=jnp.int32)
+    compiled = jax.jit(functools.partial(M.decode_step, cfg),
+                       donate_argnums=(1,)).lower(
+        params, cache, tokens).compile()
+    text = compiled.as_text()
+    assert re.search(r"%decode_attention\S* = .*tpu_custom_call", text)
+    width = cfg.n_kv_heads * cfg.resolved_head_dim
+    layer_kv = B * S * width
+    big = []
+    for m in re.finditer(r"= f32\[([\d,]+)\]\S* (copy|dynamic-slice|"
+                         r"dynamic-update-slice)\(", text):
+        n = 1
+        for d in m.group(1).split(","):
+            n *= int(d)
+        if n >= layer_kv:
+            big.append(m.group(0))
+    assert not big
+    dims = [cfg.n_layers, B, S, width]
+    args = re.findall(r"%cache__[kv]__\S* = f32\[([\d,]+)\]\{([^}]*)\} "
+                      r"parameter", text)
+    assert len(args) == 2
+    for shape, layout in args:
+        assert [int(d) for d in shape.split(",")] == dims
+        assert _padded_bytes(dims, layout) == 4 * cfg.n_layers * layer_kv
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * layer_kv
+
+
 def test_deepseek_decode_streams_every_block_weight(one_chip, monkeypatch):
     """DeepSeek-V2-Lite's decode step as the benchmark serves it (its
     widths, 9 layers with the dense one first, 8 of 64 experts held,
@@ -201,6 +267,8 @@ def test_deepseek_decode_streams_every_block_weight(one_chip, monkeypatch):
     text = jax.jit(step, donate_argnums=(1,)).lower(
         params, cache, tokens, bank, idx).compile().as_text()
     assert text.count("tpu_custom_call") == 6 + 9
+    # MLA keeps its latent cache and absorbed attention: no GQA kernel
+    assert not re.search(r"%decode_attention\S* = ", text)
     big = []
     for m in re.finditer(r"= bf16\[([\d,]+)\]\S* convert\(", text):
         n = 1
