@@ -12,12 +12,11 @@ from __future__ import annotations
 
 import copy
 
-from repro.cluster import ClusterSimulator
 from repro.core.baselines import LoraservePolicy
 from repro.core.placement import assign_loraserve
 from repro.traces import make_adapters, synth_trace
 
-from .common import emit, timed
+from .common import emit, sim_cluster, timed
 
 
 class NoDemandPolicy(LoraservePolicy):
@@ -59,8 +58,8 @@ def run(fast: bool = False):
                 ("no-rank", NoRankPolicy()),
                 ("no-permute", NoPermutePolicy())]
     for name, pol in variants:
-        sim = ClusterSimulator(4, adapters, policy=pol, seed=3,
-                               timeout=60, warmup=40)
+        sim = sim_cluster(4, adapters, timeout=60, policy=pol, seed=3,
+                          warmup=40)
         res, us = timed(lambda: sim.run(copy.deepcopy(trace)), repeat=1)
         rows.append(emit(
             f"ablation/{name}", us,

@@ -20,12 +20,11 @@ than static-max.
 """
 from __future__ import annotations
 
-from repro.cluster import ClusterSimulator
 from repro.controlplane import (ClusterController, ControllerConfig,
                                 SLOSpec)
 from repro.traces import production_trace_with_meta
 
-from .common import emit
+from .common import emit, sim_cluster
 
 SLO_TTFT = 8.0
 
@@ -42,7 +41,7 @@ def _row(rows, name, res):
     att = res.slo_attainment(SLO_TTFT)
     rows.append(emit(
         f"autoscale/{name}", res.gpu_seconds * 1e6,
-        f"gpu_hours={res.gpu_hours():.4f};slo_attainment={att:.4f};"
+        f"gpu_hours={res.gpu_seconds / 3600.0:.4f};slo_attainment={att:.4f};"
         f"p95_ttft_s={res.p95_ttft():.3f};completed={res.completed()};"
         f"timed_out={res.timed_out};scale_ups={res.scale_ups};"
         f"drains={res.drains};retires={res.retires};"
@@ -67,10 +66,9 @@ def run(fast: bool = True):
                      f"rps_base={rps};duration_s={duration}"))
 
     def sim(n, controller=None):
-        return ClusterSimulator(
-            n, meta["adapters"], policy="loraserve", seed=7,
-            timeout=60.0, warmup=0.0, rebalance_period=15.0,
-            controller=controller)
+        return sim_cluster(
+            n, meta["adapters"], timeout=60.0, policy="loraserve", seed=7,
+            warmup=0.0, rebalance_period=15.0, controller=controller)
 
     def replay(s):
         import copy

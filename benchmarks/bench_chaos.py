@@ -1,5 +1,5 @@
-"""Chaos harness — kill / flap / stall under live traffic on both
-substrates, measuring what the recovery machinery actually buys.
+"""Chaos harness — kill / flap / stall under live traffic on the
+simulated cluster, measuring what the recovery machinery actually buys.
 
 Each scenario replays the *same* trace twice: once fault-free
 (baseline) and once with a seeded ``FaultPlan``. Reported per scenario:
@@ -14,21 +14,19 @@ Each scenario replays the *same* trace twice: once fault-free
   timeouts, circuit-breaker opens, and the number of requests that
   never finished (must be 0: a crash may slow requests, never eat them).
 
-Substrates: the discrete-event ``ClusterSimulator`` (virtual clock,
-fault events on the sim heap) and the ``LoRAServeCluster`` facade over
-``SimBackend`` (incremental submit/poll loop, wall-style injector +
-heartbeat ``FailureDetector``) — the same ``FaultPlan`` drives both.
+Both scenario sets run ``LoRAServeCluster`` over ``SimBackend`` (fault
+injector + heartbeat ``FailureDetector`` on the virtual clock): "sim"
+rows rebalance periodically on a shifting trace, "facade" rows keep
+placement static on a lighter one.
 """
 from __future__ import annotations
 
 import copy
 
-from repro.cluster import ClusterSimulator, NetworkModel
 from repro.faults import FaultPlan
-from repro.serving import LoRAServeCluster, SimBackend
 from repro.traces import make_adapters, synth_trace
 
-from .common import emit
+from .common import emit, sim_cluster
 
 # tight enough that a crash's detection window + re-queue visibly
 # dents the fault-window attainment, loose enough that the healthy
@@ -37,15 +35,10 @@ SLO_TTFT = 0.25
 
 
 # ---------------------------------------------------------------------
-# shared metric helpers (both substrates reduce to (arrival, ok) pairs)
+# shared metric helpers (every run reduces to (arrival, ok) pairs)
 # ---------------------------------------------------------------------
-def _sim_pairs(res):
-    """(arrival, finished, ttft) per request of a SimResult."""
-    return [(r.arrival, r.finish >= 0, r.ttft if r.prefill_done >= 0
-             else None) for r in res.requests]
-
-
-def _facade_pairs(report):
+def _pairs(report):
+    """(arrival, finished, ttft) per request of a ClusterReport."""
     return [(r.arrival, r.finished, r.ttft) for r in report.results]
 
 
@@ -80,15 +73,15 @@ def _windows_derived(pairs, t_kill, t_restore):
 
 
 # ---------------------------------------------------------------------
-# discrete-event substrate
+# "sim" scenarios: periodic rebalancing under drift
 # ---------------------------------------------------------------------
 def _sim(adapters, plan=None, window=0.5):
     # periodic rebalances + a shifting trace keep adapter transfers in
     # flight throughout the run, so link/stall faults have something to
     # bite; timeouts are off so any lost request is the chaos plane's
     # fault, not the reaper's
-    return ClusterSimulator(
-        3, adapters, policy="loraserve", seed=7, timeout=1e9,
+    return sim_cluster(
+        3, adapters, timeout=1e9, policy="loraserve", seed=7,
         rebalance_period=6.0, prefetch=True, fault_plan=plan,
         detector_window=window, durable_ssd=True)
 
@@ -105,7 +98,7 @@ def _sim_rows(rows, fast):
                         output_len=64, seed=11)
 
     base = _sim(adapters).run(copy.deepcopy(trace))
-    base_pairs = _sim_pairs(base)
+    base_pairs = _pairs(base)
     rows.append(emit(
         "chaos/sim/baseline", 0.0,
         f"requests={len(trace)};completed={len(trace) - _lost(base_pairs)};"
@@ -116,7 +109,7 @@ def _sim_rows(rows, fast):
     # flight on the victim re-dispatched from its last emitted token
     res = _sim(adapters, FaultPlan.kill_one(t_kill, 0, t_restore),
                window).run(copy.deepcopy(trace))
-    pairs = _sim_pairs(res)
+    pairs = _pairs(res)
     det, rec = _recovery(res.recovery_records, t_kill)
     win, _, _ = _windows_derived(pairs, t_kill, t_restore)
     rows.append(emit(
@@ -133,7 +126,7 @@ def _sim_rows(rows, fast):
     # would source from it are excluded and pick an alternate peer/tier
     res = _sim(adapters, FaultPlan.link_flap(t_kill, 0, t_restore),
                window).run(copy.deepcopy(trace))
-    pairs = _sim_pairs(res)
+    pairs = _pairs(res)
     win, _, _ = _windows_derived(pairs, t_kill, t_restore)
     rows.append(emit(
         "chaos/sim/link-flap", 0.0,
@@ -153,7 +146,7 @@ def _sim_rows(rows, fast):
         plan = FaultPlan(plan.events +
                          FaultPlan.stall(t_rec + 0.002 * (i + 1)).events)
     res = _sim(adapters, plan, window).run(copy.deepcopy(trace))
-    pairs = _sim_pairs(res)
+    pairs = _pairs(res)
     win, _, _ = _windows_derived(pairs, t_kill, t_restore)
     rows.append(emit(
         "chaos/sim/kill-stall-fetch", 0.0,
@@ -165,15 +158,12 @@ def _sim_rows(rows, fast):
 
 
 # ---------------------------------------------------------------------
-# facade substrate (incremental poll loop + heartbeat detector)
+# "facade" scenarios: static placement, lighter traffic
 # ---------------------------------------------------------------------
 def _facade(adapters, plan=None, window=0.5):
-    backend = SimBackend(3, adapter_nbytes={a.adapter_id: a.nbytes
-                                            for a in adapters})
-    return LoRAServeCluster(backend, adapters, network=NetworkModel(),
-                            rebalance_period=1e9, seed=7, prefetch=True,
-                            fault_plan=plan, detector_window=window,
-                            durable_ssd=True)
+    return sim_cluster(3, adapters, rebalance_period=1e9, seed=7,
+                       prefetch=True, fault_plan=plan,
+                       detector_window=window, durable_ssd=True)
 
 
 def _facade_rows(rows, fast):
@@ -187,7 +177,7 @@ def _facade_rows(rows, fast):
                         prompt_len=128, output_len=32, seed=13)
 
     base = _facade(adapters).run(copy.deepcopy(trace))
-    base_pairs = _facade_pairs(base)
+    base_pairs = _pairs(base)
     rows.append(emit(
         "chaos/facade/baseline", 0.0,
         f"requests={len(trace)};lost={_lost(base_pairs)};"
@@ -196,7 +186,7 @@ def _facade_rows(rows, fast):
     report = _facade(adapters,
                      FaultPlan.kill_one(t_kill, 0, t_restore),
                      window).run(copy.deepcopy(trace))
-    pairs = _facade_pairs(report)
+    pairs = _pairs(report)
     det, rec = _recovery(report.recovery_records, t_kill)
     win, _, _ = _windows_derived(pairs, t_kill, t_restore)
     rows.append(emit(
@@ -217,7 +207,7 @@ def run(fast: bool = True):
     _sim_rows(rows, fast)
     _facade_rows(rows, fast)
 
-    # headline: loss-free on both substrates, SLO restored post-fault
+    # headline: loss-free in both scenario sets, SLO restored post-fault
     def field(name, key):
         for n, _, derived in rows:
             if n == name:

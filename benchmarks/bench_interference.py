@@ -1,15 +1,14 @@
 """Paper Figs 1/3/4/5/6 — rank-interference characterization from the
-calibrated cost model (and the simulator for Fig 6)."""
+calibrated cost model (and the simulated cluster for Fig 6)."""
 from __future__ import annotations
 
 import copy
 
-from repro.cluster import ClusterSimulator, ServerModel, \
-    co_serving_slowdown, make_server
+from repro.cluster import ServerModel, co_serving_slowdown, make_server
 from repro.core.types import AdapterInfo
 from repro.traces import synth_trace
 
-from .common import emit, timed
+from .common import emit, sim_cluster, timed
 
 
 def run():
@@ -49,7 +48,7 @@ def run():
         ad = [AdapterInfo(f"a{rank}", rank, 10_000_000)]
         tr = synth_trace(ad, rps=8, duration=120, arrival="poisson",
                          jitter=0.0, seed=5)
-        sim = ClusterSimulator(1, ad, policy="slora-random", timeout=600)
+        sim = sim_cluster(1, ad, timeout=600, policy="slora-random")
         res, us = timed(lambda: sim.run(copy.deepcopy(tr)), repeat=1)
         rows.append(emit(f"fig6/poisson8rps/r{rank}", us,
                          f"p95_ttft={res.p95_ttft():.3f}s"))

@@ -30,7 +30,7 @@ import time
 
 from repro.cluster.costmodel import ServerModel
 from repro.cluster.server import SimServer
-from repro.core import SimRequest
+from repro.core import ServeRequest
 
 from .common import emit
 
@@ -63,9 +63,9 @@ def modeled_rows(fast: bool):
 def _trace(n_req: int):
     # mixed rank buckets, staggered output lengths: the tail iterations
     # run at shrinking batch, which the constant-batch estimate ignores
-    return [SimRequest(req_id=i, adapter_id=f"a{i}",
-                       rank=(8, 64)[i % 2], prompt_len=128,
-                       output_len=32 + (i % 3) * 16, arrival=0.0)
+    return [ServeRequest(req_id=i, adapter_id=f"a{i}",
+                         rank=(8, 64)[i % 2], prompt_len=128,
+                         output_len=32 + (i % 3) * 16, arrival=0.0)
             for i in range(n_req)]
 
 

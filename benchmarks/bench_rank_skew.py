@@ -7,10 +7,9 @@ from __future__ import annotations
 
 import copy
 
-from repro.cluster import ClusterSimulator
 from repro.traces import make_adapters, synth_trace
 
-from .common import emit, timed
+from .common import emit, sim_cluster, timed
 
 POLICIES = ["loraserve", "slora-random", "slora-contiguous"]
 BANK_MODES = ["padded", "bucketed"]
@@ -26,9 +25,8 @@ def run(fast: bool = False):
         for pol in POLICIES:
             p95 = {}
             for mode in BANK_MODES:
-                sim = ClusterSimulator(4, adapters, policy=pol, seed=3,
-                                       timeout=60, warmup=40,
-                                       bank_mode=mode)
+                sim = sim_cluster(4, adapters, timeout=60, bank_mode=mode,
+                                  policy=pol, seed=3, warmup=40)
                 res, us = timed(lambda: sim.run(copy.deepcopy(trace)),
                                 repeat=1)
                 p95[mode] = res.p95_ttft()

@@ -4,10 +4,9 @@ from __future__ import annotations
 
 import copy
 
-from repro.cluster import ClusterSimulator
 from repro.traces import make_adapters, synth_trace
 
-from .common import emit, timed
+from .common import emit, sim_cluster, timed
 
 
 def run(fast: bool = False):
@@ -18,8 +17,8 @@ def run(fast: bool = False):
         rps = 5 * n
         trace = synth_trace(adapters, rps=rps, duration=120,
                             popularity="exponential", seed=2)
-        sim = ClusterSimulator(n, adapters, policy="loraserve", seed=3,
-                               timeout=60, warmup=40)
+        sim = sim_cluster(n, adapters, timeout=60, policy="loraserve",
+                          seed=3, warmup=40)
         res, us = timed(lambda: sim.run(copy.deepcopy(trace)), repeat=1)
         rows.append(emit(
             f"fig21/servers{n}/rps{rps}", us,

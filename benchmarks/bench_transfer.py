@@ -17,10 +17,10 @@ from __future__ import annotations
 
 import copy
 
-from repro.cluster import ClusterSimulator, NetworkModel
+from repro.cluster import NetworkModel
 from repro.traces import make_adapters, synth_trace
 
-from .common import emit
+from .common import emit, sim_cluster
 
 RANK_NBYTES = {r: r * 16_000_000 for r in (8, 16, 32, 64, 128)}
 
@@ -70,9 +70,8 @@ def run(fast: bool = True):
                                   "prefetch": True}),
     ]
     for name, kw in modes:
-        sim = ClusterSimulator(4, adapters, policy="loraserve", seed=3,
-                               warmup=15, timeout=60,
-                               rebalance_period=8.0, **kw)
+        sim = sim_cluster(4, adapters, timeout=60, policy="loraserve",
+                          seed=3, warmup=15, rebalance_period=8.0, **kw)
         res = sim.run(copy.deepcopy(trace))
         rows.append(emit(
             f"access_mode/{name}", res.p95_ttft() * 1e6,

@@ -16,6 +16,19 @@ def fmt_rows(rows):
     return "\n".join(out)
 
 
+def sim_cluster(n_servers: int, adapters, *, timeout: float = 120.0,
+                bank_mode: str = "padded", **cluster_kw):
+    """``LoRAServeCluster`` over ``SimBackend(n_servers)``, with adapter
+    fetches charged by the modeled fabric (``NetworkModel``)."""
+    from repro.cluster import NetworkModel
+    from repro.serving import LoRAServeCluster, SimBackend
+    backend = SimBackend(n_servers, timeout=timeout, bank_mode=bank_mode,
+                         adapter_nbytes={a.adapter_id: a.nbytes
+                                         for a in adapters})
+    cluster_kw.setdefault("network", NetworkModel())
+    return LoRAServeCluster(backend, adapters, **cluster_kw)
+
+
 def timed(fn, *args, repeat: int = 3, **kw):
     """Returns (result, us_per_call) — best of `repeat`."""
     best = float("inf")

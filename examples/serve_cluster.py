@@ -66,7 +66,7 @@ def real_mini_cluster():
           f"pool: fetches={res.fetches} "
           f"max_adapters/server={res.max_adapters_per_server} "
           f"invariant="
-          f"{'OK' if cluster.orch.pool.check_invariant() else 'BROKEN'}")
+          f"{'OK' if cluster.orch.store.check_invariant() else 'BROKEN'}")
 
 
 if __name__ == "__main__":

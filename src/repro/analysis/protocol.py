@@ -25,11 +25,10 @@ the cluster's safety + liveness invariants at every reachable state:
   server, advancing the clock alone empties it in finitely many steps
   so retirement is enabled.
 
-The invariants are shared with the opt-in runtime debug hook:
-``AdapterStore.check_invariants()`` / the simulator's
-``REPRO_CHECK_INVARIANTS=1`` path call :func:`check_store_invariants`
-on live objects, so sim runs validate what the checker proves
-exhaustively on small models.
+The invariants are shared with the opt-in runtime debug hook: with
+``REPRO_CHECK_INVARIANTS=1`` ``AdapterStore.check_invariants()`` calls
+:func:`check_store_invariants` on live objects at every store edge, so
+sim runs validate what the checker proves exhaustively on small models.
 
 No external dependencies (and no jax): states are deep-copied real
 objects; canonical keys use ETAs *relative to the model clock* so the

@@ -1,17 +1,16 @@
-"""Cluster simulator + calibrated cost model.
+"""Simulated serving substrate: ``SimServer`` and the calibrated cost
+and network models ``SimBackend`` runs on.
 
 Lazy exports (PEP 562): ``repro.cluster.network`` is a pure-Python
 module the import-light ``repro.analysis`` protocol checker loads in a
 bare venv; eager re-exports here would pull the numpy/jax-backed
-simulator stack with it.
+cost model with it.
 """
 _EXPORTS = {
     "ServerModel": "costmodel", "co_serving_slowdown": "costmodel",
     "make_server": "costmodel", "profile_operating_points": "costmodel",
     "NetworkModel": "network",
-    "SimRequest": "server", "SimServer": "server",
-    "ClusterSimulator": "simulator", "SimResult": "simulator",
-    "max_rps_under_slo": "simulator", "min_servers_under_slo": "simulator",
+    "SimServer": "server",
 }
 
 __all__ = list(_EXPORTS)

@@ -1,5 +1,5 @@
 """Analytic cost model for a LoRA-serving LLM inference server, calibrated
-to the paper's measurements (§III-A). The cluster simulator uses it for
+to the paper's measurements (§III-A). ``SimBackend``'s servers use it for
 iteration times; the orchestrator uses it for operating points.
 
 Calibration (derivation):

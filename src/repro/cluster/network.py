@@ -74,9 +74,6 @@ class NetworkModel:
         link quotes infinite latency and refuses new transfers."""
         self._link_down.add(src_server)
 
-    def set_link_up(self, src_server: int) -> None:
-        self._link_down.discard(src_server)
-
     def degrade_link(self, src_server: int, factor: float) -> None:
         """Multiply the link's wire time by ``factor`` (>= 1); use
         ``reset_link`` / factor 1.0 to restore full bandwidth."""
@@ -85,6 +82,7 @@ class NetworkModel:
         self._link_degrade[src_server] = factor
 
     def reset_link(self, src_server: int) -> None:
+        """Bring the link back up at full bandwidth."""
         self._link_down.discard(src_server)
         self._link_degrade.pop(src_server, None)
 

@@ -13,7 +13,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
-from repro.core.request import SimRequest  # noqa: F401  (re-export)
+from repro.core.request import ServeRequest
 from repro.lora.bank import rank_bucket
 
 from .costmodel import ServerModel
@@ -29,7 +29,8 @@ def _bucket_sums(reqs, value) -> Dict[int, int]:
 
 
 class SimServer:
-    """State machine advanced by the cluster simulator's event loop."""
+    """State machine advanced by ``SimBackend`` on the facade's virtual
+    clock."""
 
     def __init__(self, server_id: int, model: ServerModel,
                  bank_mode: str = "padded", decode_block: int = 1,
@@ -50,17 +51,14 @@ class SimServer:
         # — mirrors the engine's decode_steps(k) emitting iters=k, and
         # keeps tracing cost off the per-iteration hot path
         self._dec_span: Optional[list] = None
-        self.waiting: List[SimRequest] = []
-        self.running: List[SimRequest] = []
-        self.finished: List[SimRequest] = []   # completion feed; the
-        # event loop drains this into telemetry/SLO trackers
+        self.waiting: List[ServeRequest] = []
+        self.running: List[ServeRequest] = []
         self.busy_until: float = 0.0
         self.iterations = 0
-        self.prefill_tokens = 0
         self.busy_time = 0.0
 
     # -- iteration costs (bank-layout aware) ------------------------------
-    def _remote_surcharge(self, reqs: List[SimRequest], now: float
+    def _remote_surcharge(self, reqs: List[ServeRequest], now: float
                           ) -> float:
         """GDR remote-read tax: requests whose adapter still lives on a
         peer (local warm copy lands at ``remote_until``) stream weights
@@ -68,7 +66,7 @@ class SimServer:
         return sum(r.remote_penalty for r in reqs
                    if now < r.remote_until)
 
-    def _prefill_cost(self, batch: List[SimRequest], tokens: int,
+    def _prefill_cost(self, batch: List[ServeRequest], tokens: int,
                       now: float = 0.0) -> float:
         pen = self._remote_surcharge(batch, now)
         if self.bank_mode == "bucketed":
@@ -77,7 +75,7 @@ class SimServer:
         return pen + self.model.prefill_time(tokens,
                                              max(r.rank for r in batch))
 
-    def _decode_cost(self, running: List[SimRequest],
+    def _decode_cost(self, running: List[ServeRequest],
                      now: float = 0.0) -> float:
         pen = self._remote_surcharge(running, now)
         if self.bank_mode == "bucketed":
@@ -100,7 +98,7 @@ class SimServer:
                 max(1, len(self.running))
         return w
 
-    def enqueue(self, req: SimRequest) -> None:
+    def enqueue(self, req: ServeRequest) -> None:
         self.waiting.append(req)
 
     def has_work(self, now: float) -> bool:
@@ -136,7 +134,7 @@ class SimServer:
         Prefill-prioritized (matches S-LoRA's scheduler)."""
         ready = [r for r in self.waiting if r.ready <= now]
         if ready and len(self.running) < self.model.max_decode_batch:
-            batch: List[SimRequest] = []
+            batch: List[ServeRequest] = []
             tokens = 0
             for r in sorted(ready, key=lambda r: r.ready):
                 if tokens + r.prompt_len > self.model.max_batch_tokens \
@@ -157,11 +155,9 @@ class SimServer:
                     r.decoded = 1        # first token out of prefill
                     if r.output_len <= 1:
                         r.finish = end
-                        self.finished.append(r)
                     else:
                         self.running.append(r)
                 self.iterations += 1
-                self.prefill_tokens += tokens
                 self.busy_time += t_iter
                 self.busy_until = end
                 if self.tracer is not None:
@@ -199,7 +195,6 @@ class SimServer:
                     done.append(r)
             for r in done:
                 self.running.remove(r)
-            self.finished.extend(done)
             self.iterations += 1
             self.busy_time += t_iter
             self.busy_until = end

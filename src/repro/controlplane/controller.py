@@ -1,8 +1,8 @@
 """The closed control loop: telemetry -> drift/SLO -> action.
 
-``ClusterController`` is substrate-neutral: the host (the
-discrete-event ``ClusterSimulator`` or the ``LoRAServeCluster`` facade
-over real engines) feeds it request lifecycle events, calls ``tick``
+``ClusterController`` is substrate-neutral: its host, the
+``LoRAServeCluster`` facade (over ``SimBackend`` or real engines),
+feeds it request lifecycle events, calls ``tick``
 with a ``ClusterState`` snapshot on its own clock, and executes the
 returned ``Action``s through the existing orchestrator / adapter-store
 machinery. The policy, in priority order:

@@ -15,10 +15,8 @@ _EXPORTS = {
     "DemandEstimator": "demand",
     "ClusterOrchestrator": "orchestrator",
     "assign_loraserve": "placement",
-    "AdapterStore": "pool", "DistributedAdapterPool": "pool",
-    "FetchPlan": "pool",
+    "AdapterStore": "pool", "FetchPlan": "pool",
     "Phase": "request", "Request": "request", "ServeRequest": "request",
-    "SimRequest": "request",
     "RetiredServerError": "routing", "RoutingTable": "routing",
     "UnknownAdapterError": "routing",
     "AdapterInfo": "types", "Placement": "types",

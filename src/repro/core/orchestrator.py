@@ -1,14 +1,14 @@
-"""Cluster orchestrator facade (paper Fig 11): owns the placement policy,
-routing table, tiered adapter store, and demand estimator. The
-discrete-event simulator drives it; ``launch/serve.py`` drives the same
-object against real JAX engines for the end-to-end example.
+"""Cluster orchestrator (paper Fig 11): owns the placement policy,
+routing table, tiered adapter store, and demand estimator.
+``LoRAServeCluster`` drives it on either substrate (``SimBackend`` or
+real JAX engines).
 
 The request path speaks ``FetchPlan``s: ``route_plan`` routes a request
 and asks the ``AdapterStore`` how its adapter will be served — a hit, a
 blocking migrate fetch (async, completing at ``plan.eta``), or a GDR
 remote read from a peer while the local copy warms (``access_mode=
-"remote-read"``). The legacy ``route`` keeps the old synchronous
-(server_id, latency) contract on top of the same store.
+"remote-read"``). Transfers complete when the driver polls the store on
+its clock.
 """
 from __future__ import annotations
 
@@ -26,17 +26,10 @@ class ClusterOrchestrator:
                  operating_points: Dict[int, float],
                  policy: str = "loraserve", network=None, seed: int = 0,
                  access_mode: str = "migrate", prefetch: bool = False,
-                 sync_store: bool = True,
                  retry: Optional["FetchRetryPolicy"] = None,
                  durable_ssd: bool = False):
         if access_mode not in ("migrate", "remote-read"):
             raise ValueError(f"unknown access_mode {access_mode!r}")
-        # sync_store: legacy clock-less callers (route()/end_of_timestep
-        # with the default now=0.0) have no event loop to drive
-        # store.poll(); prefetch warms then complete synchronously so
-        # transfers cannot strand on links or pin GC. Async drivers
-        # (LoRAServeCluster) pass sync_store=False and poll themselves.
-        self.sync_store = sync_store
         self.n = n_servers
         self.adapters = adapters
         self.meta = {a.adapter_id: a for a in adapters}
@@ -59,11 +52,9 @@ class ClusterOrchestrator:
             operating_points=operating_points)
         self.placement: Placement = self.policy.place(ctx)
         self.router = RoutingTable(self.placement, seed=seed)
-        # one AdapterStore; `pool` kept as the legacy name
-        self.store = self.pool = AdapterStore(n_servers, adapters,
-                                              network, retry=retry,
-                                              durable_ssd=durable_ssd,
-                                              retry_seed=seed)
+        self.store = AdapterStore(n_servers, adapters, network,
+                                  retry=retry, durable_ssd=durable_ssd,
+                                  retry_seed=seed)
         self.store.seed(self.placement)
         self._window_tokens: Dict[str, float] = {}
 
@@ -80,28 +71,9 @@ class ClusterOrchestrator:
                                       access_mode=self.access_mode,
                                       preferred_peers=[s for s, _ in
                                                        entry])
-        if self.sync_store:
-            # no event loop will poll(): complete the transfer now so
-            # it cannot strand on links or pin GC; the plan still
-            # carries the modeled latency/ETA for accounting
-            self.store.finish(plan)
         self._window_tokens[adapter_id] = \
             self._window_tokens.get(adapter_id, 0.0) + tokens
         return sid, plan
-
-    def route(self, adapter_id: str, tokens: float = 0.0,
-              now: float = 0.0):
-        """Legacy synchronous path: returns (server_id,
-        fetch_latency_seconds); the fetch completes instantly. Callers
-        combining this path with ``prefetch=True`` should pass their
-        clock as ``now`` so background prefetch transfers (completed by
-        ``ensure_local``'s internal poll) land and release their
-        links."""
-        sid = self.router.route(adapter_id, tokens)
-        lat, _ = self.store.ensure_local(sid, adapter_id, now=now)
-        self._window_tokens[adapter_id] = \
-            self._window_tokens.get(adapter_id, 0.0) + tokens
-        return sid, lat
 
     # -- control path (Fig 11 steps 6-7) -----------------------------------
     def placeable_servers(self) -> List[int]:
@@ -119,8 +91,8 @@ class ClusterOrchestrator:
 
     def _resolve(self, now: float) -> List[FetchPlan]:
         """Re-solve placement over the placeable fleet and push it into
-        the routing table + store. Returns any started prefetch plans
-        (already completed when ``sync_store``)."""
+        the routing table + store. Returns any started prefetch
+        plans."""
         ids = self.placeable_servers()
         ctx = PlacementContext(
             n_servers=len(ids), adapters=self.adapters,
@@ -129,12 +101,8 @@ class ClusterOrchestrator:
             prev_placement=self.placement, server_ids=ids)
         self.placement = self.policy.place(ctx)
         self.router.update(self.placement)
-        plans = self.store.apply_placement(self.placement, now=now,
-                                           prefetch=self.prefetch)
-        if self.sync_store:
-            for p in plans:
-                self.store.finish(p)
-        return plans
+        return self.store.apply_placement(self.placement, now=now,
+                                          prefetch=self.prefetch)
 
     # -- adapter lifecycle (runtime register / loss-free retire) -----------
     def register_adapter(self, info: AdapterInfo, now: float = 0.0,
@@ -199,18 +167,13 @@ class ClusterOrchestrator:
                     now: float = 0.0) -> List[FetchPlan]:
         """Take ``server_id`` out of placement and routing, then migrate
         its holdings to the survivors through the store. Returns the
-        in-flight migration plans (the caller turns their ETAs into
-        fetch events; empty when ``sync_store`` completed them)."""
+        in-flight migration plans; they land when the driver polls the
+        store."""
         if server_id in self.draining:
             return []
         self.draining.add(server_id)
         self._resolve(now)
-        plans = self.store.drain_server(server_id, now=now)
-        if self.sync_store:
-            for p in plans:
-                self.store.finish(p)
-            return []
-        return plans
+        return self.store.drain_server(server_id, now=now)
 
     def drain_complete(self, server_id: int) -> bool:
         """Whether the store side of a drain has finished: no copies

@@ -1,9 +1,8 @@
 """Tiered adapter data plane (paper §IV-B, Fig 13/14).
 
-``AdapterStore`` replaces the old synchronous ``DistributedAdapterPool``
-API: adapter movement is a first-class subsystem with per-server tiers,
-explicit ``FetchPlan``s, and asynchronous in-flight transfers that
-occupy link bandwidth on the simulator clock.
+``AdapterStore`` makes adapter movement a first-class subsystem with
+per-server tiers, explicit ``FetchPlan``s, and asynchronous in-flight
+transfers that occupy link bandwidth on the driver's clock.
 
 Tiers, per server:
 
@@ -683,8 +682,8 @@ class AdapterStore:
         return done
 
     def finish(self, plan: FetchPlan) -> None:
-        """Synchronously complete one in-flight transfer ahead of its
-        ETA (for clock-less legacy callers); no-op if already done."""
+        """Complete one in-flight transfer now, ahead of its ETA; no-op
+        if already done."""
         key = (plan.dest, plan.adapter_id)
         if key in self._inflight:
             self._complete(self._inflight[key])
@@ -706,22 +705,6 @@ class AdapterStore:
         if math.isinf(t):
             return None
         return max(t, now)
-
-    # -- sync compatibility shim ------------------------------------------
-    def ensure_local(self, server_id: int, adapter_id: str,
-                     now: float = 0.0) -> Tuple[float, int]:
-        """Legacy synchronous path: start the fetch and complete *that
-        transfer* immediately (other in-flight transfers keep their
-        ETAs; whatever is genuinely due by ``now`` is drained first).
-        Returns (fetch_latency_seconds, bytes); (0, 0) on a hit. A
-        coalesced fetch is charged only the remaining wait to the
-        in-flight transfer's ETA."""
-        self.poll(now)
-        plan = self.start_fetch(server_id, adapter_id, now=now)
-        if plan.hit:
-            return 0.0, 0
-        self.finish(plan)
-        return max(0.0, plan.eta - now), plan.nbytes
 
     # -- GC (Fig 13 delete-after-copy) ------------------------------------
     def _gc(self, adapter_id: str) -> None:
@@ -792,8 +775,3 @@ class AdapterStore:
     def _debug_check(self, now: float = 0.0) -> None:
         if runtime_checks_enabled():
             self.check_invariants(now, raise_on_violation=True)
-
-
-# Legacy name: the synchronous pool grew into the tiered store; callers
-# using seed/apply_placement/ensure_local/check_invariant are unchanged.
-DistributedAdapterPool = AdapterStore

@@ -1,15 +1,11 @@
-"""Unified request lifecycle type shared by the simulator and the real
-JAX engine.
+"""The request lifecycle type both serving substrates share.
 
-Historically the repo had two incompatible request classes: the
-simulator's ``SimRequest`` (length-only workload, virtual timestamps)
-and the engine's ``Request`` (concrete token ids, wall-clock stamps).
-``ServeRequest`` merges them: every request carries its workload shape
-(``prompt_len``/``output_len``), optionally concrete prompt tokens for
-real execution, and one set of lifecycle timestamps on whatever clock
-the backend runs (virtual seconds for ``SimBackend``, seconds since run
-start for ``EngineBackend``). ``SimRequest`` and ``Request`` remain as
-thin compatibility aliases.
+Every ``ServeRequest`` carries its workload shape (``prompt_len``/
+``output_len``), optionally concrete prompt tokens for real execution,
+and one set of lifecycle timestamps on whatever clock the backend runs
+(virtual seconds for ``SimBackend``, seconds since run start for
+``EngineBackend``). ``Request`` builds one from concrete prompt tokens
+and an output budget.
 """
 from __future__ import annotations
 
@@ -98,14 +94,8 @@ class ServeRequest:
 def Request(req_id: int, adapter_id: str, prompt: List[int],
             max_new_tokens: int, arrival: float = 0.0,
             rank: int = 0) -> ServeRequest:
-    """Compatibility constructor matching the old engine ``Request``
-    signature: concrete prompt tokens + output budget."""
+    """A real-engine request: concrete prompt tokens + output budget."""
     return ServeRequest(req_id=req_id, adapter_id=adapter_id, rank=rank,
                         prompt_len=len(prompt),
                         output_len=int(max_new_tokens),
                         arrival=arrival, prompt=list(prompt))
-
-
-# The simulator constructs requests with (req_id, adapter_id, rank,
-# prompt_len, output_len, arrival) keywords — same dataclass, same name.
-SimRequest = ServeRequest

@@ -1,10 +1,10 @@
 """Chaos plane: fault injection, failure detection, loss-free recovery.
 
-Spans both substrates — the discrete-event ``ClusterSimulator`` and the
-real-engine ``LoRAServeCluster`` facade consume the same seeded
-``FaultPlan`` via a ``FaultInjector``, detect crashes with the same
-heartbeat ``FailureDetector``, and re-dispatch in-flight work with the
-same exactly-once continuation helpers.
+Spans both substrates — the ``LoRAServeCluster`` facade over
+``SimBackend`` or real engines consumes a seeded ``FaultPlan`` via a
+``FaultInjector``, detects crashes with the heartbeat
+``FailureDetector``, and re-dispatches in-flight work with the
+exactly-once continuation helpers.
 """
 from .detector import FailureDetector
 from .injector import FaultInjector

@@ -1,9 +1,9 @@
 """Fault injector: applies a ``FaultPlan``'s due events to a host.
 
 The host is anything exposing ``apply_fault(event, now) -> bool`` —
-``LoRAServeCluster`` (engine facade, wall or virtual clock),
-``ClusterSimulator`` (virtual clock), and ``ServeGateway`` (asyncio
-loop, for ``disconnect_client``). The injector owns the schedule
+``LoRAServeCluster`` (wall clock on real engines, virtual clock on
+``SimBackend``) and ``ServeGateway`` (asyncio loop, for
+``disconnect_client``). The injector owns the schedule
 cursor and the applied/skipped log; the host owns the semantics.
 """
 from __future__ import annotations

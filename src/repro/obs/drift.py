@@ -12,7 +12,7 @@ silently skewing routing and autoscaling decisions.
 Two prediction paths:
 
 * sim spans carry a precomputed ``attrs["predicted"]`` — the very
-  pen+base value the simulator charged, so the listener is a dict
+  pen+base value the simulated server charged, so the listener is a dict
   lookup and drift is exactly 0 (the sim's time *is* the model; a
   nonzero value means the plumbing is broken).
 * engine spans carry the raw batch shape (tokens / batch / max_rank /

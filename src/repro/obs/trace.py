@@ -6,8 +6,8 @@ iterations, stream finish) can be recorded as a ``Span`` — a named
 interval on the *cluster clock*. Both substrates feed the same span
 names from the same places:
 
-* the discrete-event simulator stamps spans on its event clock
-  (``EventClock`` — virtual seconds, advanced by the host);
+* ``SimBackend`` stamps spans on its event clock (``EventClock`` —
+  virtual seconds, advanced by the host);
 * the real-JAX engine stamps spans on wall-clock seconds since run
   start (``WallClock`` — the same domain ``EngineBackend.wall_now``
   serves).
@@ -61,7 +61,7 @@ class WallClock:
 
 
 class EventClock:
-    """Manually-advanced virtual clock (the simulator's event-time
+    """Manually-advanced virtual clock (``SimBackend``'s event-time
     domain). The host advances it; it never goes backwards."""
 
     def __init__(self, t: float = 0.0):
@@ -130,7 +130,7 @@ class Tracer:
     ``max_spans`` — oldest dropped first) and fans every span out to
     listeners (flight-recorder ring, drift meter, streaming writers).
     ``record`` is the only write path; it is deliberately allocation-
-    light because the simulator calls it once per iteration."""
+    light because simulated servers call it once per iteration."""
 
     def __init__(self, clock: Optional[Clock] = None, *,
                  keep_all: bool = True,
@@ -224,10 +224,9 @@ def record_request_spans(tracer: Tracer, req) -> Optional[Span]:
     root so child durations telescope to *exactly* the root duration
     (= measured TTFT + generation time) on both substrates.
 
-    Both the cluster facade and the standalone simulator call this
-    one helper, which is what guarantees sim-vs-engine span-name
-    parity. Returns None (and records nothing) for unfinished
-    requests."""
+    The cluster facade calls this one helper on both substrates,
+    which is what guarantees sim-vs-engine span-name parity. Returns
+    None (and records nothing) for unfinished requests."""
     finish = req.finish
     if finish is None or finish < 0:
         return None

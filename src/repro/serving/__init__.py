@@ -1,9 +1,10 @@
+from repro.core.request import Phase, Request, ServeRequest
+
 from .backend import EngineBackend, ServingBackend, SimBackend
 from .cluster import (ClusterEvent, ClusterReport, LoRAServeCluster,
                       ServeResult)
 from .engine import ServingEngine
 from .metrics import MetricsCollector, percentile
-from .request import Phase, Request, ServeRequest
 from .scheduler import replay
 from .paging import OutOfPages, UnifiedPagePool
 

@@ -211,7 +211,6 @@ class SimBackend:
                     self._timed_out.append(r)
             if s.busy_until <= now + 1e-12 and s.has_work(now):
                 s.step(now)
-            s.finished.clear()   # completions flow via _completed here
         still = []
         for r in self._inflight:
             (self._completed if r.finish >= 0 else still).append(r)
@@ -311,7 +310,6 @@ class SimBackend:
         stranded = list(s.waiting) + list(s.running)
         s.waiting.clear()
         s.running.clear()
-        s.finished.clear()
         s.busy_until = 0.0
         gone = {id(r) for r in stranded}
         self._inflight = [r for r in self._inflight

@@ -226,8 +226,7 @@ class LoRAServeCluster:
         self.orch = ClusterOrchestrator(
             backend.n_servers, adapters, operating_points, policy=policy,
             network=network, seed=seed, access_mode=access_mode,
-            prefetch=prefetch, sync_store=False, retry=retry_policy,
-            durable_ssd=durable_ssd)
+            prefetch=prefetch, retry=retry_policy, durable_ssd=durable_ssd)
         self.metrics = MetricsCollector()
         # always-on live telemetry window (the gateway's /metrics feed);
         # lazy import keeps repro.serving importable without dragging
@@ -426,13 +425,27 @@ class LoRAServeCluster:
         remote-read serves. The promote is unconditional (a no-op
         discard for non-remote copies) because a remote-read serve may
         have coalesced onto a transfer that started as a prefetch or
-        migrate fetch."""
-        for plan in self.orch.store.poll(now):
+        migrate fetch.
+
+        A retried transfer lands later than the ETA quoted at dispatch
+        (a stalled attempt quotes ``eta=inf`` to requests coalescing
+        onto it), so requests still waiting on a landed copy become
+        ready now. Only a virtual-clock backend gates admission on
+        ``ready``."""
+        landed = self.orch.store.poll(now)
+        for plan in landed:
             aid = plan.adapter_id
             if plan.mode in ("prefetch", "drain"):
                 self.backend.load_adapters(
                     plan.dest, {aid: self.meta[aid].rank})
             self.backend.promote_adapter(plan.dest, aid)
+        if landed and not self.backend.realtime:
+            keys = {(p.dest, p.adapter_id) for p in landed}
+            for r in self.backend.live_requests():
+                if (r.server, r.adapter_id) in keys \
+                        and r.ready > now + 1e-12:
+                    r.fetch_latency = max(0.0, now - r.arrival)
+                    r.ready = now
 
     # -- chaos plane (repro.faults) ---------------------------------------
     def apply_fault(self, ev, now: float) -> bool:
@@ -456,7 +469,7 @@ class LoRAServeCluster:
         if ev.kind == KIND_LINK_UP:
             if net is None:
                 return False
-            net.set_link_up(ev.target)
+            net.reset_link(ev.target)     # up, and any degrade healed
             return True
         if ev.kind == KIND_LINK_DEGRADE:
             if net is None:
@@ -518,10 +531,11 @@ class LoRAServeCluster:
     def inject_stall(self, target: int = -1,
                      extra: float = 0.0) -> bool:
         """Freeze (``extra == 0``) or slow one in-flight transfer
-        touching server ``target`` (any transfer when -1)."""
+        touching server ``target`` (any transfer when -1) that is not
+        already stalled, so repeated stalls hit distinct transfers."""
         store = self.orch.store
         for (dest, aid), p in sorted(store._inflight.items()):
-            if p.retry_at >= 0:
+            if p.retry_at >= 0 or p.stalled:
                 continue
             if target >= 0 and dest != target and p.src_server != target:
                 continue

@@ -46,15 +46,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.request import Phase, ServeRequest
 from repro.lora.adapter import Adapter
 from repro.lora.bank import build_bank
 from repro.models import model as M
 
 from .metrics import MetricsCollector
 from .paging import UnifiedPagePool
-from .request import Phase, ServeRequest
-
-Request = ServeRequest
 
 
 class ServingEngine:

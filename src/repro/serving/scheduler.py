@@ -13,8 +13,9 @@ import time
 import warnings
 from typing import List
 
+from repro.core.request import ServeRequest
+
 from .engine import ServingEngine
-from .request import ServeRequest
 
 
 def replay(engine: ServingEngine, requests: List[ServeRequest],

@@ -15,8 +15,8 @@ import math
 import random
 from typing import List, Optional
 
+from repro.core.request import ServeRequest
 from repro.core.types import AdapterInfo
-from repro.cluster.server import SimRequest
 
 from .synth import make_adapters
 
@@ -44,7 +44,7 @@ def _drift(pattern: str, progress: float) -> float:
 def production_trace(n_adapters: int, rps: float, duration: float,
                      prompt_len: int = 512, output_len: int = 128,
                      seed: int = 0,
-                     load_profile: Optional[str] = None) -> List[SimRequest]:
+                     load_profile: Optional[str] = None) -> List[ServeRequest]:
     reqs, _ = production_trace_with_meta(
         n_adapters, rps, duration, prompt_len=prompt_len,
         output_len=output_len, seed=seed, load_profile=load_profile)
@@ -96,7 +96,7 @@ def production_trace_with_meta(n_adapters: int, rps: float,
     peak_i = max(sum(rank_weights(p)) for p in grid)
     peak_l = max(load(p) for p in grid)
 
-    reqs: List[SimRequest] = []
+    reqs: List[ServeRequest] = []
     t, i = 0.0, 0
     while t < duration:
         t += rng.expovariate(rps * peak_i * peak_l)
@@ -122,8 +122,9 @@ def production_trace_with_meta(n_adapters: int, rps: float,
             a = rng.choices(tail, weights=aw)[0]
         pl = max(16, int(rng.lognormvariate(math.log(prompt_len), 0.4)))
         ol = max(4, int(rng.lognormvariate(math.log(output_len), 0.4)))
-        reqs.append(SimRequest(req_id=i, adapter_id=a.adapter_id, rank=rank,
-                               prompt_len=pl, output_len=ol, arrival=t))
+        reqs.append(ServeRequest(req_id=i, adapter_id=a.adapter_id,
+                                 rank=rank, prompt_len=pl, output_len=ol,
+                                 arrival=t))
         i += 1
     meta = {"patterns": patterns, "adapters": adapters,
             "load_profile": load_profile or "flat"}

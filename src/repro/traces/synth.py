@@ -17,8 +17,8 @@ import math
 import random
 from typing import Dict, List, Optional, Sequence
 
+from repro.core.request import ServeRequest
 from repro.core.types import AdapterInfo
-from repro.cluster.server import SimRequest
 
 DEFAULT_RANKS = (8, 16, 32, 64, 128)
 
@@ -87,7 +87,7 @@ def synth_trace(adapters: List[AdapterInfo], rps: float, duration: float,
                 arrival: str = "poisson", popularity: str = "uniform",
                 alpha: float = 1.0, prompt_len: int = 512,
                 output_len: int = 128, jitter: float = 0.3,
-                seed: int = 0) -> List[SimRequest]:
+                seed: int = 0) -> List[ServeRequest]:
     rng = random.Random(seed)
     by_rank: Dict[int, List[AdapterInfo]] = {}
     for a in adapters:
@@ -104,9 +104,9 @@ def synth_trace(adapters: List[AdapterInfo], rps: float, duration: float,
         a = rng.choices(pool, weights=aw)[0]
         pl = max(16, int(rng.lognormvariate(math.log(prompt_len), jitter)))
         ol = max(4, int(rng.lognormvariate(math.log(output_len), jitter)))
-        reqs.append(SimRequest(req_id=i, adapter_id=a.adapter_id,
-                               rank=rank, prompt_len=pl, output_len=ol,
-                               arrival=t))
+        reqs.append(ServeRequest(req_id=i, adapter_id=a.adapter_id,
+                                 rank=rank, prompt_len=pl, output_len=ol,
+                                 arrival=t))
     return reqs
 
 

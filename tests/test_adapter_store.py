@@ -10,13 +10,13 @@ import jax.numpy as jnp
 import pytest
 from hypothesis_shim import given, settings, st
 
-from repro.cluster import ClusterSimulator, NetworkModel
+from repro.cluster import NetworkModel
 from repro.configs import get_smoke_config
 from repro.core import AdapterInfo, AdapterStore, ServeRequest
 from repro.lora.bank import build_bank
 from repro.models import model as M
 from repro.serving import (EngineBackend, LoRAServeCluster, Request,
-                           ServingEngine)
+                           ServingEngine, SimBackend)
 from repro.traces import make_adapters, synth_trace
 
 
@@ -111,7 +111,7 @@ def test_host_cache_tier_serves_refetches():
     store, _, placement = _store()
     # migrate a0 away; the old HBM copy demotes to server 0's host cache
     store.apply_placement({**placement, "a0": {1: 1.0}})
-    store.ensure_local(1, "a0")
+    store.finish(store.start_fetch(1, "a0"))
     assert store.index["a0"] == {1}
     assert store.tier(0, "a0") == "host"
     # flip back: the refetch reads the local host tier, not a peer
@@ -169,16 +169,19 @@ def test_invariant_under_interleaved_rebalance_and_async_fetches(seed):
     assert store.total_bytes() >= max(a.nbytes for a in adapters)
 
 
-# ------------------------------------------------------------ simulator
+# ------------------------------------------------------ simulated cluster
 def test_simulator_remote_read_and_prefetch_end_to_end():
     adapters = make_adapters(12, seed=1)
     trace = synth_trace(adapters, rps=10, duration=60,
                         popularity="shifting", seed=2)
 
     def run(**kw):
-        sim = ClusterSimulator(3, adapters, policy="loraserve", seed=3,
-                               timeout=60, **kw)
-        return sim.run(copy.deepcopy(trace))
+        backend = SimBackend(3, timeout=60,
+                             adapter_nbytes={a.adapter_id: a.nbytes
+                                             for a in adapters})
+        cluster = LoRAServeCluster(backend, adapters, policy="loraserve",
+                                   seed=3, network=NetworkModel(), **kw)
+        return cluster.run(copy.deepcopy(trace))
 
     migrate = run()
     remote = run(access_mode="remote-read")
@@ -189,8 +192,8 @@ def test_simulator_remote_read_and_prefetch_end_to_end():
     assert pre.prefetches > 0
     assert migrate.remote_reads == 0 and migrate.prefetches == 0
     # remote-read never blocks on a fetch; migrate pays them on misses
-    assert all(r.fetch_latency == 0.0 for r in remote.requests)
-    assert any(r.fetch_latency > 0.0 for r in migrate.requests)
+    assert all(r.fetch_latency == 0.0 for r in remote.results)
+    assert any(r.fetch_latency > 0.0 for r in migrate.results)
 
 
 # ------------------------------------------------------- real JAX engine

@@ -1,7 +1,9 @@
 """Rank-bucketed LoRA banks end-to-end: padded-vs-bucketed parity on the
 real engine (token-identical outputs, allclose logits), the bucketed
 cost-model primitives (strictly cheaper for mixed-rank batches), the
-Pallas dispatch helper, and the simulator's bucketed iteration costs."""
+Pallas dispatch helper, and the simulated servers' bucketed iteration
+costs."""
+import copy
 import time
 
 import jax
@@ -9,11 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cluster import ClusterSimulator, ServerModel
+from repro.cluster import NetworkModel, ServerModel
 from repro.configs import get_smoke_config
 from repro.lora import LoRABank, apply_bank_sgmv, build_bank, rank_bucket
 from repro.models import model as M
-from repro.serving import Request, ServingEngine
+from repro.serving import (LoRAServeCluster, Request, ServingEngine,
+                           SimBackend)
 from repro.traces import make_adapters, synth_trace
 
 ADAPTERS = {"a-r8": 8, "b-r64": 64, "c-r8": 8}
@@ -207,18 +210,21 @@ def test_decode_time_seq_len_param():
     assert s.kv_read_bytes(512) == pytest.approx(2 * 2 * 32 * 1024 * 512)
 
 
-# -- simulator ------------------------------------------------------------
+# -- simulated cluster ----------------------------------------------------
 def test_sim_bucketed_shrinks_rank_skew():
     """The padded-mode P95 TTFT skew from co-batching heterogeneous
     ranks shrinks when the simulated servers run bucketed banks."""
     adapters = make_adapters(24, seed=1)
     trace = synth_trace(adapters, rps=25, duration=40,
                         popularity="powerlaw", alpha=1.0, seed=2)
-    import copy
     res = {}
     for mode in ("padded", "bucketed"):
-        sim = ClusterSimulator(2, adapters, policy="slora-random", seed=3,
-                               timeout=60, warmup=10, bank_mode=mode)
-        res[mode] = sim.run(copy.deepcopy(trace))
+        backend = SimBackend(2, timeout=60, bank_mode=mode,
+                             adapter_nbytes={a.adapter_id: a.nbytes
+                                             for a in adapters})
+        cluster = LoRAServeCluster(backend, adapters,
+                                   policy="slora-random", seed=3,
+                                   warmup=10, network=NetworkModel())
+        res[mode] = cluster.run(copy.deepcopy(trace))
     assert res["bucketed"].p95_ttft() < res["padded"].p95_ttft()
     assert res["bucketed"].completed() >= res["padded"].completed()

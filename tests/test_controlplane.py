@@ -4,13 +4,14 @@ import copy
 
 import pytest
 
-from repro.cluster import ClusterSimulator, NetworkModel
+from repro.cluster import NetworkModel
 from repro.controlplane import (ClusterController, ControllerConfig,
                                 DriftDetector, SLOSpec, SLOTracker,
                                 TelemetryHub)
 from repro.core import AdapterInfo, ServeRequest
 from repro.core.pool import AdapterStore
 from repro.core.routing import RetiredServerError, RoutingTable
+from repro.serving import LoRAServeCluster, SimBackend
 from repro.traces import (make_adapters, production_trace_with_meta,
                           synth_trace)
 
@@ -205,7 +206,15 @@ def test_routing_block_server():
         table.block_server(1)           # "b" would lose its only route
 
 
-# -- closed loop on the simulator --------------------------------------
+# -- closed loop on the simulated backend ------------------------------
+
+def _sim_cluster(n, adapters, controller=None):
+    backend = SimBackend(n, timeout=120,
+                         adapter_nbytes={a.adapter_id: a.nbytes
+                                         for a in adapters})
+    return LoRAServeCluster(backend, adapters, policy="loraserve", seed=3,
+                            network=NetworkModel(), controller=controller)
+
 
 def _surge_trace(adapters, seed=2):
     """Quiet first half, heavy second half: the load step a static
@@ -225,14 +234,15 @@ def _surge_trace(adapters, seed=2):
 def test_scale_up_restores_slo_under_surge():
     adapters = make_adapters(24, seed=1)
     trace = _surge_trace(adapters)
-    static = ClusterSimulator(2, adapters, policy="loraserve", seed=3,
-                              timeout=120)
-    res_static = static.run(copy.deepcopy(trace))
-    auto = ClusterSimulator(
-        2, adapters, policy="loraserve", seed=3, timeout=120,
-        controller=_controller(2, 6, patience=2, cooldown=10.0))
+    res_static = _sim_cluster(2, adapters).run(copy.deepcopy(trace))
+    auto = _sim_cluster(
+        2, adapters, _controller(2, 6, patience=2, cooldown=10.0))
     res_auto = auto.run(copy.deepcopy(trace))
     assert res_auto.scale_ups >= 1
+    first_up = next(a.time for a in res_auto.controller_actions
+                    if a.kind == "scale-up")
+    assert first_up >= 60.0   # surge starts at t=60
+    assert res_auto.gpu_seconds > 0
     assert res_auto.final_servers > 2
     att_auto = res_auto.slo_attainment(8.0)
     att_static = res_static.slo_attainment(8.0)
@@ -247,23 +257,22 @@ def test_drain_is_loss_free_with_live_traffic():
     trace = synth_trace(adapters, rps=2.0, duration=150,
                         popularity="exponential", seed=2)
     ctrl = _controller(1, 6, drain_patience=2, cooldown=10.0)
-    sim = ClusterSimulator(4, adapters, policy="loraserve", seed=3,
-                           timeout=120, controller=ctrl)
-    res = sim.run(copy.deepcopy(trace))
+    reqs = copy.deepcopy(trace)
+    res = _sim_cluster(4, adapters, ctrl).run(reqs)
     assert res.retires >= 1, "fleet never shrank; test is vacuous"
     assert res.timed_out == 0
     assert res.completed() == len(trace)
-    assert all(r.finish >= 0 for r in res.requests)
-    retire_time = {a.server: a.time for a in res.actions
+    assert all(r.finished for r in res.results)
+    retire_time = {a.server: a.time for a in res.controller_actions
                    if a.kind == "retire"}
-    for r in res.requests:
+    for r in res.results:
         if r.server in retire_time:
             assert r.arrival <= retire_time[r.server], \
                 (f"req {r.req_id} routed to server {r.server} after "
                  f"it retired at {retire_time[r.server]}")
     # a retired server stops billing: strictly cheaper than keeping
     # the whole initial fleet up for the entire run
-    end = max(r.finish for r in res.requests)
+    end = max(r.finish for r in reqs)
     assert res.gpu_seconds < 4 * end
 
 
@@ -274,20 +283,18 @@ def test_controller_noop_on_stable_trace():
     trace = synth_trace(adapters, rps=10, duration=90,
                         popularity="exponential", seed=2)
     ctrl = _controller(3, 6)   # min == initial n: drains impossible
-    sim = ClusterSimulator(3, adapters, policy="loraserve", seed=3,
-                           timeout=120, controller=ctrl)
-    res = sim.run(copy.deepcopy(trace))
+    res = _sim_cluster(3, adapters, ctrl).run(copy.deepcopy(trace))
     assert res.scale_ups == 0
     assert res.drains == 0
     assert res.retires == 0
-    assert [a for a in res.actions if a.kind != "rebalance"] == []
+    assert [a for a in res.controller_actions
+            if a.kind != "rebalance"] == []
     assert res.slo_attainment(8.0) >= 0.95
 
 
 def test_facade_drain_loss_free_simbackend():
     """Same loss-free guarantee through the serving facade path
     (LoRAServeCluster + SimBackend + real AdapterStore data plane)."""
-    from repro.serving import LoRAServeCluster, SimBackend
     adapters = make_adapters(16, seed=1)
     trace = synth_trace(adapters, rps=1.5, duration=100,
                         popularity="exponential", seed=2)
@@ -307,7 +314,6 @@ def test_facade_drain_loss_free_simbackend():
 
 
 def test_backend_add_and_retire_server():
-    from repro.serving import SimBackend
     b = SimBackend(2)
     sid = b.add_server()
     assert sid == 2 and b.n_servers == 3
@@ -315,22 +321,6 @@ def test_backend_add_and_retire_server():
     assert b.hosted_adapters(2) == {"a0": 8}
     b.retire_server(2)
     assert b.hosted_adapters(2) == {}
-
-
-def test_provision_delay_defers_capacity():
-    adapters = make_adapters(24, seed=1)
-    trace = _surge_trace(adapters)
-    auto = ClusterSimulator(
-        2, adapters, policy="loraserve", seed=3, timeout=120,
-        controller=_controller(2, 6, patience=2, cooldown=10.0),
-        provision_delay=10.0)
-    res = auto.run(copy.deepcopy(trace))
-    assert res.scale_ups >= 1
-    first_up = next(a.time for a in res.actions if a.kind == "scale-up")
-    # billed from the request, but capacity (and placement) lands later
-    assert res.gpu_seconds > 0
-    assert res.final_servers > 2
-    assert first_up >= 60.0   # surge starts at t=60
 
 
 # -- satellites --------------------------------------------------------

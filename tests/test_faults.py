@@ -27,7 +27,7 @@ import pytest
 
 import jax
 
-from repro.cluster import ClusterSimulator, NetworkModel
+from repro.cluster import NetworkModel
 from repro.configs import get_smoke_config
 from repro.core import AdapterInfo, ServeRequest
 from repro.core.pool import AdapterStore, CircuitBreaker, FetchRetryPolicy
@@ -52,6 +52,20 @@ def _attainment(reqs, t0=0.0, t1=float("inf")):
         return 1.0
     return sum(1 for r in w if r.prefill_done >= 0
                and r.ttft <= SLO_TTFT and r.finish >= 0) / len(w)
+
+
+def _sim_cluster(adapters, plan, rebalance_period=6.0, window=0.5):
+    """Three ``SimBackend`` servers with timeouts off (a lost request is
+    the chaos plane's fault, not the reaper's), prefetching recovery
+    re-warms, the SSD tier, and per-token events on."""
+    backend = SimBackend(3, timeout=1e9,
+                         adapter_nbytes={a.adapter_id: a.nbytes
+                                         for a in adapters})
+    return LoRAServeCluster(
+        backend, adapters, policy="loraserve", seed=7,
+        network=NetworkModel(), rebalance_period=rebalance_period,
+        prefetch=True, fault_plan=plan, detector_window=window,
+        durable_ssd=True, track_tokens=True)
 
 
 def _drive(cluster, trace, max_steps=200_000):
@@ -107,28 +121,29 @@ def test_sim_kill_a_server_loss_free():
     trace = synth_trace(adapters, rps=14.0, duration=24.0,
                         popularity="shifting", prompt_len=128,
                         output_len=64, seed=11)
-    sim = ClusterSimulator(3, adapters, policy="loraserve", seed=7,
-                           timeout=1e9, rebalance_period=6.0,
-                           prefetch=True,
-                           fault_plan=FaultPlan.kill_one(t_kill, 0,
-                                                         t_restore),
-                           detector_window=window, durable_ssd=True)
-    res = sim.run(copy.deepcopy(trace))
+    cluster = _sim_cluster(
+        adapters, FaultPlan.kill_one(t_kill, 0, t_restore),
+        window=window)
+    reqs = copy.deepcopy(trace)
+    events, _ = _drive(cluster, reqs)
+    res = cluster.report()
 
     assert res.server_failures == 1 and res.recoveries == 1
     assert res.redispatched >= 1
     # loss-free: every request finished, token ledger exact
-    assert all(r.finish >= 0 for r in res.requests)
-    assert all(r.decoded == r.output_len for r in res.requests)
-    assert len(res.requests) == len(trace)
+    assert all(r.finished for r in res.results)
+    assert all(r.decoded == r.output_len for r in reqs)
+    assert _token_counts(events) == {r.req_id: r.output_len
+                                     for r in reqs}
+    assert len(res.results) == len(trace)
     # detection within one window of the crash
     (rec,) = res.recovery_records
     assert rec.server == 0
     assert abs(rec.detected_at - (t_kill + window)) < 1e-6
     assert rec.redispatched == res.redispatched
     # the SLO dips during the fault and restores after
-    pre = _attainment(res.requests, 0.0, t_kill)
-    post = _attainment(res.requests, t_restore)
+    pre = _attainment(reqs, 0.0, t_kill)
+    post = _attainment(reqs, t_restore)
     assert post >= pre - 1e-9
 
 
@@ -138,19 +153,46 @@ def test_sim_kill_without_restore_survivors_carry():
     adapters = make_adapters(6, seed=3)
     trace = synth_trace(adapters, rps=10.0, duration=18.0,
                         prompt_len=128, output_len=48, seed=9)
-    sim = ClusterSimulator(3, adapters, policy="loraserve", seed=7,
-                           timeout=1e9, rebalance_period=1e9,
-                           prefetch=True,
-                           fault_plan=FaultPlan.kill_one(6.0, 1),
-                           detector_window=0.5, durable_ssd=True)
-    res = sim.run(copy.deepcopy(trace))
+    cluster = _sim_cluster(adapters, FaultPlan.kill_one(6.0, 1),
+                           rebalance_period=1e9)
+    reqs = copy.deepcopy(trace)
+    events, _ = _drive(cluster, reqs)
+    res = cluster.report()
     assert res.server_failures == 1 and res.recoveries == 1
-    assert all(r.finish >= 0 for r in res.requests)
-    assert all(r.decoded == r.output_len for r in res.requests)
+    assert all(r.finished for r in res.results)
+    assert all(r.decoded == r.output_len for r in reqs)
+    assert _token_counts(events) == {r.req_id: r.output_len
+                                     for r in reqs}
     # nothing arriving after confirmation landed on the dead server
     (rec,) = res.recovery_records
-    assert all(r.server != 1 for r in res.requests
+    assert all(r.server != 1 for r in res.results
                if r.arrival > rec.detected_at)
+
+
+def test_sim_stalled_recovery_fetches_retry_loss_free():
+    """Hang the re-warm transfers launched at crash recovery: each
+    stall hits a distinct transfer, blows its deadline and retries,
+    and requests that coalesced onto a stalled transfer (quoted
+    eta=inf) wake when the retried copy lands instead of waiting
+    forever."""
+    adapters = make_adapters(6, seed=3)
+    trace = synth_trace(adapters, rps=10.0, duration=18.0,
+                        popularity="shifting", prompt_len=128,
+                        output_len=64, seed=9)
+    plan = FaultPlan.kill_one(6.0, 0, 12.0)
+    for i in range(4):
+        plan = FaultPlan(plan.events
+                         + FaultPlan.stall(6.5 + 0.002 * (i + 1)).events)
+    cluster = _sim_cluster(adapters, plan)
+    reqs = copy.deepcopy(trace)
+    events, _ = _drive(cluster, reqs)
+    res = cluster.report()
+    assert res.fetch_timeouts >= 2
+    assert res.fetch_retries == res.fetch_timeouts
+    assert all(r.finished for r in res.results)
+    assert _token_counts(events) == {r.req_id: r.output_len
+                                     for r in reqs}
+    assert all(r.fetch_latency < float("inf") for r in res.results)
 
 
 # ---------------------------------------------------------------------
@@ -482,13 +524,13 @@ def test_sim_random_fault_storm_drains_loss_free():
                         output_len=32, seed=100 + CHAOS_SEED)
     plan = FaultPlan.random_plan(CHAOS_SEED, horizon=16.0, n_servers=3,
                                  rate=0.4)
-    sim = ClusterSimulator(3, adapters, policy="loraserve", seed=7,
-                           timeout=1e9, rebalance_period=6.0,
-                           prefetch=True, fault_plan=plan,
-                           detector_window=0.5, durable_ssd=True)
-    res = sim.run(trace)
-    assert all(r.finish >= 0 for r in res.requests)
-    assert all(r.decoded == r.output_len for r in res.requests)
+    cluster = _sim_cluster(adapters, plan)
+    events, _ = _drive(cluster, trace)
+    res = cluster.report()
+    assert all(r.finished for r in res.results)
+    assert all(r.decoded == r.output_len for r in trace)
+    assert _token_counts(events) == {r.req_id: r.output_len
+                                     for r in trace}
     # sub-window flaps heal before detection and run no recovery, so
     # recoveries may trail failures but each one must be recorded
     assert res.recoveries == len(res.recovery_records)

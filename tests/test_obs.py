@@ -12,7 +12,7 @@ import pytest
 
 import jax
 
-from repro.cluster import ClusterSimulator, NetworkModel
+from repro.cluster import NetworkModel
 from repro.configs import get_smoke_config
 from repro.controlplane import (ClusterController, ControllerConfig,
                                 SLOSpec, TelemetryHub)
@@ -31,18 +31,21 @@ from repro.traces import make_adapters, synth_trace
 # workload helpers
 # ---------------------------------------------------------------------
 def _sim_run(n_servers=2, n_adapters=8, rps=6.0, duration=8.0, seed=3,
-             controller=None, recorder=None, **sim_kw):
+             controller=None, recorder=None):
+    """Traced ``SimBackend`` run; returns (report, tracer, trace) — the
+    trace's requests carry their lifecycle stamps after the run."""
     adapters = make_adapters(n_adapters, seed=seed)
     trace = synth_trace(adapters, rps=rps, duration=duration,
                         prompt_len=96, output_len=24, seed=seed)
     tracer = Tracer(clock=EventClock())
-    sim = ClusterSimulator(n_servers, adapters, policy="loraserve",
-                           seed=seed, timeout=120.0, warmup=0.0,
-                           rebalance_period=4.0, controller=controller,
-                           tracer=tracer, flight_recorder=recorder,
-                           **sim_kw)
-    res = sim.run(trace)
-    return res, tracer
+    backend = SimBackend(n_servers, timeout=120.0,
+                         adapter_nbytes={a.adapter_id: a.nbytes
+                                         for a in adapters})
+    cluster = LoRAServeCluster(
+        backend, adapters, policy="loraserve", seed=seed,
+        network=NetworkModel(), rebalance_period=4.0,
+        controller=controller, tracer=tracer, flight_recorder=recorder)
+    return cluster.run(trace), tracer, trace
 
 
 def _facade_adapters():
@@ -102,7 +105,7 @@ def _request_trees(tracer):
 
 
 def test_sim_span_tree_and_exact_decomposition():
-    res, tracer = _sim_run()
+    res, tracer, trace = _sim_run()
     assert res.completed() > 0 and tracer.n_spans > 0
     by_id = {s.span_id: s for s in tracer.spans}
     for s in tracer.spans:
@@ -121,8 +124,7 @@ def test_sim_span_tree_and_exact_decomposition():
         assert math.isclose(total, root.duration,
                             rel_tol=0, abs_tol=1e-9)
     # root duration is the measured arrival->finish interval
-    fin = {r.req_id: r for r in res.requests if r.finish is not None
-           and r.finish >= 0}
+    fin = {r.req_id: r for r in trace if r.finish >= 0}
     for rid, (root, _kids) in trees.items():
         assert math.isclose(root.duration,
                             fin[rid].finish - fin[rid].arrival,
@@ -315,7 +317,7 @@ def test_engine_clock_reads_without_tracer(engine_setup):
 # Perfetto / JSONL export
 # ---------------------------------------------------------------------
 def test_perfetto_golden_schema(tmp_path):
-    res, tracer = _sim_run(duration=4.0)
+    _res, tracer, _trace = _sim_run(duration=4.0)
     doc = to_perfetto(tracer)
     assert set(doc) == {"traceEvents", "displayTimeUnit"}
     assert doc["displayTimeUnit"] == "ms"
@@ -341,7 +343,7 @@ def test_perfetto_golden_schema(tmp_path):
 
 
 def test_jsonl_export_round_trip(tmp_path):
-    _res, tracer = _sim_run(duration=3.0)
+    _res, tracer, _trace = _sim_run(duration=3.0)
     path = os.path.join(tmp_path, "spans.jsonl")
     n = write_jsonl(tracer, path)
     with open(path) as f:
@@ -360,7 +362,7 @@ def test_sim_drift_is_zero_validating_the_plumbing():
     """Sim iteration spans carry the exact time the simulator charged,
     so modeled==measured up to float noise — any real bias here means
     the pairing (not the model) is broken."""
-    res, _tracer = _sim_run()
+    res, _tracer, _trace = _sim_run()
     drift = res.cost_drift
     assert set(drift) >= {"prefill", "decode"}
     for phase in ("prefill", "decode"):
@@ -421,7 +423,7 @@ def test_flight_recorder_dumps_on_forced_slo_violation(tmp_path):
                          max_servers=3))
     rec = FlightRecorder(capacity=512, out_dir=str(tmp_path),
                          min_interval=0.0)
-    res, _tracer = _sim_run(controller=ctrl, recorder=rec)
+    res, _tracer, _trace = _sim_run(controller=ctrl, recorder=rec)
     assert res.completed() > 0
     assert rec.n_dumps >= 1 and res.flight_dumps == rec.n_dumps
     reasons = {d["reason"] for d in rec.dumps}

@@ -1,11 +1,11 @@
-"""Routing table phi-weighting + distributed adapter pool invariants."""
+"""Routing table phi-weighting + adapter store fetch/GC invariants."""
 import random
 
 import pytest
 from hypothesis_shim import given, settings, st
 
 from repro.cluster import NetworkModel
-from repro.core import AdapterInfo, DistributedAdapterPool, RoutingTable
+from repro.core import AdapterInfo, AdapterStore, RoutingTable
 
 
 def test_route_respects_phi():
@@ -30,30 +30,36 @@ def test_route_counts_tracked():
 def _mk_pool(n_servers=4, n_adapters=6):
     adapters = [AdapterInfo(f"a{i}", 8, nbytes=1000 * (i + 1))
                 for i in range(n_adapters)]
-    pool = DistributedAdapterPool(n_servers, adapters, NetworkModel())
+    pool = AdapterStore(n_servers, adapters, NetworkModel())
     placement = {a.adapter_id: {i % n_servers: 1.0}
                  for i, a in enumerate(adapters)}
     pool.seed(placement)
     return pool, adapters, placement
 
 
+def _fetch(pool, server_id, adapter_id):
+    """Start a fetch and land it at once; returns its plan."""
+    plan = pool.start_fetch(server_id, adapter_id)
+    pool.finish(plan)
+    return plan
+
+
 def test_pool_hit_is_free_miss_pays_fetch():
     pool, adapters, placement = _mk_pool()
     home = next(iter(placement["a0"]))
-    lat, nbytes = pool.ensure_local(home, "a0")
-    assert lat == 0.0 and nbytes == 0
+    plan = _fetch(pool, home, "a0")
+    assert plan.hit and plan.latency == 0.0 and plan.nbytes == 0
     # placement moves a0 to another server; first access there fetches
     other = (home + 1) % 4
     pool.apply_placement({**placement, "a0": {other: 1.0}})
-    lat, nbytes = pool.ensure_local(other, "a0")
-    assert lat > 0.0 and nbytes == adapters[0].nbytes
+    plan = _fetch(pool, other, "a0")
+    assert plan.latency > 0.0 and plan.nbytes == adapters[0].nbytes
     # second access on the new server is now a hit
-    lat2, _ = pool.ensure_local(other, "a0")
-    assert lat2 == 0.0
+    assert _fetch(pool, other, "a0").hit
     # fetch to a server NOT in the desired placement is transient: the
     # delete-after-copy step GC's it while the desired copy survives
     third = (home + 2) % 4
-    pool.ensure_local(third, "a0")
+    _fetch(pool, third, "a0")
     assert pool.check_invariant()
     assert other in pool.index["a0"]
 
@@ -63,7 +69,7 @@ def test_pool_gc_after_migration_keeps_one_copy():
     home = next(iter(placement["a0"]))
     new_home = (home + 2) % 4
     pool.apply_placement({**placement, "a0": {new_home: 1.0}})
-    pool.ensure_local(new_home, "a0")
+    _fetch(pool, new_home, "a0")
     assert pool.index["a0"] == {new_home}   # old copy GC'd
     assert pool.check_invariant()
 
@@ -74,7 +80,7 @@ def test_pool_never_loses_sole_copy():
     # desired moves a1 elsewhere, but no access happens on the new server;
     # a hit on the old server must not evict the only copy
     pool.apply_placement({**placement, "a1": {(home + 1) % 4: 1.0}})
-    lat, _ = pool.ensure_local(home, "a1")   # still a hit on the old home
+    assert _fetch(pool, home, "a1").hit   # still a hit on the old home
     assert pool.check_invariant()
     assert len(pool.index["a1"]) >= 1
 
@@ -88,7 +94,7 @@ def test_pool_invariant_under_random_ops(seed):
     for _ in range(60):
         op = rng.random()
         if op < 0.7:
-            pool.ensure_local(rng.randrange(3), rng.choice(aids))
+            _fetch(pool, rng.randrange(3), rng.choice(aids))
         else:
             new_pl = {aid: {rng.randrange(3): 1.0} for aid in aids}
             pool.apply_placement(new_pl)

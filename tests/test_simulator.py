@@ -1,9 +1,11 @@
-"""Cluster simulator end-to-end behavior + paper-claim directions."""
+"""Simulated cluster end to end (``LoRAServeCluster`` over
+``SimBackend``) + paper-claim directions."""
 import copy
 
 import pytest
 
-from repro.cluster import ClusterSimulator
+from repro.cluster import NetworkModel
+from repro.serving import LoRAServeCluster, SimBackend
 from repro.traces import make_adapters, synth_trace
 
 
@@ -15,10 +17,14 @@ def setup():
     return adapters, trace
 
 
-def _run(adapters, trace, policy, n=4):
-    sim = ClusterSimulator(n, adapters, policy=policy, seed=3,
-                           timeout=60, warmup=40)
-    return sim.run(copy.deepcopy(trace))
+def _run(adapters, trace, policy, n=4, seed=3, timeout=60.0, warmup=40):
+    backend = SimBackend(n, timeout=timeout,
+                         adapter_nbytes={a.adapter_id: a.nbytes
+                                         for a in adapters})
+    cluster = LoRAServeCluster(backend, adapters, policy=policy,
+                               seed=seed, warmup=warmup,
+                               network=NetworkModel())
+    return cluster.run(copy.deepcopy(trace))
 
 
 def test_all_policies_complete(setup):
@@ -73,9 +79,9 @@ def test_weak_scaling_direction():
                      popularity="uniform", seed=6)
     t8 = synth_trace(adapters, rps=40, duration=120,
                      popularity="uniform", seed=6)
-    r4 = ClusterSimulator(4, adapters, policy="loraserve", seed=7,
-                          warmup=30).run(copy.deepcopy(t4))
-    r8 = ClusterSimulator(8, adapters, policy="loraserve", seed=7,
-                          warmup=30).run(copy.deepcopy(t8))
+    r4 = _run(adapters, t4, "loraserve", n=4, seed=7, timeout=120.0,
+              warmup=30)
+    r8 = _run(adapters, t8, "loraserve", n=8, seed=7, timeout=120.0,
+              warmup=30)
     # same per-server load => comparable tail latency (within 4x)
     assert r8.p95_ttft() < max(4 * r4.p95_ttft(), 2.0)
